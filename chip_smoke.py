@@ -1,0 +1,554 @@
+"""chip_smoke.py — does the system still start, compile and finish on the chip?
+
+One process drives the two main paths once, through the entry points a user
+calls, at the full width of the model the repo carries for this purpose
+(vocab 32000, h=2048, ffn 5632, 12 layers, 16 heads x 128, bf16, ~745M
+parameters; weights random from a seed):
+
+  device   platform / device_kind / count, versions, compile-cache root.
+           Not a TPU -> exit 1 before anything is built.
+  kernels  flash_attention (fwd+bwd), paged_attention (bf16 and int8 pool),
+           grouped_matmul (bf16 and int8 rhs): compiled by Mosaic, not
+           interpreted, and equal to their XLA fallbacks within a tolerance.
+  train    AdamW(multi_precision) + jit.TrainStep (donation on) fed by the
+           forked-worker DataLoader, seq 2048: loss finite and falling,
+           traced and compiled once, parameters on the TPU.
+  serve    serving.Engine (decode_kernel="auto", prefix cache, chunked
+           prefill, AOT compile cache) behind serving.Server: HTTP
+           completions, one streamed, two sharing a prefix; one greedy
+           request equals model.generate; the decode program holds the
+           Pallas custom call; a second Engine over the same cache
+           directory compiles nothing and answers identically.
+  4 chips  (only with >= 4 devices) the same trainer under
+           dist.parallelize(dp=2 x mp=2) and the same engine at
+           tp_degree=4: every device holds weight and KV shards, results
+           agree with the one-chip phases.
+
+Every phase evaluates all of its checks, prints them, and raises if one
+failed; nothing is caught, so the first failed phase ends the run. The last
+stdout line is one JSON object, printed only when every phase passed. The
+timings printed along the way are smoke output, not metrics.
+
+    python3 chip_smoke.py
+"""
+from __future__ import annotations
+
+import gc
+import http.client
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+
+WIDTH = dict(
+    vocab_size=32000, hidden_size=2048, intermediate_size=5632,
+    num_hidden_layers=12, num_attention_heads=16,
+    max_position_embeddings=2048,
+)
+SEQ, HEADS, HEAD_DIM, PAGE = 2048, 16, 128, 16
+TRAIN_BATCH, TRAIN_STEPS = 2, 4
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+class Checks:
+    """One phase's checks: all evaluated and printed, then the phase
+    fails if any did."""
+
+    def __init__(self, phase):
+        self.phase, self.failed = phase, []
+        self.t0 = time.perf_counter()
+        say(f"== {phase}")
+
+    def check(self, name, ok, detail=""):
+        say(f"  [{'ok' if ok else 'FAIL'}] {name}" +
+            (f" — {detail}" if detail else ""))
+        if not ok:
+            self.failed.append(name)
+
+    def done(self):
+        if self.failed:
+            raise SystemExit(
+                f"chip_smoke: phase {self.phase!r} FAILED: {self.failed}"
+            )
+        say(f"== {self.phase} passed "
+            f"({time.perf_counter() - self.t0:.1f}s)")
+
+
+# ---------------------------------------------------------------- device
+def phase_device():
+    import jax
+    import jaxlib
+
+    from paddle_tpu.compilecache import enable_persistent_cache
+
+    cache_root = enable_persistent_cache()
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    try:
+        import libtpu
+        libtpu_version = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        libtpu_version = "absent"
+    say(f"== device {device} jax={jax.__version__} "
+        f"jaxlib={jaxlib.__version__} libtpu={libtpu_version} "
+        f"compile_cache={cache_root}")
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke: JAX reports platform {dev.platform!r}, not a "
+            "TPU; nothing is built off the chip"
+        )
+    return device, cache_root
+
+
+# --------------------------------------------------------------- kernels
+def _rel_err(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.max(np.abs(got - ref)) / max(1.0, np.max(np.abs(ref))))
+
+
+def _mosaic(fn, *args):
+    """The jitted fn and whether its lowering carries the Mosaic call."""
+    import jax
+
+    jitted = jax.jit(fn)
+    return jitted, "tpu_custom_call" in jitted.lower(*args).as_text()
+
+
+def phase_kernels():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.pallas import _compat
+    from paddle_tpu.kernels.pallas.flash_attention import flash_attention
+    from paddle_tpu.kernels.pallas.grouped_matmul import (
+        grouped_matmul, grouped_matmul_xla,
+    )
+    from paddle_tpu.kernels.pallas.paged_attention import (
+        paged_attention, paged_attention_xla, quantize_tokens,
+    )
+    from paddle_tpu.ops.impl.nn_ops import scaled_dot_product_attention
+    from paddle_tpu.quantization import weight_quantize_grouped
+
+    c = Checks("kernels")
+    c.check("Pallas kernels are compiled, not interpreted",
+            not _compat.interpret_mode())
+    bf16, tol = jnp.bfloat16, 2e-2
+    key = jax.random.key(0)
+
+    # flash attention, forward and backward, against the math sdpa (an
+    # explicit causal mask keeps sdpa off the kernel route)
+    kq, kk, kv, kw, key = jax.random.split(key, 5)
+    shape = (2, SEQ, HEADS, HEAD_DIM)
+    q, k, v, w = (jax.random.normal(r, shape, bf16)
+                  for r in (kq, kk, kv, kw))
+    causal = jnp.tril(jnp.ones((SEQ, SEQ), bool))
+
+    def flash_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+
+    def math_loss(q, k, v):
+        out = scaled_dot_product_attention(q, k, v, causal)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+
+    flash, is_mosaic = _mosaic(
+        jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True),
+        q, k, v,
+    )
+    c.check("flash_attention fwd+bwd lowered to tpu_custom_call", is_mosaic)
+    (_, out), grads = flash(q, k, v)
+    (_, ref), ref_grads = jax.jit(jax.value_and_grad(
+        math_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    err = _rel_err(out, ref)
+    c.check("flash_attention forward == math sdpa", err <= tol,
+            f"rel err {err:.2e} (tol {tol})")
+    for name, g, rg in zip("qkv", grads, ref_grads):
+        err = _rel_err(g, rg)
+        c.check(f"flash_attention d{name} == math sdpa", err <= tol,
+                f"rel err {err:.2e} (tol {tol})")
+
+    # paged decode attention: a length-0 slot, a one-token slot, full and
+    # partial last pages, a full-length slot; pages scattered in the pool
+    lengths = jnp.array([0, 1, 15, 16, 17, 700, SEQ - 1, SEQ], jnp.int32)
+    batch, pages_per_seq = lengths.shape[0], SEQ // PAGE
+    n_pages = batch * pages_per_seq
+    kq, kk, kv, kt, key = jax.random.split(key, 5)
+    qd = jax.random.normal(kq, (batch, HEADS, HEAD_DIM), bf16)
+    pool = (HEADS, n_pages, PAGE, HEAD_DIM)
+    kp = jax.random.normal(kk, pool, bf16)
+    vp = jax.random.normal(kv, pool, bf16)
+    tables = jax.random.permutation(kt, n_pages).reshape(
+        batch, pages_per_seq).astype(jnp.int32)
+    for label, kpool, vpool in (
+        ("bf16 pool", kp, vp),
+        ("int8 pool", quantize_tokens(kp), quantize_tokens(vp)),
+    ):
+        paged, is_mosaic = _mosaic(
+            paged_attention, qd, kpool, vpool, tables, lengths)
+        c.check(f"paged_attention ({label}) lowered to tpu_custom_call",
+                is_mosaic)
+        got = paged(qd, kpool, vpool, tables, lengths)
+        ref = jax.jit(paged_attention_xla)(qd, kpool, vpool, tables, lengths)
+        err = _rel_err(got, ref)
+        c.check(f"paged_attention ({label}) == XLA gather path", err <= tol,
+                f"rel err {err:.2e} (tol {tol})")
+        c.check(f"paged_attention ({label}) length-0 slot is exact zeros",
+                not np.asarray(got[0], np.float32).any())
+
+    # ragged grouped matmul at the FFN shape: uneven groups, one empty,
+    # boundaries off the 128-row tile
+    sizes = jnp.array([1000, 0, 37, 1500, 128, 391, 9, 1031], jnp.int32)
+    n, kdim, m = int(sizes.sum()), WIDTH["hidden_size"], \
+        WIDTH["intermediate_size"]
+    kl, kr, key = jax.random.split(key, 3)
+    lhs = jax.random.normal(kl, (n, kdim), bf16)
+    rhs = jax.random.normal(kr, (sizes.shape[0], kdim, m), bf16)
+    rhs8, scales = (t._data for t in weight_quantize_grouped(rhs))
+    for label, r, s in (("bf16", rhs, None), ("int8 rhs", rhs8, scales)):
+        gmm, is_mosaic = _mosaic(
+            lambda a, b, g, s=s: grouped_matmul(
+                a, b, g, rhs_scales=s, impl="pallas"),
+            lhs, r, sizes,
+        )
+        c.check(f"grouped_matmul ({label}) lowered to tpu_custom_call",
+                is_mosaic)
+        got = gmm(lhs, r, sizes)
+        ref = jax.jit(
+            lambda a, b, g, s=s: grouped_matmul_xla(a, b, g, s)
+        )(lhs, r, sizes)
+        err = _rel_err(got, ref)
+        c.check(f"grouped_matmul ({label}) == XLA segment path", err <= tol,
+                f"rel err {err:.2e} (tol {tol})")
+    c.done()
+
+
+# ----------------------------------------------------------------- train
+def _peak_gib(dev):
+    return dev.memory_stats()["peak_bytes_in_use"] / 2**30
+
+
+def _bytes_in_use(devices):
+    return [d.memory_stats()["bytes_in_use"] for d in devices]
+
+
+def _build_model():
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(**WIDTH, fused_loss_chunk=2048))
+    model.bfloat16()
+    return model
+
+
+class _RepeatedSequence:
+    """Map-style dataset whose every item is the same seeded token row:
+    the loader is exercised and the batch still repeats, so the loss must
+    fall."""
+
+    def __init__(self, n):
+        self._n = n
+        self._row = np.random.RandomState(0).randint(
+            0, WIDTH["vocab_size"], (SEQ,)).astype("int32")
+
+    def __len__(self):
+        return self._n
+
+    def __getitem__(self, i):
+        return self._row
+
+
+def _train(c, model, device_label):
+    """TRAIN_STEPS steps of TrainStep over the forked-worker loader;
+    returns the losses."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.observability import jit_events
+
+    opt = paddle.optimizer.AdamW(
+        learning_rate=3e-4, weight_decay=0.1,
+        parameters=model.parameters(), multi_precision=True,
+    )
+
+    def loss_fn(m, ids):
+        return m(ids, labels=ids)[1]
+
+    step = paddle.jit.TrainStep(model, loss_fn, opt)
+    loader = paddle.io.DataLoader(
+        _RepeatedSequence(TRAIN_BATCH * TRAIN_STEPS),
+        batch_size=TRAIN_BATCH, num_workers=2, use_shared_memory=True,
+    )
+    jit_events.clear_compile_log()
+    losses, walls = [], []
+    for ids in loader:
+        t0 = time.perf_counter()
+        loss = step(ids)
+        jax.block_until_ready(loss._data)
+        walls.append(time.perf_counter() - t0)
+        losses.append(float(loss.numpy()))
+    compiles = [e for e in jit_events.compile_log()
+                if e["kind"] == "train_step"]
+    c.check(f"{TRAIN_STEPS} steps from the forked-worker DataLoader",
+            len(losses) == TRAIN_STEPS, f"losses {losses}")
+    c.check("loss finite and falling",
+            bool(np.all(np.isfinite(losses))) and losses[-1] < losses[0])
+    c.check("exactly one TrainStep compile", len(compiles) == 1,
+            f"{len(compiles)} compile events")
+    say(f"  smoke output ({device_label}): compile+first step "
+        f"{walls[0]:.1f}s, later steps "
+        f"{[round(w * 1e3) for w in walls[1:]]} ms, batch {TRAIN_BATCH} x "
+        f"seq {SEQ}")
+    return losses
+
+
+def phase_train():
+    import jax
+
+    c = Checks("train")
+    dev = jax.devices()[0]
+    model = _build_model()
+    say(f"  model {model.num_params() / 1e6:.1f}M params on {dev}")
+    losses = _train(c, model, str(dev))
+    platforms = {d.platform for p in model.parameters()
+                 for d in p._data.devices()}
+    c.check("parameters live on the TPU", platforms == {"tpu"},
+            str(platforms))
+    say(f"  smoke output: peak_bytes_in_use {_peak_gib(dev):.2f} GiB")
+    c.done()
+    return losses
+
+
+# ----------------------------------------------------------------- serve
+def _requests():
+    """The request set: three unrelated prompts of mixed length (sent
+    concurrently, the long one chunk-prefilled, the middle one streamed)
+    and two that share a prefix (sent in order, so the second meets the
+    first's cached blocks)."""
+    rng = np.random.RandomState(1)
+
+    def toks(n):
+        return [int(t) for t in rng.randint(1, WIDTH["vocab_size"], n)]
+
+    shared = toks(160)
+    return {
+        "short": dict(prompt=toks(24), max_tokens=16),
+        "streamed": dict(prompt=toks(200), max_tokens=24, stream=True),
+        "chunked": dict(prompt=toks(700), max_tokens=12),
+        "prefix_a": dict(prompt=shared + toks(30), max_tokens=12),
+        "prefix_b": dict(prompt=shared + toks(45), max_tokens=12),
+    }
+
+
+def _post(port, body):
+    """One /v1/completions call; (token_ids, finish_reason). A streamed
+    call is reassembled from its SSE chunks."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=600)
+    try:
+        conn.request("POST", "/v1/completions", body=json.dumps(body),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        if resp.status != 200:
+            raise RuntimeError(f"HTTP {resp.status}: {resp.read()!r}")
+        if not body.get("stream"):
+            choice = json.loads(resp.read())["choices"][0]
+            return choice["token_ids"], choice["finish_reason"]
+        streamed, final = [], None
+        for line in resp:
+            line = line.strip()
+            if not line.startswith(b"data: ") or line == b"data: [DONE]":
+                continue
+            final = json.loads(line[len(b"data: "):])["choices"][0]
+            if final.get("finish_reason") is None:
+                streamed += final["token_ids"]
+        if streamed != final["token_ids"]:
+            raise RuntimeError("SSE chunks do not reassemble the final "
+                               f"tokens: {streamed} vs {final['token_ids']}")
+        return final["token_ids"], final["finish_reason"]
+    finally:
+        conn.close()
+
+
+def _serve_requests(engine, reqs):
+    """Serve the request set over HTTP; {name: (tokens, finish_reason)}."""
+    from paddle_tpu.serving import Server
+
+    out = {}
+    srv = Server(engine, port=0)
+    try:
+        threads = [
+            threading.Thread(
+                target=lambda n=n: out.__setitem__(n, _post(srv.port, reqs[n]))
+            )
+            for n in ("short", "streamed", "chunked")
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for n in ("prefix_a", "prefix_b"):
+            out[n] = _post(srv.port, reqs[n])
+    finally:
+        srv.close()
+    return out
+
+
+_PROBES = ("prefill_compiles", "prefill_ext_compiles", "decode_compiles",
+           "cow_compiles", "verify_compiles")
+
+
+def _serve(c, model, cache_dir, label, **engine_kw):
+    """One engine, then a second over the same AOT directory. The store
+    may already be warm from an earlier process: the first engine then
+    compiles nothing either, and the checks say so."""
+    from paddle_tpu.kernels.pallas import _compat
+    from paddle_tpu.serving import Engine, EngineConfig
+
+    def build():
+        t0 = time.perf_counter()
+        eng = Engine(model, EngineConfig(
+            enable_prefix_cache=True, prefill_chunk_tokens=256,
+            compile_cache=cache_dir, **engine_kw,
+        ))
+        return eng, time.perf_counter() - t0
+
+    reqs = _requests()
+    first, first_s = build()
+    cc = first._cc.metrics
+    store_was_cold = cc.misses > 0
+    first_out = _serve_requests(first, reqs)
+    for name, (tokens, reason) in first_out.items():
+        c.check(f"{label} request {name!r} finished {reason!r} with "
+                f"{len(tokens)} tokens",
+                reason in ("stop", "length")
+                and len(tokens) == reqs[name]["max_tokens"])
+    m = first.metrics
+    c.check(f"{label} decode ran from at most one compile",
+            m.decode_compiles <= 1 and m.decode_steps > 0,
+            f"decode_compiles={m.decode_compiles} over {m.decode_steps} "
+            f"steps, AOT store was {'cold' if store_was_cold else 'warm'}")
+    c.check(f"{label} prefix cache hit on the shared prefix",
+            m.prefix_hit_tokens > 0, f"{m.prefix_hit_tokens} tokens")
+    c.check(f"{label} chunked prefill ran", m.prefill_chunks > 0,
+            f"{m.prefill_chunks} chunks")
+    c.check(f"{label} block manager drained",
+            first.block_manager.num_used == len(first.prefix_cache)
+            and first.health()["kv_active_utilization"] == 0.0,
+            f"used={first.block_manager.num_used} "
+            f"cached={len(first.prefix_cache)}")
+
+    second, second_s = build()
+    second_out = _serve_requests(second, reqs)
+    probes = {p: getattr(second.metrics, p) for p in _PROBES}
+    c.check(f"{label} second engine: every compile probe 0",
+            not any(probes.values()), str(probes))
+    c.check(f"{label} second engine: identical outputs",
+            second_out == first_out)
+    c.check("no kernel or compile-cache fallback",
+            _compat.fallbacks_total() == 0 and cc.fallbacks == 0
+            and cc.store_errors == 0,
+            f"kernel fallbacks={_compat.fallbacks_total()} cache "
+            f"fallbacks={cc.fallbacks} store_errors={cc.store_errors} "
+            f"hits={cc.hits} misses={cc.misses}")
+    say(f"  smoke output ({label}): first engine build {first_s:.1f}s "
+        f"({'compiled' if store_was_cold else 'loaded'} "
+        f"{len(first.config.prefill_buckets)} prefill buckets), second "
+        f"engine {second_s:.1f}s")
+    return first, first_out
+
+
+def phase_serve(cache_root):
+    import jax
+
+    import paddle_tpu as paddle
+
+    c = Checks("serve")
+    model = _build_model()
+    model.eval()
+    engine, out = _serve(c, model, os.path.join(cache_root, "aot", "tp1"),
+                         "tp=1")
+    decode_hlo = engine._ensure_program("decode", any_sample=False).as_text()
+    c.check("decode program holds the Pallas paged-attention custom call",
+            "tpu_custom_call" in decode_hlo)
+    short = _requests()["short"]
+    ref = model.generate(
+        paddle.to_tensor(np.asarray([short["prompt"]], "int64")),
+        max_new_tokens=short["max_tokens"],
+    ).numpy()[0, len(short["prompt"]):].tolist()
+    c.check("greedy request == one-at-a-time model.generate",
+            out["short"][0] == ref, f"engine {out['short'][0]} ref {ref}")
+    dev = jax.devices()[0]
+    say(f"  served on {dev}; smoke output: peak_bytes_in_use "
+        f"{_peak_gib(dev):.2f} GiB")
+    c.done()
+    return out
+
+
+# ------------------------------------------------------------ four chips
+def phase_four_chips(cache_root, one_chip_losses, one_chip_out):
+    import jax
+
+    import paddle_tpu.distributed as dist
+
+    c = Checks("four chips")
+    devices = jax.devices()[:4]
+
+    model = _build_model()
+    model, _ = dist.parallelize(
+        model, None, config={"dp_degree": 2, "mp_degree": 2})
+    losses = _train(c, model, "dp=2 x mp=2")
+    weight = dict(model.named_parameters())[
+        "llama.layers.0.self_attn.q_proj.weight"]._data
+    c.check("a weight is sharded over all four devices",
+            weight.sharding.device_set == set(devices)
+            and not weight.sharding.is_fully_replicated,
+            str(weight.sharding))
+    c.check("first-step loss == one-chip first-step loss",
+            abs(losses[0] - one_chip_losses[0]) <= 2e-2 * one_chip_losses[0],
+            f"{losses[0]:.4f} vs {one_chip_losses[0]:.4f}")
+    in_use = _bytes_in_use(devices)
+    c.check("every device holds training state", all(in_use),
+            f"bytes_in_use {in_use}")
+    del model, weight
+    gc.collect()
+
+    model = _build_model()
+    model.eval()
+    engine, out = _serve(c, model, os.path.join(cache_root, "aot", "tp4"),
+                         "tp=4", tp_degree=4)
+    pool = engine.pool.k[0]
+    pool = pool[0] if isinstance(pool, (tuple, list)) else pool
+    c.check("the KV pool is sharded over all four devices",
+            pool.sharding.device_set == set(devices)
+            and not pool.sharding.is_fully_replicated, str(pool.sharding))
+    in_use = _bytes_in_use(devices)
+    c.check("every device holds serving state", all(in_use),
+            f"bytes_in_use {in_use}")
+    same = {n: out[n] == one_chip_out[n] for n in out}
+    c.check("tp=4 outputs == one-chip outputs", all(same.values()),
+            str(same))
+    c.done()
+
+
+def main():
+    device, cache_root = phase_device()
+    phase_kernels()
+    losses = phase_train()
+    gc.collect()
+    out = phase_serve(cache_root)
+    gc.collect()
+    if device["count"] >= 4:
+        phase_four_chips(cache_root, losses, out)
+    say(json.dumps({"ok": True, "device": device, "claim": None}))
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -28,6 +28,7 @@ import textwrap
 from dataclasses import dataclass, field
 
 import jax
+from jax.extend import core as jex_core
 
 from .findings import AnalysisError, Finding, Severity
 from .trace import TraceResult, fn_location, frame_of_eqn
@@ -71,9 +72,9 @@ def _walk_eqns(jaxpr, in_loop=False):
 def _sub_jaxprs(eqn):
     for v in eqn.params.values():
         for item in v if isinstance(v, (list, tuple)) else (v,):
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jex_core.Jaxpr):
                 yield item
 
 
@@ -269,8 +270,8 @@ def _const_bloat(ctx):
     if closed is None:
         return
     file, line = ctx.trace.fn_file, ctx.trace.fn_line
-    for var, val in zip(closed.jaxpr.constvars, closed.consts):
-        nbytes = getattr(val, "nbytes", 0)
+    for var in closed.jaxpr.constvars:
+        nbytes = var.aval.size * var.aval.dtype.itemsize
         if nbytes >= ctx.const_bloat_bytes:
             yield Finding(
                 rule="const-bloat",
@@ -334,11 +335,11 @@ def _donation_misuse(ctx):
     used = set()
     for eqn, _ in _walk_eqns(tr.closed.jaxpr):
         used.update(
-            id(v) for v in eqn.invars if not isinstance(v, jax.core.Literal)
+            id(v) for v in eqn.invars if not isinstance(v, jex_core.Literal)
         )
     used.update(
         id(v) for v in tr.closed.jaxpr.outvars
-        if not isinstance(v, jax.core.Literal)
+        if not isinstance(v, jex_core.Literal)
     )
     for argnum in sorted(donated):
         invars = [
@@ -367,7 +368,7 @@ def _dead_output(ctx):
         return
     jaxpr = closed.jaxpr
     live = {
-        id(v) for v in jaxpr.outvars if not isinstance(v, jax.core.Literal)
+        id(v) for v in jaxpr.outvars if not isinstance(v, jex_core.Literal)
     }
     prefer = ctx.trace.prefer_file
     dead = []
@@ -379,7 +380,7 @@ def _dead_output(ctx):
         if keep:
             live.update(
                 id(v) for v in eqn.invars
-                if not isinstance(v, jax.core.Literal)
+                if not isinstance(v, jex_core.Literal)
             )
         else:
             dead.append(eqn)

@@ -99,7 +99,7 @@ class TraceResult:
     broke on a host sync), the innermost python function, argument
     bookkeeping for donation checks, and the break finding if any."""
 
-    closed: object = None          # jax.core.ClosedJaxpr | None
+    closed: object = None          # jax.extend.core.ClosedJaxpr | None
     fn: object = None              # innermost callable
     fn_file: str | None = None
     fn_line: int | None = None

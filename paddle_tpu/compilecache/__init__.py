@@ -42,6 +42,8 @@ import threading
 import time
 import weakref
 
+import jax
+
 from .aot import (
     AOTUnavailableError,
     abstractify,
@@ -58,11 +60,46 @@ from .store import ArtifactStore, CacheCorruptError
 __all__ = [
     "CompileCache", "CacheMetrics", "ArtifactStore", "WarmupManifest",
     "CacheCorruptError", "AOTUnavailableError", "resolve",
+    "cache_root", "enable_persistent_cache",
     "content_key", "env_fingerprint", "signature_str", "abstractify",
     "code_fingerprint", "serialize_compiled", "deserialize_compiled",
 ]
 
 _EXEC_BLOB = "exec"
+
+_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def cache_root():
+    """The one directory everything this framework caches lives under:
+    JAX's persistent compilation cache (flat, at the top), the AOT
+    executable store a caller places below it
+    (``EngineConfig(compile_cache=os.path.join(cache_root(), ...))``)
+    and ``io.native``'s built library. ``JAX_COMPILATION_CACHE_DIR``
+    when the environment sets it, else ``<checkout>/.jax_cache`` — a
+    fixed path, because the path is part of what a later process must
+    find again."""
+    return os.path.abspath(
+        os.environ.get(_CACHE_DIR_ENV)
+        or os.path.join(_CHECKOUT, ".jax_cache")
+    )
+
+
+def enable_persistent_cache():
+    """Point JAX's persistent compilation cache at :func:`cache_root`
+    and keep every program, however fast it compiled (the serving
+    programs compile in well under jax's default one-second floor).
+    Call before the first compile. Where the environment names the
+    directory jax has already read it, and nothing is set in code.
+    Returns the root."""
+    root = cache_root()
+    if not os.environ.get(_CACHE_DIR_ENV):
+        jax.config.update("jax_compilation_cache_dir", root)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return root
 
 # monotonic ids for metric labels (same rationale as the engine/fleet
 # counters: a re-created cache over the same dir must not alias a

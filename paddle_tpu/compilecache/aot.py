@@ -12,12 +12,19 @@ compile-count probes (``EngineMetrics.*_compiles``,
 
 Key derivation is content addressing over *(fn name, abstract
 signature, environment fingerprint)*: the fingerprint pins the jax /
-jaxlib / backend / framework versions, so an upgraded process simply
-misses (and re-populates) rather than loading an executable built for a
-different runtime. The fingerprint is ALSO recorded in each artifact's
-metadata and re-checked at load — a copied or hand-edited artifact
-whose recorded environment disagrees with the running one is treated as
-stale, never executed.
+jaxlib / backend / ``device_kind`` / framework versions, so an upgraded
+process — or the same cache directory met by another chip generation —
+simply misses (and re-populates) rather than loading an executable
+built for a different runtime. The fingerprint is ALSO recorded in each
+artifact's metadata and re-checked at load — a copied or hand-edited
+artifact whose recorded environment disagrees with the running one is
+treated as stale, never executed.
+
+Device binding: a serialized executable is bound to the devices it was
+compiled for. Their ids travel in the pickle frame and are handed back
+to ``deserialize_and_load(execution_devices=)`` at load; left to its
+default, jax loads onto EVERY device of the backend and a one-device
+program then fails at execute on a multi-device host.
 
 Serialized artifacts are pickle-based (jax's executable serialization
 uses pickle for the pytree defs): a cache directory is TRUSTED INPUT,
@@ -38,7 +45,7 @@ __all__ = [
     "AOTUnavailableError",
 ]
 
-EXEC_FORMAT = "pjrt-exec-pickle-v1"
+EXEC_FORMAT = "pjrt-exec-pickle-v2"
 
 
 class AOTUnavailableError(RuntimeError):
@@ -72,6 +79,7 @@ def env_fingerprint():
         "jax": jax.__version__,
         "jaxlib": getattr(jaxlib, "__version__", "unknown"),
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "framework": framework_version,
         "python": platform.python_version(),
         "exec_format": EXEC_FORMAT,
@@ -180,8 +188,11 @@ def serialize_compiled(compiled):
             f"backend {jax.default_backend()!r} cannot serialize "
             f"compiled executables: {type(e).__name__}: {e}"
         ) from e
+    device_ids = [
+        d.id for d in compiled.runtime_executable().local_devices()
+    ]
     buf = io.BytesIO()
-    pickle.dump((payload, in_tree, out_tree), buf,
+    pickle.dump((payload, in_tree, out_tree, device_ids), buf,
                 protocol=pickle.HIGHEST_PROTOCOL)
     return buf.getvalue()
 
@@ -195,5 +206,9 @@ def deserialize_compiled(data):
         deserialize_and_load,
     )
 
-    payload, in_tree, out_tree = pickle.loads(data)
-    return deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(data)
+    by_id = {d.id: d for d in jax.devices()}
+    return deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids],
+    )

@@ -17,14 +17,19 @@ _DEFAULT_SEED = 34342423252
 
 
 class Generator:
+    """The key is built on first use, not at construction: building it
+    initialises the JAX backend, and ``import paddle_tpu`` (which makes
+    ``default_generator``) must leave the accelerator unclaimed so a
+    launcher parent can hand it to its worker."""
+
     def __init__(self, seed: int | None = None):
         self._lock = threading.Lock()
         self.manual_seed(seed if seed is not None else _DEFAULT_SEED)
 
     def manual_seed(self, seed: int):
-        with getattr(self, "_lock", threading.Lock()):
+        with self._lock:
             self._seed = int(seed)
-            self._key = jax.random.key(int(seed) % (2**63))
+            self._key = None
         return self
 
     seed = manual_seed
@@ -32,17 +37,25 @@ class Generator:
     def initial_seed(self) -> int:
         return self._seed
 
+    def _ensure_key(self):
+        # caller holds self._lock
+        if self._key is None:
+            self._key = jax.random.key(self._seed % (2**63))
+        return self._key
+
     def split_key(self):
         """Return a fresh subkey, advancing the generator state."""
         with self._lock:
-            self._key, sub = jax.random.split(self._key)
+            self._key, sub = jax.random.split(self._ensure_key())
             return sub
 
     def get_state(self):
-        return jax.random.key_data(self._key)
+        with self._lock:
+            return jax.random.key_data(self._ensure_key())
 
     def set_state(self, state):
-        self._key = jax.random.wrap_key_data(np.asarray(state))
+        with self._lock:
+            self._key = jax.random.wrap_key_data(np.asarray(state))
 
 
 default_generator = Generator()

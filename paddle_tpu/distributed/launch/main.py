@@ -25,6 +25,7 @@ import subprocess
 import sys
 import time
 
+from ...compilecache import cache_root
 from ...resilience.train_state import HANG_EXIT_CODE, PREEMPT_EXIT_CODE
 
 __all__ = ["launch"]
@@ -103,6 +104,10 @@ def _worker_env(args, local_rank, node_rank=None, nnodes=None,
         env["JAX_PROCESS_ID"] = str(rank)
     if args.devices:
         env["TPU_VISIBLE_DEVICES"] = args.devices
+    # the worker is the user's script: hand it the compile-cache root
+    # through the variables jax itself reads at import
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_root()
+    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     return env
 
 

@@ -202,10 +202,22 @@ class _ShardedInputModel:
     def __call__(self, *args, **kwargs):
         import jax
 
+        # function-scoped like every pallas import: plain
+        # `import paddle_tpu` must not load the TPU kernel stack
+        from ..kernels.pallas._compat import spmd_axes
+
         is_t = lambda v: isinstance(v, Tensor)  # noqa: E731
         args = jax.tree_util.tree_map(self._shard_in, args, is_leaf=is_t)
         kwargs = jax.tree_util.tree_map(self._shard_in, kwargs, is_leaf=is_t)
-        return self._model(*args, **kwargs)
+        names = self.mesh.dim_names
+        # tell the Pallas kernels traced below which mesh axes the batch
+        # and head dims ride on (Mosaic kernels have no SPMD rule)
+        with spmd_axes(
+            self.mesh.jax_mesh(),
+            batch_axis="dp" if "dp" in names else None,
+            head_axis="tp" if "tp" in names else None,
+        ):
+            return self._model(*args, **kwargs)
 
     def __getattr__(self, name):
         return getattr(self._model, name)

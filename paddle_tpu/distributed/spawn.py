@@ -35,6 +35,19 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     when join=False; raises if any worker fails."""
     if nprocs <= 0:
         nprocs = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
+    from jax._src import xla_bridge
+
+    from ..core.device import on_tpu
+
+    if xla_bridge.backends_are_initialized() and on_tpu():
+        # a chip belongs to one process: forked workers of a parent that
+        # holds it would fail or hang at their first jax call
+        raise RuntimeError(
+            "distributed.spawn cannot fork workers from a process that "
+            "already holds the TPU; start them with `python -m "
+            "paddle_tpu.distributed.launch` (its parent stays off JAX) "
+            "or call spawn before this process touches JAX"
+        )
     ctx = _mp.get_context("fork")
     err_q = ctx.Queue()
     procs = [

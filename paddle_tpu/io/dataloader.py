@@ -179,7 +179,7 @@ def _shm_pack(tree):
     SharedMemory blocks and described by (name, shape, dtype) — the
     pickle-free transport of the reference's shm tensors
     (io/dataloader/worker.py:418 _convert_to_tensor_list analogue)."""
-    from multiprocessing import shared_memory
+    from multiprocessing import resource_tracker, shared_memory
 
     shms = []
 
@@ -190,6 +190,11 @@ def _shm_pack(tree):
             shm = shared_memory.SharedMemory(
                 create=True, size=max(1, v.nbytes)
             )
+            # ownership passes to the consumer, which unlinks. Left
+            # registered, the block is unlinked by this worker's resource
+            # tracker when the worker exits — before a slow consumer (a
+            # training step that is still compiling) has opened it
+            resource_tracker.unregister(shm._name, "shared_memory")
             dst = np.ndarray(v.shape, v.dtype, buffer=shm.buf)
             dst[...] = v
             shms.append(shm)

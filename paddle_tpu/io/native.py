@@ -2,10 +2,11 @@
 
 ref: §2.14 #30 — the reference's C++ data_feed/data_set/data_loader core.
 The .so is built on first use with the baked-in g++ (pybind11 is not in
-this image; plain C ABI + ctypes instead) into a per-user cache directory,
-keyed on a content hash of the source — never committed, never stale after
-a clone, and safe across machines (no -march=native). Every entry point
-has a numpy fallback so the framework works without a compiler.
+this image; plain C ABI + ctypes instead) under the framework's cache root
+(``compilecache.cache_root()/native``), keyed on a content hash of the
+source — never committed, never stale after a clone, and safe across
+machines (no -march=native). Every entry point has a numpy fallback so the
+framework works without a compiler; a failed build says so once on stderr.
 """
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 import threading
 
@@ -31,11 +33,9 @@ _build_failed = False
 
 
 def _cache_dir():
-    base = os.environ.get("PADDLE_TPU_CACHE") or os.path.join(
-        os.environ.get("XDG_CACHE_HOME")
-        or os.path.join(os.path.expanduser("~"), ".cache"),
-        "paddle_tpu",
-    )
+    from ..compilecache import cache_root
+
+    base = os.path.join(cache_root(), "native")
     os.makedirs(base, exist_ok=True)
     return base
 
@@ -86,8 +86,14 @@ def _load():
                 ctypes.c_void_p,
             ]
             _lib = lib
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
             _build_failed = True
+            detail = getattr(e, "stderr", b"") or b""
+            sys.stderr.write(
+                "[io.native] libdatafeed did not build or load "
+                f"({type(e).__name__}: {e}); the numpy paths are used "
+                f"instead. {detail.decode(errors='replace')[-400:]}\n"
+            )
     return _lib
 
 

@@ -1,58 +1,55 @@
-"""Shared runtime for the Pallas TPU kernels: jax compat + dispatch.
+"""Shared runtime for the Pallas TPU kernels: dispatch + route accounting.
 
-Three concerns every kernel in this package routes through, instead of
-per-file version sniffing and ad-hoc interpret checks:
+Three concerns every kernel in this package routes through:
 
-  * ``CompilerParams`` — jax renamed ``pltpu.TPUCompilerParams`` to
-    ``pltpu.CompilerParams``; resolve whichever this jax exposes once,
-    and fail loudly at import time (not at first kernel call) if
-    neither exists. Audited against the current pin (jax 0.4.37 ships
-    ``TPUCompilerParams``; newer jax ships ``CompilerParams``).
-  * ``pl_call()`` — the one ``pl.pallas_call`` wrapper: interpret-mode
-    autoselect off-TPU (so CPU tier-1 exercises the same kernel code
-    path the TPU compiles) and ``dimension_semantics`` threading
-    through the resolved CompilerParams class.
-  * ``record_fallback()`` — kernel-path observability: every time a
-    Pallas hot path degrades to its XLA fallback (unsupported backend,
-    shape, or dtype) the degradation is counted in
+  * ``pl_call()`` — the one ``pl.pallas_call`` wrapper. On a TPU the
+    kernel is compiled by Mosaic; off-TPU it runs under the Pallas
+    interpreter (``interpret_mode()``), so CPU tier-1 exercises the same
+    kernel body. There is no third mode: a kernel Mosaic refuses raises
+    from the enclosing ``jit`` compile — nothing here catches it.
+  * ``spmd_axes()`` — Mosaic kernels "cannot be automatically
+    partitioned": in a sharded program the code that knows the mesh (the
+    ``dist.parallelize`` wrapper, a tensor-parallel serving adapter)
+    declares which mesh axes the batch and head dims ride on, and a
+    kernel that is independent along those dims wraps itself in
+    ``shard_map`` over them (``flash_attention``). A sharded program that
+    reaches a kernel without the declaration fails in the lowering with
+    jax's own "wrap the call in a shard_map" error.
+  * ``record_fallback()`` — an EXPLICIT Pallas request that the backend
+    cannot honour off-TPU (``decode_kernel="pallas"`` on the CPU mesh
+    without ``FLAGS_pallas_interpret``) is counted in
     ``paddle_tpu_kernels_fallbacks_total{kernel,reason}`` and warned
-    once per (kernel, reason). Degradation never raises; the counter is
-    best-effort (a broken metrics registry must not take down a
-    launch).
+    once per (kernel, reason). The ``"auto"`` routes never count: they
+    select a path from what they can observe (backend, shape, dtype,
+    sharding), and on a TPU the counter staying at 0 is what
+    ``chip_smoke.py`` asserts.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 import warnings
 
-import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if CompilerParams is None:  # pragma: no cover - future-jax guard
-    raise ImportError(
-        f"jax {jax.__version__}: neither pallas.tpu.CompilerParams nor "
-        "TPUCompilerParams exists; update kernels/pallas/_compat.py for "
-        "this jax version"
-    )
+from ...core.device import on_tpu
 
 
 def interpret_mode():
     """True off-TPU: kernels run under the Pallas interpreter so the
     same kernel body is testable on the CPU mesh."""
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def pl_call(kernel, *, dimension_semantics=None, interpret=None,
             compiler_params=None, **kwargs):
     """``pl.pallas_call`` with the package-wide defaults applied:
     interpret-mode autoselect (``interpret=None``) and
-    ``dimension_semantics`` routed through the version-resolved
-    CompilerParams class. Any explicit ``compiler_params`` wins."""
+    ``dimension_semantics`` routed through ``pltpu.CompilerParams``.
+    Any explicit ``compiler_params`` wins."""
     if compiler_params is None and dimension_semantics is not None:
-        compiler_params = CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=tuple(dimension_semantics)
         )
     if interpret is None:
@@ -63,31 +60,49 @@ def pl_call(kernel, *, dimension_semantics=None, interpret=None,
     )
 
 
+_spmd = threading.local()
+
+
+@contextlib.contextmanager
+def spmd_axes(mesh, batch_axis=None, head_axis=None):
+    """Declare, for the code traced inside, the ``jax.sharding.Mesh`` and
+    the names of the mesh axes that shard attention's batch and head dims
+    (None = that dim is whole on every device)."""
+    prev = getattr(_spmd, "axes", None)
+    _spmd.axes = (mesh, batch_axis, head_axis)
+    try:
+        yield
+    finally:
+        _spmd.axes = prev
+
+
+def current_spmd_axes():
+    """``(mesh, batch_axis, head_axis)`` of the enclosing ``spmd_axes``,
+    or None in a single-device program."""
+    return getattr(_spmd, "axes", None)
+
+
 # (kernel, reason) pairs already warned about — the counter moves on
 # every degradation, the warning fires once per pair per process
 _warned_fallbacks = set()
 
 
-def record_fallback(kernel, reason, hint=None):
-    """A Pallas path degraded to its XLA fallback. Count it (always)
-    and warn (once per (kernel, reason)); NEVER raise — degradation is
-    the contract, the fallback produces the same math. ``hint`` lets
-    the caller append remediation that actually applies to ITS
-    degradation (e.g. the interpret flag for an off-backend serving
-    request)."""
-    try:
-        from ...observability import counter
+def _fallback_counter():
+    from ...observability import counter
 
-        counter(
-            "paddle_tpu_kernels_fallbacks_total",
-            "Pallas kernel launches degraded to the XLA fallback",
-            labelnames=("kernel", "reason"),
-        ).inc(kernel=kernel, reason=reason)
-    except Exception:
-        # analysis: allow(broad-except) fallback telemetry is
-        # best-effort: a broken metrics registry must not take down the
-        # launch that is already degrading gracefully
-        pass
+    return counter(
+        "paddle_tpu_kernels_fallbacks_total",
+        "Pallas kernel launches degraded to the XLA fallback",
+        labelnames=("kernel", "reason"),
+    )
+
+
+def record_fallback(kernel, reason, hint=None):
+    """An explicitly requested Pallas path ran its XLA fallback instead.
+    Count it (always) and warn (once per (kernel, reason)). ``hint``
+    lets the caller append remediation that applies to ITS degradation
+    (e.g. the interpret flag for an off-backend serving request)."""
+    _fallback_counter().inc(kernel=kernel, reason=reason)
     if (kernel, reason) not in _warned_fallbacks:
         _warned_fallbacks.add((kernel, reason))
         msg = (
@@ -101,17 +116,5 @@ def record_fallback(kernel, reason, hint=None):
 
 def fallbacks_total():
     """Current total of the degradation counter (test/diagnostic
-    accessor); 0 when the registry is unavailable."""
-    try:
-        from ...observability import counter
-
-        c = counter(
-            "paddle_tpu_kernels_fallbacks_total",
-            "Pallas kernel launches degraded to the XLA fallback",
-            labelnames=("kernel", "reason"),
-        )
-        return sum(child.value for _, child in c._series())
-    except Exception:
-        # analysis: allow(broad-except) same best-effort contract as
-        # record_fallback above
-        return 0
+    accessor)."""
+    return sum(child.value for _, child in _fallback_counter()._series())

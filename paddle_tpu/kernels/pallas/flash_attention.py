@@ -25,8 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from ._compat import pl_call
+from ._compat import current_spmd_axes, pl_call
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -334,13 +335,30 @@ def _flash_core_bwd(scale, causal, block_q, block_k, res, do):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+def _flash_4d(q, k, v, scale, causal, block_q, block_k):
+    """[b, s, h, d] in and out around the [b*h, s, d] kernel layout."""
+    b, sq, h, d = q.shape
+
+    def _merge(x):
+        return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
+
+    out = _flash_core(_merge(q), _merge(k), _merge(v), scale, causal,
+                      block_q, block_k)
+    return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2)
+
+
 def flash_attention(q, k, v, *, causal=True, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
     """q/k/v: [batch, seq, heads, head_dim] -> same-shape output.
 
     Requirements: no attention mask (causal flag instead), no dropout —
-    callers fall back to the math sdpa otherwise (nn_ops dispatch)."""
-    b, sq, h, d = q.shape
+    callers fall back to the math sdpa otherwise (nn_ops dispatch).
+
+    Inside a sharded program the caller declares the mesh axes of the
+    batch and head dims with ``_compat.spmd_axes`` and the kernel runs
+    per shard under ``shard_map``: Mosaic kernels have no automatic SPMD
+    rule, and attention is independent per batch row and per head."""
+    sq, d = q.shape[1], q.shape[3]
     sk = k.shape[1]
     # The kernel has no padding mask for partial tail blocks; out-of-range
     # rows/cols would silently attend to block padding.
@@ -352,13 +370,22 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
         )
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-
-    def _merge(x):
-        return (
-            jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], d)
+    fn = functools.partial(
+        _flash_4d, scale=float(scale), causal=bool(causal),
+        block_q=int(block_q), block_k=int(block_k),
+    )
+    axes = current_spmd_axes()
+    if axes is not None:
+        mesh, batch_axis, head_axis = axes
+        # an axis that does not divide its dim leaves the dim whole
+        # (computed redundantly along that axis) instead of failing
+        if batch_axis is not None and q.shape[0] % mesh.shape[batch_axis]:
+            batch_axis = None
+        if head_axis is not None and q.shape[2] % mesh.shape[head_axis]:
+            head_axis = None
+        spec = P(batch_axis, None, head_axis, None)
+        fn = jax.shard_map(
+            fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
         )
-
-    qm, km, vm = _merge(q), _merge(k), _merge(v)
-    out = _flash_core(qm, km, vm, float(scale), bool(causal),
-                      int(block_q), int(block_k))
-    return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2)
+    return fn(q, k, v)

@@ -30,10 +30,8 @@ scales, so no dense float copy of the weights ever exists.
 
 Fallback: ``grouped_matmul_xla`` — the same contraction as a pure-XLA
 sort/segment program (tile-aligned segment padding + one batched
-matmul; measured at parity with the capacity-padded dense einsum on
-CPU, where ``jax.lax.ragged_dot`` lowers 3-6x slower). CPU tier-1 runs
-this path, and it is the counted degradation target for unsupported
-shapes/dtypes on TPU. Both paths are differentiable: the custom VJP
+matmul). ``impl="auto"`` takes it off-TPU and for dtypes the kernel
+body does not handle. Both paths are differentiable: the custom VJP
 computes the kernel's grads through the fallback's contraction.
 
 Contract: ``sum(group_sizes) == lhs.shape[0]`` — every row belongs to a
@@ -50,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import interpret_mode, pl_call, record_fallback
+from ...core.device import on_tpu
+from ._compat import pl_call
 
 __all__ = ["grouped_matmul", "grouped_matmul_xla"]
 
@@ -143,7 +142,7 @@ def _gmm_kernel_quant(tile_ref, gid_ref, lo_ref, hi_ref, x_ref, w_ref,
     contrib = jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * s_ref[0][None, :]                       # dequant-in-kernel
+    ) * s_ref[0]                                # [1, tn] dequant
     row = tile * tm + jax.lax.broadcasted_iota(
         jnp.int32, contrib.shape, 0
     )
@@ -182,10 +181,13 @@ def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn):
     ]
     operands = [lhs, rhs]
     if quant:
+        # scales go in as [e, 1, m]: a (1, tn) block over [e, m] breaks
+        # Mosaic's rule that a block's second-to-last dim is a multiple
+        # of 8 or the whole array dim
         in_specs.append(pl.BlockSpec(
-            (1, tn), lambda j, t, tile, gid, lo, hi: (gid[t], j)
+            (1, 1, tn), lambda j, t, tile, gid, lo, hi: (gid[t], 0, j)
         ))
-        operands.append(rhs_scales.astype(jnp.float32))
+        operands.append(rhs_scales.astype(jnp.float32)[:, None, :])
 
     out = pl_call(
         functools.partial(
@@ -295,19 +297,11 @@ def grouped_matmul_xla(lhs, rhs, group_sizes, rhs_scales=None, *,
     return y.reshape(num_tiles * tm, m)[ppos].astype(lhs.dtype)
 
 
-def _pallas_supported(lhs, rhs):
-    """(ok, reason) for the real-TPU kernel; interpret mode (off-TPU)
-    has no tiling constraints."""
-    if lhs.dtype not in (jnp.float32, jnp.bfloat16):
-        return False, "dtype"
-    if rhs.dtype not in (jnp.float32, jnp.bfloat16, jnp.int8):
-        return False, "dtype"
-    if interpret_mode():
-        return True, None
-    k, m = rhs.shape[1], rhs.shape[2]
-    if k % 8 or m % 128:
-        return False, "shape"
-    return True, None
+def _kernel_dtypes(lhs, rhs):
+    """The dtypes the kernel body handles. Shapes are not restricted:
+    every block is (8, 128)-divisible or spans its whole array dim."""
+    return (lhs.dtype in (jnp.float32, jnp.bfloat16)
+            and rhs.dtype in (jnp.float32, jnp.bfloat16, jnp.int8))
 
 
 def grouped_matmul(lhs, rhs, group_sizes, *, rhs_scales=None,
@@ -320,10 +314,10 @@ def grouped_matmul(lhs, rhs, group_sizes, *, rhs_scales=None,
     accumulation on every path).
 
     impl:
-      * ``"auto"`` — the Pallas kernel on TPU (FLAGS_use_pallas_kernels),
-        the XLA ``ragged_dot`` fallback elsewhere; an unsupported
-        shape/dtype on TPU degrades to the fallback (warned + counted in
-        ``paddle_tpu_kernels_fallbacks_total``), never raises.
+      * ``"auto"`` — the Pallas kernel on TPU (FLAGS_use_pallas_kernels)
+        for the dtypes its body handles, the XLA fallback elsewhere. A
+        kernel this route selects and Mosaic refuses raises from the
+        compile; nothing degrades silently.
       * ``"pallas"`` — always the kernel (interpreter off-TPU): the
         parity-testing path.
       * ``"xla"`` — always the fallback.
@@ -339,16 +333,10 @@ def grouped_matmul(lhs, rhs, group_sizes, *, rhs_scales=None,
     if impl == "auto":
         from ...core import flags
 
-        if (jax.default_backend() == "tpu"
-                and flags.get_flag("FLAGS_use_pallas_kernels")):
-            ok, reason = _pallas_supported(lhs, rhs)
-            if ok:
-                impl = "pallas"
-            else:
-                record_fallback("grouped_matmul", reason)
-                impl = "xla"
-        else:
-            impl = "xla"
+        use_pallas = (on_tpu()
+                      and flags.get_flag("FLAGS_use_pallas_kernels")
+                      and _kernel_dtypes(lhs, rhs))
+        impl = "pallas" if use_pallas else "xla"
     if impl == "xla":
         return grouped_matmul_xla(lhs, rhs, group_sizes, rhs_scales)
     if rhs_scales is not None:

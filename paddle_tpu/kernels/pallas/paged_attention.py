@@ -103,8 +103,11 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
 def _decode_kernel_quant(lengths_ref, tables_ref, q_ref, k_ref, v_ref,
                          ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                          scale, page_size):
-    """Int8 variant: dequantize the page in-kernel from its per-token
-    scales before the online-softmax step."""
+    """Int8 variant: dequantize in-kernel from the per-token scales.
+    The scales arrive as [1, page_size] rows, the layout of the score
+    tile's lane axis, so they are applied to the scores and to the
+    probabilities instead of to the pages: ``q·(k8*ks)ᵀ == (q·k8ᵀ)*ks``
+    and ``p·(v8*vs) == (p*vs)·v8`` — no lane-to-sublane relayout."""
     b = pl.program_id(0)
     page = pl.program_id(2)
     n_pages = pl.num_programs(2)
@@ -119,11 +122,12 @@ def _decode_kernel_quant(lengths_ref, tables_ref, q_ref, k_ref, v_ref,
     @pl.when(page * page_size < length)
     def _visit():
         q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         _online_softmax_step(
             q, k, v, m_scr, l_scr, acc_scr,
             scale=scale, page_size=page_size, page=page, length=length,
+            k_scale=ks_ref[0, 0], v_scale=vs_ref[0, 0],
         )
 
     @pl.when(page == n_pages - 1)
@@ -134,11 +138,14 @@ def _decode_kernel_quant(lengths_ref, tables_ref, q_ref, k_ref, v_ref,
 
 
 def _online_softmax_step(q, k, v, m_scr, l_scr, acc_scr, *, scale,
-                         page_size, page, length):
+                         page_size, page, length, k_scale=None,
+                         v_scale=None):
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale  # [group_pad, page_size]
+    if k_scale is not None:
+        s = s * k_scale  # [1, page_size] per-token dequant
 
     # mask cache slots at/after the current length (unwritten tail of
     # the last partially-filled page)
@@ -155,8 +162,9 @@ def _online_softmax_step(q, k, v, m_scr, l_scr, acc_scr, *, scale,
         l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
         l_scr.shape,
     )
+    pv = p if v_scale is None else p * v_scale
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        pv, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -200,7 +208,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         return (h, tabs[b, i], 0, 0)
 
     def sc_map(b, h, i, lens, tabs):
-        return (h, tabs[b, i], 0)
+        return (h, tabs[b, i], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, group_pad, d), q_map),
@@ -210,11 +218,14 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     operands = [qg, k_pages, v_pages]
     if quant:
         kernel = _decode_kernel_quant
+        # scale planes go in as [heads, pages, 1, page_size]: Mosaic
+        # wants a block's last two dims to be (8, 128)-divisible or the
+        # whole array dims, and (1, page_size) is the whole of this view
         in_specs += [
-            pl.BlockSpec((1, 1, page_size), sc_map),
-            pl.BlockSpec((1, 1, page_size), sc_map),
+            pl.BlockSpec((1, 1, 1, page_size), sc_map),
+            pl.BlockSpec((1, 1, 1, page_size), sc_map),
         ]
-        operands += [k_scales, v_scales]
+        operands += [k_scales[:, :, None, :], v_scales[:, :, None, :]]
     else:
         kernel = _decode_kernel
 

@@ -31,13 +31,13 @@ under ``paddle_tpu/observability/``:
              == prefill_tokens + decode_tokens - aborted
       wasted_spec == spec_proposed - spec_accepted
 
-- MFU: achieved flops/s over the sample window divided by a per-backend
-  peak table. Flops-per-token is the PaLM ``2 * N_params`` forward
-  convention derived from the adapter's weight pytree — deliberately
-  architecture-agnostic (required adapter attrs don't include
-  hidden_size). On CPU smoke runs the peak entry is a round
-  placeholder, so treat CPU MFU as a sanity signal, not a benchmark
-  (docs/observability.md).
+- MFU: achieved flops/s over the sample window divided by the chip's
+  published bf16 peak (``core.device.device_peaks``, keyed by
+  ``device_kind``; a TPU that is not in the table raises). Flops-per-
+  token is the PaLM ``2 * N_params`` forward convention derived from the
+  adapter's weight pytree — deliberately architecture-agnostic (required
+  adapter attrs don't include hidden_size). Off-TPU there is no peak and
+  therefore no MFU: the gauge is not exported (docs/observability.md).
 
 Nothing here touches traced code: every hot-path call is host-side
 attribute arithmetic plus one ``LatencyDigest.record`` per launch, and
@@ -55,21 +55,10 @@ from collections import deque
 from .latency import DEFAULT_QUANTILES, LatencyDigest
 
 __all__ = [
-    "PEAK_FLOPS_PER_CHIP",
     "StepStats",
     "flops_per_token",
     "register_stepstats_view",
 ]
-
-# Dense peak FLOP/s per chip by jax backend. The tpu/gpu rows are bf16
-# peaks of the parts the toolchain targets (TPU v4 / A100-class); the
-# cpu row is a deliberately round smoke-test figure so CPU MFU stays a
-# plausibility check rather than pretending to be a measurement.
-PEAK_FLOPS_PER_CHIP = {
-    "tpu": 275e12,
-    "gpu": 312e12,
-    "cpu": 1e11,
-}
 
 # Goodput ledger classes, in export order (label value -> attr).
 LEDGER_CLASSES = (
@@ -116,7 +105,7 @@ class StepStats:
     the same torn-read-tolerant contract as ``EngineMetrics``."""
 
     def __init__(self, adapter=None, tp_degree=1, shard_degree=1,
-                 ring=256, backend=None, peak_flops_per_chip=None):
+                 ring=256, peak_flops_per_chip=None):
         ring = int(ring)
         if ring < 1:
             raise ValueError(f"stepstats ring must be >= 1, got {ring}")
@@ -130,19 +119,12 @@ class StepStats:
         self.flops_per_token = (
             flops_per_token(adapter) if adapter is not None else None
         )
-        if peak_flops_per_chip is None:
-            if backend is None:
-                try:
-                    import jax
-
-                    backend = jax.default_backend()
-                except Exception:  # analysis: allow(broad-except) no jax
-                    backend = "cpu"
-            peak_flops_per_chip = PEAK_FLOPS_PER_CHIP.get(
-                backend, PEAK_FLOPS_PER_CHIP["cpu"]
-            )
-        self.backend = backend
-        self.peak_flops_per_chip = float(peak_flops_per_chip)
+        # None = no published peak for where this runs (the CPU mesh):
+        # mfu() then reports None instead of a made-up utilization
+        self.peak_flops_per_chip = (
+            None if peak_flops_per_chip is None
+            else float(peak_flops_per_chip)
+        )
         # goodput ledger (host-side ints, bumped by the engine hot path)
         self.useful_tokens = 0
         self.wasted_spec_tokens = 0
@@ -261,13 +243,12 @@ class StepStats:
         """Live model-flops-utilization over the sample window: tokens
         computed (useful AND wasted — MFU measures chip work, goodput
         discounts it) times flops-per-token, over the window span,
-        against the per-backend peak. None until a sample exists or
-        when the adapter exposes no weights."""
-        if self.flops_per_token is None or not self.samples:
+        against the chip's published peak. None until a sample exists,
+        when the adapter exposes no weights, or off-TPU (no peak)."""
+        if (self.flops_per_token is None or not self.samples
+                or not self.peak_flops_per_chip):
             return None
         peak = self.peak_flops_per_chip * self.n_chips
-        if peak <= 0:
-            return None
         now = time.time() if now is None else now
         span = max(now - self.samples[0]["ts"], 1e-6)
         toks = sum(s["tokens"] for s in self.samples)
@@ -297,7 +278,6 @@ class StepStats:
             "tokens": self.ledger(),
             "step_ms": step_ms,
             "samples": len(self.samples),
-            "backend": self.backend,
             "flops_per_token": self.flops_per_token,
             "peak_flops_per_chip": self.peak_flops_per_chip,
         }
@@ -357,7 +337,7 @@ def register_stepstats_view(stats, engine_id, registry=None):
             fams.append(MetricFamily(
                 "paddle_tpu_serving_mfu", "gauge",
                 "model flops utilization over the sample window "
-                "(per-backend peak table; CPU entry is a placeholder)",
+                "(published bf16 peak by device_kind; TPU only)",
             ).add(mfu, label))
         return fams
 

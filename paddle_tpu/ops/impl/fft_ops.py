@@ -5,51 +5,10 @@ variants, fftfreq/rfftfreq, fftshift/ifftshift). The reference dispatches
 to cuFFT/onemkl kernels (phi/kernels/funcs/fft.cc); here each op lowers
 to the XLA FFT HLO with the reference's argument contract (n/s size
 padding-or-truncation, axis selection, backward/forward/ortho norm).
-
-TPU caveat: the TPU vector unit has no complex register format and this
-backend rejects complex arrays outright, so on a TPU default backend the
-eager ops execute on the HOST CPU backend (host_fft below): complex
-results stay host-resident, real-valued results are transferred back to
-the accelerator. Inside a TPU-staged program (tracers) there is no host
-to detour through — a clear NotImplementedError replaces the backend's
-opaque UNIMPLEMENTED. On CPU meshes everything, including gradients,
-runs natively.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-
-
-def _complex_ok():
-    return jax.default_backend() != "tpu"
-
-
-def _host_fft(fn):
-    """Run an fft impl on the host CPU when the default backend cannot
-    hold complex arrays; send real-valued outputs back to the device."""
-
-    @functools.wraps(fn)
-    def wrapped(x, **kw):
-        if _complex_ok():
-            return fn(x, **kw)
-        if isinstance(x, jax.core.Tracer):
-            raise NotImplementedError(
-                f"{fn.__name__}: this TPU backend has no complex-number "
-                "support, so fft ops cannot run inside a TPU-staged "
-                "program; call them eagerly (host execution) or stage on "
-                "a CPU mesh"
-            )
-        cpu = jax.devices("cpu")[0]
-        with jax.default_device(cpu):
-            out = fn(jax.device_put(x, cpu), **kw)
-        if jnp.issubdtype(out.dtype, jnp.complexfloating):
-            return out  # complex stays host-resident
-        return jax.device_put(out, jax.devices()[0])
-
-    return wrapped
 
 
 def _norm(norm):
@@ -60,32 +19,26 @@ def _norm(norm):
     return norm
 
 
-@_host_fft
 def fft(x, *, n=None, axis=-1, norm="backward"):
     return jnp.fft.fft(x, n=n, axis=int(axis), norm=_norm(norm))
 
 
-@_host_fft
 def ifft(x, *, n=None, axis=-1, norm="backward"):
     return jnp.fft.ifft(x, n=n, axis=int(axis), norm=_norm(norm))
 
 
-@_host_fft
 def rfft(x, *, n=None, axis=-1, norm="backward"):
     return jnp.fft.rfft(x, n=n, axis=int(axis), norm=_norm(norm))
 
 
-@_host_fft
 def irfft(x, *, n=None, axis=-1, norm="backward"):
     return jnp.fft.irfft(x, n=n, axis=int(axis), norm=_norm(norm))
 
 
-@_host_fft
 def hfft(x, *, n=None, axis=-1, norm="backward"):
     return jnp.fft.hfft(x, n=n, axis=int(axis), norm=_norm(norm))
 
 
-@_host_fft
 def ihfft(x, *, n=None, axis=-1, norm="backward"):
     return jnp.fft.ihfft(x, n=n, axis=int(axis), norm=_norm(norm))
 
@@ -94,45 +47,37 @@ def _axes2(axes):
     return tuple(int(a) for a in axes)
 
 
-@_host_fft
 def fft2(x, *, s=None, axes=(-2, -1), norm="backward"):
     return jnp.fft.fft2(x, s=s, axes=_axes2(axes), norm=_norm(norm))
 
 
-@_host_fft
 def ifft2(x, *, s=None, axes=(-2, -1), norm="backward"):
     return jnp.fft.ifft2(x, s=s, axes=_axes2(axes), norm=_norm(norm))
 
 
-@_host_fft
 def rfft2(x, *, s=None, axes=(-2, -1), norm="backward"):
     return jnp.fft.rfft2(x, s=s, axes=_axes2(axes), norm=_norm(norm))
 
 
-@_host_fft
 def irfft2(x, *, s=None, axes=(-2, -1), norm="backward"):
     return jnp.fft.irfft2(x, s=s, axes=_axes2(axes), norm=_norm(norm))
 
 
-@_host_fft
 def fftn(x, *, s=None, axes=None, norm="backward"):
     axes = None if axes is None else _axes2(axes)
     return jnp.fft.fftn(x, s=s, axes=axes, norm=_norm(norm))
 
 
-@_host_fft
 def ifftn(x, *, s=None, axes=None, norm="backward"):
     axes = None if axes is None else _axes2(axes)
     return jnp.fft.ifftn(x, s=s, axes=axes, norm=_norm(norm))
 
 
-@_host_fft
 def rfftn(x, *, s=None, axes=None, norm="backward"):
     axes = None if axes is None else _axes2(axes)
     return jnp.fft.rfftn(x, s=s, axes=axes, norm=_norm(norm))
 
 
-@_host_fft
 def irfftn(x, *, s=None, axes=None, norm="backward"):
     axes = None if axes is None else _axes2(axes)
     return jnp.fft.irfftn(x, s=s, axes=axes, norm=_norm(norm))

@@ -124,6 +124,9 @@ def main(argv=None):
         # cheap flag validation first, so a bad --api-key fails before
         # the (expensive) model + engine build
         api_keys = _parse_api_keys(args.api_key)
+        from ..compilecache import enable_persistent_cache
+
+        enable_persistent_cache()
         backend = _build_backend(args)
         from .qos import QoSConfig
         from .server import serve as _serve

@@ -23,11 +23,12 @@ dispatch to, so serving numerics match ``generate``'s). Decode attention
 is selected by the adapter's ``decode_kernel`` attribute
 (``EngineConfig(decode_kernel=)`` sets it): ``"auto"`` uses the Pallas
 paged kernel on TPU and the XLA reference path elsewhere; ``"pallas"``
-requests the kernel and DEGRADES to the XLA fallback — warned and
-counted in ``paddle_tpu_kernels_fallbacks_total``, never fatal — when
-the backend/shape/dtype cannot honor it (``FLAGS_pallas_interpret``
-forces the interpreted kernel off-TPU for parity testing); ``"xla"``
-pins the fallback.
+requests the kernel, which off-TPU degrades to the XLA fallback — warned
+and counted in ``paddle_tpu_kernels_fallbacks_total`` — unless
+``FLAGS_pallas_interpret`` pins the interpreted kernel for parity
+testing; ``"xla"`` pins the fallback. On a TPU the selected kernel is
+compiled by Mosaic and a refusal is a compile error, never a silent
+switch of path.
 
 Quantized KV (``EngineConfig(kv_cache_dtype="int8")``): every per-layer
 pool entry is an int8 ``(pages, scales)`` pair. All page writes
@@ -100,17 +101,18 @@ def _paged_attn(q, kp, vp, block_tables, lengths, kernel="auto"):
     # `import paddle_tpu` must not load — nor fail on — the TPU kernel
     # stack; these run at trace time only
     from ..core import flags
+    from ..core.device import on_tpu
     from ..kernels.pallas._compat import record_fallback
     from ..kernels.pallas.paged_attention import (
         paged_attention,
         paged_attention_xla,
     )
 
-    on_tpu = jax.default_backend() == "tpu"
+    tpu = on_tpu()
     if kernel == "pallas":
         # explicit request: off-TPU it degrades (warn + count) unless
         # FLAGS_pallas_interpret pins the interpreted kernel (tests)
-        use_pallas = on_tpu or bool(
+        use_pallas = tpu or bool(
             flags.get_flag("FLAGS_pallas_interpret")
         )
         if not use_pallas:
@@ -120,7 +122,7 @@ def _paged_attn(q, kp, vp, block_tables, lengths, kernel="auto"):
                      "under the Pallas interpreter off-TPU instead",
             )
     elif kernel == "auto":
-        use_pallas = on_tpu and flags.get_flag("FLAGS_use_pallas_kernels")
+        use_pallas = tpu and flags.get_flag("FLAGS_use_pallas_kernels")
     elif kernel == "xla":
         use_pallas = False
     else:
@@ -128,24 +130,10 @@ def _paged_attn(q, kp, vp, block_tables, lengths, kernel="auto"):
             f'decode_kernel must be "auto", "pallas" or "xla", got '
             f"{kernel!r}"
         )
-    if use_pallas and on_tpu:
-        # real-TPU tiling constraints: degrade, never raise (the
-        # fallback computes the same math). Pages tile at
-        # (sublane, 128) with the sublane minimum set by the pool
-        # dtype — f32 8, bf16 16, int8 32.
-        pages, scales = _split_pages(kp)
-        min_sublane = {
-            jnp.dtype(jnp.float32): 8,
-            jnp.dtype(jnp.bfloat16): 16,
-            jnp.dtype(jnp.int8): 32,
-        }.get(jnp.dtype(pages.dtype))
-        if (q.dtype not in (jnp.float32, jnp.bfloat16)
-                or min_sublane is None):
-            record_fallback("paged_attention", "dtype")
-            use_pallas = False
-        elif pages.shape[2] % min_sublane or q.shape[-1] % 128:
-            record_fallback("paged_attention", "shape")
-            use_pallas = False
+    # on a TPU the selected kernel is compiled by Mosaic and a refusal
+    # raises from the decode program's compile: every block of the
+    # kernel spans whole trailing array dims, so no page_size / head_dim
+    # / pool dtype is pre-screened here
     if use_pallas:
         return paged_attention(q, kp, vp, block_tables, lengths)
     return paged_attention_xla(q, kp, vp, block_tables, lengths)
@@ -343,6 +331,17 @@ class LlamaServingAdapter:
         v = (h @ wl["wv"]).reshape(b, s, self.num_kv_heads, self.head_dim)
         return q, k, v
 
+    def _prompt_attention(self, q, k, v):
+        """Causal attention over a whole prompt. At long buckets sdpa
+        routes to the Pallas flash kernel, which under tensor parallelism
+        must be told the head dim rides the ``tp`` axis."""
+        if self.tp_spec is None:
+            return _sdpa(q, k, v, is_causal=True)
+        from ..kernels.pallas._compat import spmd_axes
+
+        with spmd_axes(self.tp_spec.mesh, head_axis="tp"):
+            return _sdpa(q, k, v, is_causal=True)
+
     def _mlp(self, wl, x):
         h = _rms_norm(x, wl["ln2"], epsilon=self.eps)
         return x + _row_matmul(
@@ -376,7 +375,7 @@ class LlamaServingAdapter:
                 v = jnp.repeat(v, rep, axis=2)
             # causal attention over the in-flight prompt; right-padding is
             # invisible to valid queries under causality
-            attn = _sdpa(q, k, v, is_causal=True)
+            attn = self._prompt_attention(q, k, v)
             x = x + _row_matmul(
                 attn.reshape(1, s, -1), wl["wo"], self.tp_spec
             )

@@ -88,6 +88,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.device import on_tpu
 from ..distributed.watchdog import CommTimeoutError, get_comm_watchdog
 from ..jit.bucketing import next_bucket
 from ..observability import flight as _flight
@@ -286,11 +287,11 @@ class EngineConfig:
         # longest trailing n-gram the prompt-lookup drafter matches on
         self.speculate_ngram = int(speculate_ngram)
         # decode attention path (kernels/pallas/paged_attention):
-        # "auto" keeps today's selection (Pallas on TPU under
-        # FLAGS_use_pallas_kernels, XLA elsewhere); "pallas" requests
-        # the kernel — degrading to the XLA fallback with a warning and
-        # a paddle_tpu_kernels_fallbacks_total count when the backend/
-        # shape/dtype cannot honor it, never raising; "xla" pins the
+        # "auto" is Pallas on TPU under FLAGS_use_pallas_kernels, XLA
+        # elsewhere; "pallas" requests the kernel — off-TPU (or under
+        # tp sharding) that degrades to the XLA fallback with a warning
+        # and a paddle_tpu_kernels_fallbacks_total count; on a TPU a
+        # kernel Mosaic refuses is a compile error; "xla" pins the
         # fallback (the byte-reference path)
         if decode_kernel not in ("auto", "pallas", "xla"):
             raise ValueError(
@@ -665,10 +666,15 @@ class Engine:
                 StepStats, register_stepstats_view,
             )
 
+            from ..core.device import device_peaks
+
             self.stepstats = StepStats(
                 adapter=self.adapter, tp_degree=cfg.tp_degree,
                 shard_degree=self.pool.shard_degree,
                 ring=cfg.stepstats_ring,
+                peak_flops_per_chip=(
+                    device_peaks().bf16_flops if on_tpu() else None
+                ),
             )
             register_stepstats_view(self.stepstats, self.engine_id)
         # KV headroom gauge (free + reclaimable blocks): what the
@@ -724,7 +730,7 @@ class Engine:
         adapter, metrics = self.adapter, self.metrics
         # donation keeps the pool single-buffered on TPU; CPU PJRT ignores
         # donation (and warns), so skip it there
-        donate = (1, 2) if jax.default_backend() == "tpu" else ()
+        donate = (1, 2) if on_tpu() else ()
         # poison isolation needs to know whether a failed launch may
         # have consumed the donated pool buffers (see _decode_subset)
         self._pool_donated = bool(donate)

@@ -69,6 +69,12 @@ def _tokens(engine):
     """Greedy token tuples in submission order (the generate
     contract), the bit-parity comparison unit."""
     outs = engine.generate(PROMPTS, SamplingParams(max_new_tokens=6))
+    # a loaded executable that fails at execute comes back as
+    # finish_reason="error" with no tokens: it must not read as
+    # "identical (empty) output"
+    assert all(
+        o.finish_reason != "error" and o.token_ids for o in outs
+    ), [(o.finish_reason, o.token_ids) for o in outs]
     return [tuple(o.token_ids) for o in outs]
 
 

@@ -473,6 +473,30 @@ class TestReviewRegressions:
                              axis=-1)
         assert [list(p.shape) for p in parts] == [[3, 1], [3, 2], [3, 1]]
 
+    def test_bf16_params_and_moments_keep_their_dtype(self):
+        """lr and t enter the update as float32: without master weights a
+        bf16 parameter used to come back float32 after one step, its
+        moments after two, and a staged TrainStep retraced while the
+        dtypes settled (the chip's "slow first steps")."""
+        from paddle_tpu.observability import jit_events
+
+        paddle.seed(0)
+        layer = Linear(8, 8)
+        layer.bfloat16()
+        o = opt.AdamW(learning_rate=1e-2, parameters=layer.parameters())
+        step = paddle.jit.TrainStep(
+            layer, lambda m, x: (m(x) ** 2).mean(), o)
+        x = paddle.to_tensor(np.ones((4, 8), np.float32)).astype("bfloat16")
+        jit_events.clear_compile_log()
+        for _ in range(3):
+            step(x)
+        assert len([e for e in jit_events.compile_log()
+                    if e["kind"] == "train_step"]) == 1
+        for p in layer.parameters():
+            assert p.dtype == paddle.bfloat16
+            assert {str(v.dtype) for v in o._accumulators[id(p)].values()
+                    } == {"bfloat16"}
+
     def test_multiplicative_decay_incremental(self):
         s = opt.lr.MultiplicativeDecay(1.0, lr_lambda=lambda e: 0.5)
         for _ in range(3):

@@ -83,7 +83,7 @@ class TestStepStatsUnit:
         assert flops_per_token(object()) is None
 
     def test_ledger_classes_and_goodput(self):
-        st = StepStats(backend="cpu")
+        st = StepStats()
         assert st.goodput_fraction() == 1.0  # idle engine wastes nothing
         st.begin_step()
         st.note_prefill(10)                      # first-time: useful
@@ -106,7 +106,7 @@ class TestStepStatsUnit:
         """A host-spill restore (serving/spill.py) makes the residual
         prefill real forward progress: cause="restored" lands in
         useful, not preempt_recompute."""
-        st = StepStats(backend="cpu")
+        st = StepStats()
         st.begin_step()
         st.note_prefill(6, cause="restored")
         st.note_prefill(4, cause="preempt")
@@ -115,7 +115,7 @@ class TestStepStatsUnit:
         assert st.wasted_preempt_tokens == 4
 
     def test_idle_step_skipped_but_gauges_refresh(self):
-        st = StepStats(backend="cpu")
+        st = StepStats()
         st.begin_step()
         assert st.end_step(occupancy=0.0, queue_depth=0) is None
         assert not st.samples
@@ -125,7 +125,7 @@ class TestStepStatsUnit:
         assert st.last_occupancy == 0.25 and st.last_queue_depth == 2
 
     def test_host_overhead_split_and_sample_shape(self):
-        st = StepStats(backend="cpu")
+        st = StepStats()
         st.begin_step()
         st.record_launch("prefill", 0.010)
         st.record_launch("decode", 0.005)
@@ -147,7 +147,7 @@ class TestStepStatsUnit:
     def test_mfu_window_deterministic(self):
         st = StepStats(
             adapter=self._fake_adapter(100),   # 200 flops/token
-            tp_degree=2, backend="cpu", peak_flops_per_chip=100.0,
+            tp_degree=2, peak_flops_per_chip=100.0,
         )
         assert st.mfu() is None                # no samples yet
         st.begin_step()
@@ -158,20 +158,20 @@ class TestStepStatsUnit:
         assert st.mfu(now=t0 + 5.0) == pytest.approx(2.0)
 
     def test_ring_bound_and_validation(self):
-        st = StepStats(backend="cpu", ring=4)
+        st = StepStats(ring=4)
         for _ in range(10):
             st.begin_step()
             st.note_decode(1)
             st.end_step(occupancy=1.0)
         assert len(st.samples) == 4
         with pytest.raises(ValueError, match="ring"):
-            StepStats(backend="cpu", ring=0)
+            StepStats(ring=0)
         with pytest.raises(ValueError, match="stepstats_ring"):
             EngineConfig(max_model_len=32, stepstats_ring=0)
 
     def test_view_weakref_unregisters_on_drop(self):
         reg = MetricsRegistry()
-        st = StepStats(backend="cpu")
+        st = StepStats()
         st.begin_step()
         st.note_decode(2)
         st.end_step(occupancy=0.5)
@@ -215,13 +215,18 @@ class TestEngineIntegration:
             "paddle_tpu_serving_occupancy",
             "paddle_tpu_serving_goodput_fraction",
             "paddle_tpu_serving_goodput_tokens_total",
-            "paddle_tpu_serving_mfu",
             "paddle_tpu_serving_kv_headroom_blocks",
         ):
             assert any(
                 line.startswith(family) and eid in line
                 for line in text.splitlines()
             ), family
+        # the CPU mesh has no published peak: no utilization is made up
+        assert st.mfu() is None
+        assert not any(
+            line.startswith("paddle_tpu_serving_mfu") and eid in line
+            for line in text.splitlines()
+        )
 
     def test_goodput_spec_reject_reconciles(self, model, monkeypatch):
         """A forced always-wrong drafter: every proposed token is
@@ -460,5 +465,5 @@ class TestCLI:
         for prog in ("prefill", "decode", "host"):
             assert prog in out
         assert "occupancy=" in out and "goodput=" in out
-        assert "mfu=" in out
+        assert "mfu=" not in out   # no peak off-TPU, so no gauge
         assert f"kv headroom: engine {eid}" in out
