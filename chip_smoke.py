@@ -26,8 +26,9 @@ parameters; weights random from a seed):
 
 Every phase evaluates all of its checks, prints them, and raises if one
 failed; nothing is caught, so the first failed phase ends the run. The last
-stdout line is one JSON object, printed only when every phase passed. The
-timings printed along the way are smoke output, not metrics.
+stdout line is one JSON object with exactly the keys "ok" and "device"
+(platform, kind, count as JAX reports them), printed only when every phase
+passed. The timings printed along the way are smoke output, not metrics.
 
     python3 chip_smoke.py
 """
@@ -538,6 +539,11 @@ def phase_four_chips(cache_root, one_chip_losses, one_chip_out):
     c.done()
 
 
+def result_line(device):
+    """The last stdout line: exactly these keys, which the driver parses."""
+    return json.dumps({"ok": True, "device": device})
+
+
 def main():
     device, cache_root = phase_device()
     phase_kernels()
@@ -547,7 +553,7 @@ def main():
     gc.collect()
     if device["count"] >= 4:
         phase_four_chips(cache_root, losses, out)
-    say(json.dumps({"ok": True, "device": device, "claim": None}))
+    say(result_line(device))
 
 
 if __name__ == "__main__":

@@ -85,6 +85,18 @@ def test_chip_smoke_refuses_the_cpu_before_building_anything():
     assert not any(line.startswith("{") for line in lines)
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys(monkeypatch):
+    """The driver refuses any other key set (an extra "claim" did it once)."""
+    import json
+
+    monkeypatch.syspath_prepend(REPO_ROOT)
+    import chip_smoke
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    assert json.loads(chip_smoke.result_line(device)) == {
+        "ok": True, "device": device}
+
+
 def test_bench_refuses_the_cpu_before_any_row():
     proc = _run(os.path.join(REPO_ROOT, "bench.py"))
     assert proc.returncode != 0
