@@ -23,6 +23,7 @@ import jax
 
 __all__ = [
     "GradNode",
+    "scope",
     "no_grad",
     "enable_grad",
     "is_grad_enabled",
@@ -35,6 +36,7 @@ __all__ = [
 class _GradState(threading.local):
     def __init__(self):
         self.enabled = True
+        self.scope = ()  # names of the enclosing `scope`s, outermost first
 
 
 _state = _GradState()
@@ -79,6 +81,26 @@ def enable_grad(func=None):
     return ctx
 
 
+@contextlib.contextmanager
+def scope(*names):
+    """``jax.named_scope`` that the tape remembers. A device trace names an
+    operation by the scopes it was traced under, but the tape runs every
+    ``vjp_fn`` later, from ``backward()``, outside all of them, and JAX
+    keeps only the part of the name stack that lay inside the ``jax.vjp``.
+    So a ``GradNode`` records the scope path it was created under and
+    ``dispatch.call_vjp`` re-enters it: the forward reads
+    ``attention/jvp()/...`` and its backward
+    ``attention/transpose(jvp())/...``. Costs one tuple read an op and one
+    ``with`` a node, at trace time only under ``jit.TrainStep``."""
+    prev = _state.scope
+    _state.scope = prev + names
+    try:
+        with jax.named_scope("/".join(names)):
+            yield
+    finally:
+        _state.scope = prev
+
+
 class GradNode:
     """One recorded op on the tape.
 
@@ -101,6 +123,7 @@ class GradNode:
         "post_hooks",
         "output_hooks",
         "_cached_vjp",
+        "scope",
     )
 
     def __init__(self, name, vjp_fn, inputs, n_outputs, out_treedef):
@@ -117,6 +140,7 @@ class GradNode:
         self._out_cotangents = None
         self._pending = 0
         self._cached_vjp = False
+        self.scope = _state.scope  # re-entered around the vjp
         self.post_hooks = []
         # (out_index, hook) from register_hook on non-leaf outputs; fired
         # on the fully-accumulated output cotangent before the vjp runs

@@ -356,7 +356,16 @@ def _wrap_in_cots(node, in_cots):
 
 
 def call_vjp(node, cotangents, create_graph=False):
-    """Run a node's vjp. `cotangents`: list (len n_outputs) of Tensor|None.
+    """Run a node's vjp under the ``autograd.scope`` path it was recorded
+    in, so that a device trace files the backward with its forward."""
+    if node.scope:
+        with autograd.scope(*node.scope):
+            return _call_vjp(node, cotangents, create_graph)
+    return _call_vjp(node, cotangents, create_graph)
+
+
+def _call_vjp(node, cotangents, create_graph):
+    """`cotangents`: list (len n_outputs) of Tensor|None.
 
     Fast path uses the residual closure captured at forward time. The
     create_graph path instead re-runs jax.vjp *through the dispatcher* with

@@ -17,9 +17,18 @@ TPU-first host pipeline, two worker transports:
   JAX parent is safe.
 
 num_workers=0 degrades to synchronous iteration.
+
+Every ``next`` of the three iterators is a ``loader.next`` span
+(``batch=``) with three children: ``loader.wait`` (blocked until the
+batch is there; ``ready=`` the batches workers had already delivered
+when ``next`` was called), ``loader.unpack`` (shared memory to arrays,
+or collate) and ``loader.h2d`` (arrays to device Tensors). Worker threads
+collate and place ahead of the consumer, so under THREADS the last two
+are spans of the worker's own thread.
 """
 from __future__ import annotations
 
+import itertools
 import multiprocessing as _mp
 import queue
 import threading
@@ -29,6 +38,7 @@ import traceback
 import numpy as np
 
 from ..core.tensor import Tensor
+from ..observability.spans import span
 from ..resilience import faults
 from .dataset import Dataset, IterableDataset
 from .sampler import BatchSampler
@@ -69,6 +79,14 @@ def _to_device(obj, place=None):
     if isinstance(obj, (list, tuple)):
         return type(obj)(_to_device(v, place) for v in obj)
     return obj
+
+
+def _deliver(collate_fn, items):
+    """Collate one batch and place it, each under its span."""
+    with span("loader.unpack"):
+        batch = collate_fn(items)
+    with span("loader.h2d"):
+        return _to_device(batch)
 
 
 class _Prefetcher:
@@ -138,28 +156,28 @@ class _Prefetcher:
                 return
 
     def __iter__(self):
-        done = 0
-        pending = {}
-        want = 0
-        while True:
-            item = self._q.get()
-            idx, payload = item
-            if payload is self._DONE:
-                done += 1
-                if done == self._n:
-                    # drain any stragglers already produced in order
-                    while want in pending:
-                        yield pending.pop(want)
-                        want += 1
-                    return
-                continue
-            if isinstance(payload, Exception):
-                self.shutdown()
-                raise payload
+        self._done, pending = 0, {}
+        for want in itertools.count():
+            with span("loader.next", batch=want), span(
+                "loader.wait", ready=len(pending) + self._q.qsize()
+            ):
+                # after the last worker is done nothing more comes: what
+                # is pending then is all there is
+                while want not in pending and self._done < self._n:
+                    self._receive(pending)
+            if want not in pending:
+                return
+            yield pending.pop(want)
+
+    def _receive(self, pending):
+        idx, payload = self._q.get()
+        if payload is self._DONE:
+            self._done += 1
+        elif isinstance(payload, Exception):
+            self.shutdown()
+            raise payload
+        else:
             pending[idx] = payload
-            while want in pending:
-                yield pending.pop(want)
-                want += 1
 
     def shutdown(self):
         self._stop.set()
@@ -314,42 +332,59 @@ class _MPLoaderIter:
             self._sent_stop = True
 
     def __iter__(self):
-        done, served, want, pending = 0, 0, 0, {}
+        self._done, pending = 0, {}
         try:
             self._feed(0)
-            while served < self._total:
-                try:
-                    bidx, tag, payload = self._result_q.get(timeout=5.0)
-                except queue.Empty:
-                    # liveness: a worker killed by the OS (OOM/segfault)
-                    # posts nothing; if nobody is left and the queue
-                    # stayed empty through the timeout, nothing will come
-                    if not any(p.is_alive() for p in self._procs):
-                        raise RuntimeError(
-                            "DataLoader workers died before producing "
-                            "all batches (killed by the OS?)"
-                        )
-                    continue
-                if tag == "__done__":
-                    done += 1
-                    if done == self._n and served < self._total:
-                        raise RuntimeError(
-                            "DataLoader workers exited before producing "
-                            "all batches"
-                        )
-                    continue
-                if tag == "__err__":
-                    raise RuntimeError(
-                        f"DataLoader worker failed:\n{payload}"
-                    )
-                pending[bidx] = payload
-                while want in pending:
-                    yield _to_device(_shm_unpack(pending.pop(want)))
-                    want += 1
-                    served += 1
-                    self._feed(served)
+            for want in range(self._total):
+                with span("loader.next", batch=want):
+                    with span("loader.wait") as wait:
+                        # what the workers delivered while the step ran
+                        while self._receive(pending, block=False):
+                            pass
+                        wait.attrs["ready"] = len(pending)
+                        while want not in pending:
+                            if self._done == self._n:
+                                # a worker's batches come before its
+                                # leave: this one is lost
+                                raise RuntimeError(
+                                    "DataLoader workers exited before "
+                                    "producing all batches"
+                                )
+                            self._receive(pending, block=True)
+                    with span("loader.unpack"):
+                        batch = _shm_unpack(pending.pop(want))
+                    with span("loader.h2d"):
+                        batch = _to_device(batch)
+                yield batch
+                self._feed(want + 1)
         finally:
             self.shutdown()
+
+    def _receive(self, pending, block):
+        """One message of the result queue into ``pending``; False when
+        none came (right away if not ``block``, else within 5 s)."""
+        try:
+            bidx, tag, payload = (
+                self._result_q.get(timeout=5.0) if block
+                else self._result_q.get_nowait()
+            )
+        except queue.Empty:
+            # liveness: a worker killed by the OS (OOM/segfault) posts
+            # nothing; if nobody is left and the queue stayed empty
+            # through the timeout, nothing will come
+            if block and not any(p.is_alive() for p in self._procs):
+                raise RuntimeError(
+                    "DataLoader workers died before producing "
+                    "all batches (killed by the OS?)"
+                )
+            return False
+        if tag == "__done__":
+            self._done += 1
+        elif tag == "__err__":
+            raise RuntimeError(f"DataLoader worker failed:\n{payload}")
+        else:
+            pending[bidx] = payload
+        return True
 
     def shutdown(self, grace=None):
         """Stop workers with escalation: SIGTERM, wait out the grace
@@ -558,9 +593,15 @@ class DataLoader:
 
     def _iter_impl(self):
         if self.num_workers == 0:
-            for batch in self._produce():
-                yield _to_device(self.collate_fn(batch))
-            return
+            produced = self._produce()
+            for want in itertools.count():
+                with span("loader.next", batch=want):
+                    with span("loader.wait", ready=0):
+                        items = next(produced, None)
+                    if items is None:
+                        return
+                    batch = _deliver(self.collate_fn, items)
+                yield batch
 
         if self.use_shared_memory and not self._iterable_mode:
             yield from _MPLoaderIter(self)
@@ -571,17 +612,16 @@ class DataLoader:
                 # iterable datasets must be pulled sequentially; workers
                 # parallelize collate + H2D only
                 for batch in self._batches_iterable():
-                    yield (lambda b=batch: _to_device(self.collate_fn(b)))
+                    yield (lambda b=batch: _deliver(self.collate_fn, b))
             else:
                 # map-style: item loading happens INSIDE the job so worker
                 # threads overlap dataset reads (the reference's
                 # multiprocess worker loop, worker.py:293)
                 for indices in self._index_batches():
                     yield (
-                        lambda idx=indices: _to_device(
-                            self.collate_fn(
-                                [self.dataset[i] for i in idx]
-                            )
+                        lambda idx=indices: _deliver(
+                            self.collate_fn,
+                            [self.dataset[i] for i in idx],
                         )
                     )
 

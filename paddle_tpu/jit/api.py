@@ -22,6 +22,7 @@ from ..core import random as random_mod
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer
 from ..observability import jit_events
+from ..observability.spans import span
 
 _NOT_TO_STATIC = set()
 
@@ -660,12 +661,13 @@ class TrainStep:
                 targets = tuple(
                     self._out_shardings[i] for i in live_idx
                 )
-                new_live, new_states = opt_step_fn(
-                    attrs, targets, lr, t, found_inf,
-                    [p._data for p in live],
-                    live_grads,
-                    [states[i] for i in live_idx],
-                )
+                with jax.named_scope("optimizer"):
+                    new_live, new_states = opt_step_fn(
+                        attrs, targets, lr, t, found_inf,
+                        [p._data for p in live],
+                        live_grads,
+                        [states[i] for i in live_idx],
+                    )
                 new_param_arrays = list(param_arrays)
                 out_states = list(states)
                 for j, i in enumerate(live_idx):
@@ -784,12 +786,13 @@ class TrainStep:
                     for i in live_idx
                 ]
                 targets = tuple(self._out_shardings[i] for i in live_idx)
-                new_live, new_states = opt_step_fn(
-                    attrs, targets, lr, t, found_inf,
-                    [params[i]._data for i in live_idx],
-                    live_grads,
-                    [states[i] for i in live_idx],
-                )
+                with jax.named_scope("optimizer"):
+                    new_live, new_states = opt_step_fn(
+                        attrs, targets, lr, t, found_inf,
+                        [params[i]._data for i in live_idx],
+                        live_grads,
+                        [states[i] for i in live_idx],
+                    )
                 new_param_arrays = list(param_arrays)
                 out_states = list(states)
                 for j, i in enumerate(live_idx):
@@ -844,6 +847,41 @@ class TrainStep:
         )
 
     def __call__(self, *args, **kwargs):
+        """One step. Its host side is three spans under ``train_step``
+        (``step=`` the optimizer's global step): ``prepare`` builds the
+        arguments, ``launch`` is the compiled call, which returns before
+        the device is done, ``rebind`` writes the results back."""
+        opt = self._opt
+        with span("train_step", step=opt._global_step + 1):
+            with span("train_step.prepare"):
+                operands = self._prepare(args, kwargs)
+            with span("train_step.launch"), jit_events.watch(
+                getattr(self._loss_fn, "__name__", "train_step"),
+                kind="train_step",
+                signature=f"{self._instance_tok:x}:"
+                f"{hash(self._cur_nan_key) & 0xFFFFFFFF:08x}",
+            ):
+                (new_params, new_buffers, new_states, loss_val, _,
+                 nan_flags) = self._compiled(*operands)
+            with span("train_step.rebind"), autograd.no_grad():
+                for p, a, ns in zip(self._params, new_params, new_states):
+                    p._rebind(a)
+                    p.grad = None
+                    opt._accumulators[id(p)] = ns
+                for b, a in zip(self._buffers, new_buffers):
+                    b._rebind(a)
+                opt._global_step += 1
+        if self._built_nan:
+            # raise AFTER rebinding: the pre-step buffers were donated,
+            # so the new (NaN-carrying but valid) arrays must land on the
+            # params or a caught error leaves the model pointing at
+            # deleted buffers — resume from checkpoint to recover values
+            self._nan_nets[self._cur_nan_key].raise_if(nan_flags)
+        return Tensor(loss_val, stop_gradient=True)
+
+    def _prepare(self, args, kwargs):
+        """The compiled step's operands: optimizer state, the layouts the
+        staged update is constrained to, lr, step, key, argument arrays."""
         opt = self._opt
         if self._compiled is not None and (
             getattr(self, "_built_nan", False) != _nan_check_enabled()
@@ -878,30 +916,8 @@ class TrainStep:
                 if hasattr(a, "shape")
             ),
         )
-        with jit_events.watch(
-            getattr(self._loss_fn, "__name__", "train_step"),
-            kind="train_step",
-            signature=f"{self._instance_tok:x}:"
-            f"{hash(self._cur_nan_key) & 0xFFFFFFFF:08x}",
-        ):
-            (new_params, new_buffers, new_states, loss_val, _,
-             nan_flags) = self._compiled(
-                [p._data for p in self._params],
-                [b._data for b in self._buffers],
-                states, lr, t, found_inf, key, tree_args,
-            )
-        with autograd.no_grad():
-            for p, a, ns in zip(self._params, new_params, new_states):
-                p._rebind(a)
-                p.grad = None
-                opt._accumulators[id(p)] = ns
-            for b, a in zip(self._buffers, new_buffers):
-                b._rebind(a)
-        opt._global_step += 1
-        if self._built_nan:
-            # raise AFTER rebinding: the pre-step buffers were donated,
-            # so the new (NaN-carrying but valid) arrays must land on the
-            # params or a caught error leaves the model pointing at
-            # deleted buffers — resume from checkpoint to recover values
-            self._nan_nets[self._cur_nan_key].raise_if(nan_flags)
-        return Tensor(loss_val, stop_gradient=True)
+        return (
+            [p._data for p in self._params],
+            [b._data for b in self._buffers],
+            states, lr, t, found_inf, key, tree_args,
+        )

@@ -42,12 +42,15 @@ def interpret_mode():
     return not on_tpu()
 
 
-def pl_call(kernel, *, dimension_semantics=None, interpret=None,
+def pl_call(kernel, *, name, dimension_semantics=None, interpret=None,
             compiler_params=None, **kwargs):
     """``pl.pallas_call`` with the package-wide defaults applied:
     interpret-mode autoselect (``interpret=None``) and
     ``dimension_semantics`` routed through ``pltpu.CompilerParams``.
-    Any explicit ``compiler_params`` wins."""
+    Any explicit ``compiler_params`` wins. ``name`` is required: it is
+    the kernel's name in the lowered program and in a device trace
+    (docs/kernels.md lists the five), which a benchmark's reduction finds
+    the kernel's time by."""
     if compiler_params is None and dimension_semantics is not None:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=tuple(dimension_semantics)
@@ -55,8 +58,8 @@ def pl_call(kernel, *, dimension_semantics=None, interpret=None,
     if interpret is None:
         interpret = interpret_mode()
     return pl.pallas_call(
-        kernel, compiler_params=compiler_params, interpret=interpret,
-        **kwargs,
+        kernel, name=name, compiler_params=compiler_params,
+        interpret=interpret, **kwargs,
     )
 
 

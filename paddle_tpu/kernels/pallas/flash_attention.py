@@ -111,6 +111,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             _fwd_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, seq_k=sk,
         ),
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -266,6 +267,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
             _bwd_dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         ),
+        name="flash_attention_bwd_dq",
         grid=(bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -286,6 +288,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k):
             _bwd_dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         ),
+        name="flash_attention_bwd_dkv",
         grid=(bh, pl.cdiv(sk, block_k), pl.cdiv(sq, block_q)),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),

@@ -193,6 +193,7 @@ def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn):
         functools.partial(
             kernel, tm=tm, n_items=n_items, quant=quant,
         ),
+        name="grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(num_col_tiles, n_items),

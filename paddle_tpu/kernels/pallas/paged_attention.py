@@ -233,6 +233,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         functools.partial(
             kernel, scale=float(scale), page_size=page_size,
         ),
+        name="paged_attention_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,

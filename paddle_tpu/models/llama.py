@@ -15,6 +15,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import ops as F
+from ..core.autograd import scope
 from ..generation import GenerationMixin, KVCache
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.container import LayerList
@@ -201,24 +202,26 @@ class LlamaDecoderLayer(Layer):
             self.mlp = LlamaMLP(config)
 
     def forward(self, hidden, attn_mask=None, cache=None, position=None):
-        residual = hidden
-        hidden = self.input_layernorm(hidden)
-        if cache is None:
-            hidden = self.self_attn(hidden, attn_mask)
-            new_cache = None
-        else:
-            hidden, new_cache = self.self_attn(
-                hidden, attn_mask, cache, position
-            )
-        hidden = residual + hidden
-        residual = hidden
-        hidden = self.post_attention_layernorm(hidden)
-        aux = None
-        if self._moe:
-            hidden, aux = self.mlp(hidden)
-        else:
-            hidden = self.mlp(hidden)
-        out = residual + hidden
+        with scope("attention"):
+            residual = hidden
+            hidden = self.input_layernorm(hidden)
+            if cache is None:
+                hidden = self.self_attn(hidden, attn_mask)
+                new_cache = None
+            else:
+                hidden, new_cache = self.self_attn(
+                    hidden, attn_mask, cache, position
+                )
+            hidden = residual + hidden
+        with scope("mlp"):
+            residual = hidden
+            hidden = self.post_attention_layernorm(hidden)
+            aux = None
+            if self._moe:
+                hidden, aux = self.mlp(hidden)
+            else:
+                hidden = self.mlp(hidden)
+            out = residual + hidden
         if cache is not None:
             return out, new_cache
         return (out, aux) if self._moe else out
@@ -235,7 +238,8 @@ class LlamaModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, attn_mask=None, caches=None, position=None):
-        hidden = self.embed_tokens(input_ids)
+        with scope("embedding"):
+            hidden = self.embed_tokens(input_ids)
         aux_total = None
         new_caches = []
         for i, layer in enumerate(self.layers):
@@ -257,7 +261,8 @@ class LlamaModel(Layer):
                     aux_total = aux if aux_total is None else aux_total + aux
             else:
                 hidden = out
-        hidden = self.norm(hidden)
+        with scope("lm_head_loss"):
+            hidden = self.norm(hidden)
         if caches is not None:
             return hidden, new_caches
         if self.config.num_experts > 0:
@@ -308,48 +313,45 @@ class LlamaForCausalLM(GenerationMixin, Layer):
             hidden, new_caches = self.llama(
                 input_ids, attn_mask, caches=caches, position=position
             )
-            if self.lm_head is not None:
-                logits = self.lm_head(hidden)
-            else:
-                logits = F.matmul(
-                    hidden, self.llama.embed_tokens.weight, transpose_y=True
-                )
-            return logits, new_caches
+            with scope("lm_head_loss"):
+                return self._logits(hidden), new_caches
         hidden = self.llama(input_ids, attn_mask)
         aux = None
         if isinstance(hidden, tuple):
             hidden, aux = hidden
-        if labels is not None and self.config.fused_loss_chunk > 0:
-            b, s, h = hidden.shape
-            head_w = (
-                self.lm_head.weight if self.lm_head is not None
-                else F.transpose(self.llama.embed_tokens.weight, [1, 0])
-            )
-            loss = F.fused_linear_cross_entropy(
-                F.reshape(hidden[:, :-1], [-1, h]), head_w,
-                F.reshape(labels[:, 1:], [-1]),
-                chunk_size=self.config.fused_loss_chunk,
-            )
+        with scope("lm_head_loss"):
+            if labels is not None and self.config.fused_loss_chunk > 0:
+                b, s, h = hidden.shape
+                head_w = (
+                    self.lm_head.weight if self.lm_head is not None
+                    else F.transpose(self.llama.embed_tokens.weight, [1, 0])
+                )
+                logits = None
+                loss = F.fused_linear_cross_entropy(
+                    F.reshape(hidden[:, :-1], [-1, h]), head_w,
+                    F.reshape(labels[:, 1:], [-1]),
+                    chunk_size=self.config.fused_loss_chunk,
+                )
+            else:
+                logits = self._logits(hidden)
+                if labels is None:
+                    return logits
+                # causal LM loss: shift by one
+                b, s, v = logits.shape
+                loss = F.cross_entropy(
+                    F.reshape(logits[:, :-1], [-1, v]),
+                    F.reshape(labels[:, 1:], [-1]),
+                )
             if aux is not None:
                 loss = loss + self.config.router_aux_loss_coef * aux
-            return None, loss
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            logits = F.matmul(
-                hidden, self.llama.embed_tokens.weight, transpose_y=True
-            )
-        if labels is None:
-            return logits
-        # causal LM loss: shift by one
-        b, s, v = logits.shape
-        loss = F.cross_entropy(
-            F.reshape(logits[:, :-1], [-1, v]),
-            F.reshape(labels[:, 1:], [-1]),
-        )
-        if aux is not None:
-            loss = loss + self.config.router_aux_loss_coef * aux
         return logits, loss
+
+    def _logits(self, hidden):
+        if self.lm_head is not None:
+            return self.lm_head(hidden)
+        return F.matmul(
+            hidden, self.llama.embed_tokens.weight, transpose_y=True
+        )
 
     def num_params(self):
         return sum(int(np.prod(p.shape)) for p in self.parameters())
@@ -515,26 +517,30 @@ class LlamaPipeline:
             # each; activations between blocks stay replicated over tp
             # (unvarying — shard_map's type system transposes grads
             # exactly, see distributed/pipeline.py scaffold docstring)
-            x = _rms(h, bp["ln1"], epsilon=eps)
-            b, s = x.shape[0], x.shape[1]
-            q = (x @ bp["wq"]).reshape(b, s, nh_l, hd)
-            k = (x @ bp["wk"]).reshape(b, s, nkv_l, hd)
-            v = (x @ bp["wv"]).reshape(b, s, nkv_l, hd)
-            q, k = _rope(q, k, base=theta)
-            if nkv_l != nh_l:
-                rep = nh_l // nkv_l
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            o = _sdpa(q, k, v, is_causal=True)
-            part = o.reshape(b, s, nh_l * hd) @ bp["wo"]
-            if tp_ax:
-                part = jax.lax.psum(part, tp_ax)
-            h = h + part
-            x = _rms(h, bp["ln2"], epsilon=eps)
-            part = _swiglu(x @ bp["wg"], x @ bp["wu"]) @ bp["wd"]
-            if tp_ax:
-                part = jax.lax.psum(part, tp_ax)
-            h = h + part
+            # the scope names of LlamaDecoderLayer (plain named_scope:
+            # this region is differentiated by jax, not by the tape)
+            with jax.named_scope("attention"):
+                x = _rms(h, bp["ln1"], epsilon=eps)
+                b, s = x.shape[0], x.shape[1]
+                q = (x @ bp["wq"]).reshape(b, s, nh_l, hd)
+                k = (x @ bp["wk"]).reshape(b, s, nkv_l, hd)
+                v = (x @ bp["wv"]).reshape(b, s, nkv_l, hd)
+                q, k = _rope(q, k, base=theta)
+                if nkv_l != nh_l:
+                    rep = nh_l // nkv_l
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                o = _sdpa(q, k, v, is_causal=True)
+                part = o.reshape(b, s, nh_l * hd) @ bp["wo"]
+                if tp_ax:
+                    part = jax.lax.psum(part, tp_ax)
+                h = h + part
+            with jax.named_scope("mlp"):
+                x = _rms(h, bp["ln2"], epsilon=eps)
+                part = _swiglu(x @ bp["wg"], x @ bp["wu"]) @ bp["wd"]
+                if tp_ax:
+                    part = jax.lax.psum(part, tp_ax)
+                h = h + part
             return h
 
         def stage_fn(sp, h):
@@ -544,8 +550,10 @@ class LlamaPipeline:
             return h
 
         def first_fn(fp, ids):
-            return fp["embed"][ids]
+            with jax.named_scope("embedding"):
+                return fp["embed"][ids]
 
+        @jax.named_scope("lm_head_loss")
         def last_fn(lp, h, labels):
             h = _rms(h, lp["norm"], epsilon=eps)
             logits = (h[:, :-1] @ lp["head"]).astype(jnp.float32)

@@ -10,9 +10,10 @@ three always-on layers (docs/observability.md):
     snapshot; subsystems with their own counter structs (the serving
     engine) publish as pull-time collector views, so the hot path
     writes nothing.
-  * **spans** — trace/span ids layered on ``profiler.RecordEvent``,
-    propagated across ``TCPStore`` and ``distributed.rpc`` boundaries,
-    exportable as Chrome-trace JSONL.
+  * **spans** — trace/span ids on the profiler's clock: every span is
+    a host event of whatever ``jax.profiler`` session is recording and
+    an entry of a bounded ring, propagated across ``TCPStore`` and
+    ``distributed.rpc`` boundaries, exportable as Chrome-trace JSONL.
   * **flight recorder** — a bounded ring of recent events (compiles,
     preemptions, fault fires, shed/timed-out requests, watchdog probe
     snapshots) dumped to a postmortem JSON file on a watchdog trip, an
@@ -20,7 +21,8 @@ three always-on layers (docs/observability.md):
     ``python -m paddle_tpu.observability dump``.
 
 Plus the **compile/retrace event log** (``jit_events``): every XLA
-trace is recorded with fn/signature/elapsed, and a retrace of an
+trace is recorded with fn/signature/elapsed and its trace, lower and
+compile phases, and a retrace of an
 already-warm signature increments an alarmable counter — "recompile
 after warmup" stops being a flaky bench and becomes a monitorable
 number. An optional scrape thread (``start_scrape_server``) serves

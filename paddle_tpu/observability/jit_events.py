@@ -17,7 +17,13 @@ traced body calls :func:`mark_traced` at its top. The body of a
 ``jax.jit`` function only executes while XLA is TRACING it, so
 ``mark_traced`` fires exactly on compiles and is free on the warm
 path; the enclosing ``watch`` supplies the event's identity and
-measures elapsed time (trace + compile + first run).
+measures the call's elapsed time, first run included. What JAX itself
+times inside that call (``jax.monitoring``: tracing to a jaxpr,
+lowering to a module, the backend's compile or the persistent cache's
+load) the watch hears on its own thread and records as ``jit.trace``,
+``jit.lower`` and ``jit.compile`` spans (``cache_hit=`` on the last)
+and as ``trace_s``, ``lower_s``, ``compile_s`` on the event. A compile
+outside any ``watch`` records nothing.
 
 Executables loaded from the persistent compile cache
 (``paddle_tpu.compilecache``) are recorded via :func:`mark_aot_hit`
@@ -37,7 +43,10 @@ import threading
 import time
 from collections import deque
 
+import jax.monitoring
+
 from . import metrics as _metrics
+from . import spans as _spans
 
 __all__ = [
     "watch", "mark_traced", "mark_aot_hit", "suppress", "compile_log",
@@ -90,9 +99,49 @@ class suppress:
         return False
 
 
+# JAX's own duration events -> the phase they are recorded as
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_time_span(event, start_s, end_s, **kwargs):
+    """jax.monitoring listener, on the thread that compiles: an interval
+    of the innermost open ``watch``. Only the outermost intervals are
+    kept, so the phases never count a second twice: a jit traced inside
+    another's trace, a helper traced while lowering or a constant
+    compiled while tracing belongs to the phase that contains it."""
+    phase = _PHASES.get(event)
+    st = getattr(_tls, "stack", None)
+    if phase is None or not st or _suppressed():
+        return
+    w = st[-1]
+    hit = phase == "compile" and w._cache_hits > 0
+    if phase == "compile":
+        w._cache_hits = 0
+    if any(p[1] <= start_s and end_s <= p[2] for p in w.phases):
+        return
+    w.phases = [p for p in w.phases
+                if not (start_s <= p[1] and p[2] <= end_s)]
+    w.phases.append((phase, start_s, end_s, hit))
+
+
+def _on_event(event, **kwargs):
+    st = getattr(_tls, "stack", None)
+    if event == _CACHE_HIT and st:
+        st[-1]._cache_hits += 1
+
+
+jax.monitoring.register_event_time_span_listener(_on_time_span)
+jax.monitoring.register_event_listener(_on_event)
+
+
 class watch:
     """Wrap one jitted call; supplies identity + elapsed time for any
-    trace that fires inside it::
+    trace that fires inside it, and hears JAX's compile phases::
 
         with jit_events.watch("decode", kind="serving", signature="s"):
             out = decode_jit(...)
@@ -103,6 +152,8 @@ class watch:
         self.kind = kind
         self.signature = str(signature)
         self.events = []
+        self.phases = []      # (phase, start_s, end_s, cache_hit)
+        self._cache_hits = 0
         self._t0 = None
 
     def __enter__(self):
@@ -121,8 +172,18 @@ class watch:
                 pass
         if self.events:
             elapsed = time.perf_counter() - self._t0
+            sums = {"trace": 0.0, "lower": 0.0, "compile": 0.0}
+            for phase, start_s, end_s, hit in self.phases:
+                sums[phase] += end_s - start_s
+                attrs = {"cache_hit": hit} if phase == "compile" else {}
+                _spans.record(
+                    "jit." + phase, start_s * 1e9, end_s * 1e9,
+                    fn=self.name, kind=self.kind, **attrs,
+                )
             for ev in self.events:
                 ev["elapsed_s"] = elapsed
+                for phase, seconds in sums.items():
+                    ev[phase + "_s"] = seconds
                 _emit(ev)
         return False
 
@@ -157,6 +218,7 @@ def mark_traced(name=None, kind=None, signature=None):
         "trace_no": count,
         "retrace": retrace,
         "elapsed_s": None,
+        "trace_s": None, "lower_s": None, "compile_s": None,
     }
     if w is not None:
         w.events.append(ev)   # elapsed filled at watch exit

@@ -1,4 +1,4 @@
-"""Structured spans: trace/span ids layered on ``profiler.RecordEvent``.
+"""Structured spans: trace/span ids on the profiler's clock.
 
 The Dapper model: every span carries a ``trace_id`` shared by the whole
 request and a fresh ``span_id``; the current span rides a contextvar so
@@ -8,10 +8,17 @@ nesting needs no plumbing, and a compact **traceparent** string
 the server side with :func:`remote_span`, so one request can be
 followed wall-to-wall across workers.
 
-Each span still enters a ``profiler.RecordEvent`` range, so spans show
-up in the sampled profiler exactly like hand-written annotations;
-finished spans additionally land in a bounded in-memory buffer
-exportable as Chrome-trace JSONL (:func:`export_chrome_trace`, load via
+Every span enters a ``jax.profiler.TraceAnnotation``, always: whoever
+started the ``jax.profiler`` session that is recording (this package's
+``Profiler``, a plain ``jax.profiler.start_trace``, the capture server),
+the span is a host event of its xplane beside the device's operations.
+With no session the annotation costs well under a microsecond (PERF.md
+has the chip host's figure). Finished spans additionally land in a
+bounded in-memory ring, with start and end as integer nanoseconds of
+``time.time_ns()``, the clock the profiler stamps host events with, so a
+span in the ring and its event in a trace are the same interval. The
+ring is read with :func:`finished_spans` and :func:`last`, or exported
+as Chrome-trace JSONL (:func:`export_chrome_trace`, load via
 ``chrome://tracing`` / Perfetto "json" mode).
 """
 from __future__ import annotations
@@ -26,13 +33,15 @@ import time
 import warnings
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from .. import profiler as _profiler
-from ..profiler import RecordEvent
 
 __all__ = [
     "Span", "span", "remote_span", "current_span", "current_trace_id",
-    "current_traceparent", "finished_spans", "clear_finished_spans",
-    "export_chrome_trace", "set_span_buffer_capacity",
+    "current_traceparent", "finished_spans", "last", "record",
+    "clear_finished_spans", "export_chrome_trace",
+    "set_span_buffer_capacity",
 ]
 
 _current: contextvars.ContextVar = contextvars.ContextVar(
@@ -60,7 +69,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "attrs",
-        "start_us", "duration_s", "_t0", "_record",
+        "start_ns", "end_ns",
     )
 
     def __init__(self, name, trace_id=None, parent_id=None, **attrs):
@@ -69,14 +78,18 @@ class Span:
         self.span_id = _new_id(8)
         self.parent_id = parent_id
         self.attrs = attrs
-        self.start_us = None
-        self.duration_s = None
-        self._t0 = None
-        self._record = None
+        self.start_ns = None   # time.time_ns(), the profiler's host clock
+        self.end_ns = None     # None while the span is open
 
     @property
     def traceparent(self):
         return f"{self.trace_id}-{self.span_id}"
+
+    @property
+    def duration_s(self):
+        if self.end_ns is None:
+            return None
+        return (self.end_ns - self.start_ns) * 1e-9
 
     def to_chrome_event(self):
         """One Chrome-trace "complete" (ph=X) event."""
@@ -84,7 +97,7 @@ class Span:
             "name": self.name,
             "cat": "paddle_tpu",
             "ph": "X",
-            "ts": self.start_us,
+            "ts": self.start_ns / 1e3,
             "dur": (self.duration_s or 0.0) * 1e6,
             "pid": os.getpid(),
             "tid": threading.get_ident() & 0x7FFFFFFF,
@@ -105,31 +118,39 @@ class _SpanScope:
     def __init__(self, sp):
         self.span = sp
         self._token = None
+        self._annotation = None
 
     def __enter__(self):
         sp = self.span
-        sp.start_us = time.time() * 1e6
-        sp._t0 = time.perf_counter()
-        # profiler integration only while a session is RECORDING: an
-        # always-on TraceAnnotation would cost tens of microseconds per
-        # span with nobody listening — the difference between telemetry
-        # riding a decode step for free and taxing it
-        if _profiler._session_active():
-            sp._record = RecordEvent(sp.name)
-            sp._record.begin()
         self._token = _current.set(sp)
+        # the annotation innermost, the clock read beside it: the ring's
+        # interval and the trace's event are the same one
+        self._annotation = TraceAnnotation(sp.name)
+        self._annotation.__enter__()
+        sp.start_ns = time.time_ns()
         return sp
 
     def __exit__(self, *exc):
         sp = self.span
+        sp.end_ns = time.time_ns()
+        self._annotation.__exit__(None, None, None)
         _current.reset(self._token)
-        if sp._record is not None:
-            sp._record.end()
-            sp._record = None
-        sp.duration_s = time.perf_counter() - sp._t0
+        if _profiler._stats_active():
+            # a Profiler in a RECORD state tabulates spans like RecordEvents
+            _profiler._record_span(sp.name, sp.duration_s, "user")
         with _buf_lock:
             _finished.append(sp)
         return False
+
+
+def _child(name, attrs):
+    """A span under the current one (a fresh trace root when none)."""
+    parent = _current.get()
+    if parent is None:
+        return Span(name, **attrs)
+    return Span(
+        name, trace_id=parent.trace_id, parent_id=parent.span_id, **attrs
+    )
 
 
 def span(name, **attrs):
@@ -139,15 +160,18 @@ def span(name, **attrs):
         with observability.span("serving.decode", step=i):
             ...
     """
-    parent = _current.get()
-    if parent is not None:
-        sp = Span(
-            name, trace_id=parent.trace_id, parent_id=parent.span_id,
-            **attrs,
-        )
-    else:
-        sp = Span(name, **attrs)
-    return _SpanScope(sp)
+    return _SpanScope(_child(name, attrs))
+
+
+def record(name, start_ns, end_ns, **attrs):
+    """A span that somebody else timed, on the same clock, and that is
+    already over (``jit_events`` gets JAX's compile phases this way):
+    child of the current span, straight into the ring, no annotation."""
+    sp = _child(name, attrs)
+    sp.start_ns, sp.end_ns = int(start_ns), int(end_ns)
+    with _buf_lock:
+        _finished.append(sp)
+    return sp
 
 
 def remote_span(name, traceparent, **attrs):
@@ -187,6 +211,21 @@ def finished_spans():
     """Snapshot of the bounded finished-span buffer (newest last)."""
     with _buf_lock:
         return list(_finished)
+
+
+def last(name, n=1):
+    """The newest ``n`` finished spans called ``name``, oldest first, or
+    None when the ring holds fewer: it has wrapped, or they never
+    finished. How a reader takes exactly the steps of a window without
+    a clock: count them, then ask for that many."""
+    out = []
+    with _buf_lock:
+        for sp in reversed(_finished):
+            if sp.name == name:
+                out.append(sp)
+                if len(out) == n:
+                    return out[::-1]
+    return None
 
 
 def clear_finished_spans():

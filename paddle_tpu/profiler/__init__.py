@@ -111,20 +111,10 @@ def load_profiler_result(path):
 # aggregated table.
 
 _op_stats: dict | None = None
-_jax_tracing = 0   # jax.profiler.start_trace sessions in flight
 
 
 def _stats_active():
     return _op_stats is not None
-
-
-def _session_active():
-    """True while a profiler session is recording (op stats window or a
-    device trace). ``observability.spans`` uses this to skip the
-    TraceAnnotation + stats work on the serving hot path when nobody is
-    profiling — an annotation with no session behind it costs tens of
-    microseconds per step and records nothing."""
-    return _op_stats is not None or _jax_tracing > 0
 
 
 def _record_span(name, seconds, category="op"):
@@ -272,7 +262,6 @@ class Profiler:
                 self._on_trace_ready(self)
 
     def _start_trace(self):
-        global _jax_tracing
         self._log_dir = (
             self._export_dir
             or getattr(self._on_trace_ready, "dir_name", None)
@@ -280,13 +269,10 @@ class Profiler:
         )
         jax.profiler.start_trace(self._log_dir)
         self._tracing = True
-        _jax_tracing += 1
 
     def _stop_trace(self):
-        global _jax_tracing
         jax.profiler.stop_trace()
         self._tracing = False
-        _jax_tracing = max(0, _jax_tracing - 1)
 
     def __enter__(self):
         return self.start()

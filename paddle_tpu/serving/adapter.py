@@ -343,10 +343,11 @@ class LlamaServingAdapter:
             return _sdpa(q, k, v, is_causal=True)
 
     def _mlp(self, wl, x):
-        h = _rms_norm(x, wl["ln2"], epsilon=self.eps)
-        return x + _row_matmul(
-            _swiglu(h @ wl["wg"], h @ wl["wu"]), wl["wd"], self.tp_spec
-        )
+        with jax.named_scope("mlp"):
+            h = _rms_norm(x, wl["ln2"], epsilon=self.eps)
+            return x + _row_matmul(
+                _swiglu(h @ wl["wg"], h @ wl["wu"]), wl["wd"], self.tp_spec
+            )
 
     def _logits(self, w, x):
         head = w["head"]
@@ -359,30 +360,34 @@ class LlamaServingAdapter:
         """ids [S] (padded to a bucket), length scalar, block_table [P].
         Returns (logits [vocab] at position length-1, kp, vp)."""
         s = ids.shape[0]
-        x = w["embed"][ids][None]                      # [1, S, hid]
+        with jax.named_scope("embedding"):
+            x = w["embed"][ids][None]                      # [1, S, hid]
         pos = jnp.arange(s, dtype=jnp.int32)[None]     # prompts start at 0
         kp, vp = list(kp), list(vp)
         for li in range(self.num_layers):
             wl = w["layers"][li]
-            h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
-            q, k, v = self._qkv(wl, h, 1, s)
-            q, k = _rope_qk(q, k, pos, base=self.rope_theta)
-            kp[li] = _write_prompt_pages(kp[li], k[0], block_table, length)
-            vp[li] = _write_prompt_pages(vp[li], v[0], block_table, length)
-            if self.num_kv_heads != self.num_heads:
-                rep = self.num_heads // self.num_kv_heads
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            # causal attention over the in-flight prompt; right-padding is
-            # invisible to valid queries under causality
-            attn = self._prompt_attention(q, k, v)
-            x = x + _row_matmul(
-                attn.reshape(1, s, -1), wl["wo"], self.tp_spec
-            )
+            with jax.named_scope("attention"):
+                h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
+                q, k, v = self._qkv(wl, h, 1, s)
+                q, k = _rope_qk(q, k, pos, base=self.rope_theta)
+                kp[li] = _write_prompt_pages(kp[li], k[0], block_table, length)
+                vp[li] = _write_prompt_pages(vp[li], v[0], block_table, length)
+                if self.num_kv_heads != self.num_heads:
+                    rep = self.num_heads // self.num_kv_heads
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                # causal attention over the in-flight prompt; right-padding is
+                # invisible to valid queries under causality
+                attn = self._prompt_attention(q, k, v)
+                x = x + _row_matmul(
+                    attn.reshape(1, s, -1), wl["wo"], self.tp_spec
+                )
             x = self._mlp(wl, x)
-        x = _rms_norm(x, w["norm"], epsilon=self.eps)
-        h_last = jnp.take(x[0], length - 1, axis=0)    # [hid]
-        return self._logits(w, h_last), tuple(kp), tuple(vp)
+        with jax.named_scope("lm_head_loss"):
+            x = _rms_norm(x, w["norm"], epsilon=self.eps)
+            h_last = jnp.take(x[0], length - 1, axis=0)    # [hid]
+            logits = self._logits(w, h_last)
+        return logits, tuple(kp), tuple(vp)
 
     def prefill_ext(self, w, kp, vp, ids, length, cache_len, block_table):
         """Prefill CONTINUATION: run one chunk of a prompt whose first
@@ -403,7 +408,8 @@ class LlamaServingAdapter:
         values, and padded/garbage context rows are exact zeros in the
         softmax."""
         s = ids.shape[0]
-        x = w["embed"][ids][None]                       # [1, S, hid]
+        with jax.named_scope("embedding"):
+            x = w["embed"][ids][None]                       # [1, S, hid]
         pos = (cache_len + jnp.arange(s, dtype=jnp.int32))[None]
         kp, vp = list(kp), list(vp)
         capacity = block_table.shape[0] * _pages_geometry(kp[0])[1]
@@ -416,29 +422,33 @@ class LlamaServingAdapter:
         )[None, None]                                   # [1, 1, S, C]
         for li in range(self.num_layers):
             wl = w["layers"][li]
-            h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
-            q, k, v = self._qkv(wl, h, 1, s)
-            q, k = _rope_qk(q, k, pos, base=self.rope_theta)
-            kp[li] = _write_chunk_pages(
-                kp[li], k[0], block_table, length, cache_len
-            )
-            vp[li] = _write_chunk_pages(
-                vp[li], v[0], block_table, length, cache_len
-            )
-            kc = _gather_context(kp[li], block_table)[None]  # [1, C, kv, d]
-            vc = _gather_context(vp[li], block_table)[None]
-            if self.num_kv_heads != self.num_heads:
-                rep = self.num_heads // self.num_kv_heads
-                kc = jnp.repeat(kc, rep, axis=2)
-                vc = jnp.repeat(vc, rep, axis=2)
-            attn = _sdpa(q, kc, vc, keep, is_causal=False)
-            x = x + _row_matmul(
-                attn.reshape(1, s, -1), wl["wo"], self.tp_spec
-            )
+            with jax.named_scope("attention"):
+                h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
+                q, k, v = self._qkv(wl, h, 1, s)
+                q, k = _rope_qk(q, k, pos, base=self.rope_theta)
+                kp[li] = _write_chunk_pages(
+                    kp[li], k[0], block_table, length, cache_len
+                )
+                vp[li] = _write_chunk_pages(
+                    vp[li], v[0], block_table, length, cache_len
+                )
+                # [1, C, kv, d]
+                kc = _gather_context(kp[li], block_table)[None]
+                vc = _gather_context(vp[li], block_table)[None]
+                if self.num_kv_heads != self.num_heads:
+                    rep = self.num_heads // self.num_kv_heads
+                    kc = jnp.repeat(kc, rep, axis=2)
+                    vc = jnp.repeat(vc, rep, axis=2)
+                attn = _sdpa(q, kc, vc, keep, is_causal=False)
+                x = x + _row_matmul(
+                    attn.reshape(1, s, -1), wl["wo"], self.tp_spec
+                )
             x = self._mlp(wl, x)
-        x = _rms_norm(x, w["norm"], epsilon=self.eps)
-        h_last = jnp.take(x[0], length - 1, axis=0)     # [hid]
-        return self._logits(w, h_last), tuple(kp), tuple(vp)
+        with jax.named_scope("lm_head_loss"):
+            x = _rms_norm(x, w["norm"], epsilon=self.eps)
+            h_last = jnp.take(x[0], length - 1, axis=0)     # [hid]
+            logits = self._logits(w, h_last)
+        return logits, tuple(kp), tuple(vp)
 
     def decode(self, w, kp, vp, tokens, positions, block_tables, active):
         """tokens/positions [slots], block_tables [slots, P], active
@@ -463,31 +473,35 @@ class LlamaServingAdapter:
             dphys, dslot = _window_routing(
                 block_tables, wpos, wpos < capacity, n_blocks, bs_pg,
             )
-        x = w["embed"][tokens]                         # [slots, hid]
+        with jax.named_scope("embedding"):
+            x = w["embed"][tokens]                         # [slots, hid]
         kp, vp = list(kp), list(vp)
         for li in range(self.num_layers):
             wl = w["layers"][li]
-            h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
-            q, k, v = self._qkv(wl, h[:, None, :], b, 1)
-            q, k = _rope_qk(q, k, positions[:, None], base=self.rope_theta)
-            if self.tp_spec is not None:
-                kp[li] = _write_window_pages(kp[li], k, dphys, dslot)
-                vp[li] = _write_window_pages(vp[li], v, dphys, dslot)
-            else:
-                kp[li], vp[li] = update_pages(
-                    kp[li], vp[li], k[:, 0], v[:, 0], block_tables,
-                    write_pos,
+            with jax.named_scope("attention"):
+                h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
+                q, k, v = self._qkv(wl, h[:, None, :], b, 1)
+                q, k = _rope_qk(q, k, positions[:, None], base=self.rope_theta)
+                if self.tp_spec is not None:
+                    kp[li] = _write_window_pages(kp[li], k, dphys, dslot)
+                    vp[li] = _write_window_pages(vp[li], v, dphys, dslot)
+                else:
+                    kp[li], vp[li] = update_pages(
+                        kp[li], vp[li], k[:, 0], v[:, 0], block_tables,
+                        write_pos,
+                    )
+                attn = _paged_attn(
+                    q[:, 0], kp[li], vp[li], block_tables, lengths,
+                    kernel=self.decode_kernel,
+                )                                          # [slots, heads, d]
+                x = x + _row_matmul(
+                    attn.reshape(b, -1), wl["wo"], self.tp_spec
                 )
-            attn = _paged_attn(
-                q[:, 0], kp[li], vp[li], block_tables, lengths,
-                kernel=self.decode_kernel,
-            )                                          # [slots, heads, d]
-            x = x + _row_matmul(
-                attn.reshape(b, -1), wl["wo"], self.tp_spec
-            )
             x = self._mlp(wl, x)
-        x = _rms_norm(x, w["norm"], epsilon=self.eps)
-        return self._logits(w, x), tuple(kp), tuple(vp)
+        with jax.named_scope("lm_head_loss"):
+            x = _rms_norm(x, w["norm"], epsilon=self.eps)
+            logits = self._logits(w, x)
+        return logits, tuple(kp), tuple(vp)
 
     def verify(self, w, kp, vp, tokens, positions, draft_lens,
                block_tables, active):
@@ -536,28 +550,32 @@ class LlamaServingAdapter:
             jnp.arange(capacity, dtype=jnp.int32)[None, None, :]
             <= pos[:, :, None]
         )[:, None]                                         # [b, 1, S, C]
-        x = w["embed"][tokens]                             # [b, S, hid]
+        with jax.named_scope("embedding"):
+            x = w["embed"][tokens]                             # [b, S, hid]
         kp, vp = list(kp), list(vp)
         for li in range(self.num_layers):
             wl = w["layers"][li]
-            h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
-            q, k, v = self._qkv(wl, h, b, s)
-            q, k = _rope_qk(q, k, pos, base=self.rope_theta)
-            kp[li] = _write_window_pages(kp[li], k, phys, slot)
-            vp[li] = _write_window_pages(vp[li], v, phys, slot)
-            kc = _gather_context_batch(kp[li], block_tables)
-            vc = _gather_context_batch(vp[li], block_tables)
-            if self.num_kv_heads != self.num_heads:
-                rep = self.num_heads // self.num_kv_heads
-                kc = jnp.repeat(kc, rep, axis=2)
-                vc = jnp.repeat(vc, rep, axis=2)
-            attn = _sdpa(q, kc, vc, keep, is_causal=False)
-            x = x + _row_matmul(
-                attn.reshape(b, s, -1), wl["wo"], self.tp_spec
-            )
+            with jax.named_scope("attention"):
+                h = _rms_norm(x, wl["ln1"], epsilon=self.eps)
+                q, k, v = self._qkv(wl, h, b, s)
+                q, k = _rope_qk(q, k, pos, base=self.rope_theta)
+                kp[li] = _write_window_pages(kp[li], k, phys, slot)
+                vp[li] = _write_window_pages(vp[li], v, phys, slot)
+                kc = _gather_context_batch(kp[li], block_tables)
+                vc = _gather_context_batch(vp[li], block_tables)
+                if self.num_kv_heads != self.num_heads:
+                    rep = self.num_heads // self.num_kv_heads
+                    kc = jnp.repeat(kc, rep, axis=2)
+                    vc = jnp.repeat(vc, rep, axis=2)
+                attn = _sdpa(q, kc, vc, keep, is_causal=False)
+                x = x + _row_matmul(
+                    attn.reshape(b, s, -1), wl["wo"], self.tp_spec
+                )
             x = self._mlp(wl, x)
-        x = _rms_norm(x, w["norm"], epsilon=self.eps)
-        return self._logits(w, x), tuple(kp), tuple(vp)
+        with jax.named_scope("lm_head_loss"):
+            x = _rms_norm(x, w["norm"], epsilon=self.eps)
+            logits = self._logits(w, x)
+        return logits, tuple(kp), tuple(vp)
 
 
 def build_adapter(model):
