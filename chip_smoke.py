@@ -165,6 +165,9 @@ def phase_kernels():
         q, k, v,
     )
     c.check("flash_attention fwd+bwd lowered to tpu_custom_call", is_mosaic)
+    tiles = sorted(_compat.flash_blocks())
+    c.check("flash_attention's three kernels recorded their tiles",
+            len({kernel for kernel, _, _ in tiles}) == 3, str(tiles))
     (_, out), grads = flash(q, k, v)
     (_, ref), ref_grads = jax.jit(jax.value_and_grad(
         math_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)

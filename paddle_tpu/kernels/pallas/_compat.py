@@ -23,6 +23,12 @@ Three concerns every kernel in this package routes through:
     select a path from what they can observe (backend, shape, dtype,
     sharding), and on a TPU the counter staying at 0 is what
     ``chip_smoke.py`` asserts.
+
+A fourth, smaller one: ``record_flash_blocks()`` — the flash kernels
+choose their tile from the call's shapes at trace time, and each traced
+kernel call bumps ``paddle_tpu_kernels_flash_blocks{kernel,block_q,
+block_k}``, so a test, ``chip_smoke.py`` or a reader of the metrics
+registry can say which tile a shape got.
 """
 from __future__ import annotations
 
@@ -121,3 +127,30 @@ def fallbacks_total():
     """Current total of the degradation counter (test/diagnostic
     accessor)."""
     return sum(child.value for _, child in _fallback_counter()._series())
+
+
+def _flash_blocks_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_flash_blocks",
+        "Traced flash-attention kernel calls by the tile they were given",
+        labelnames=("kernel", "block_q", "block_k"),
+    )
+
+
+def record_flash_blocks(kernel, block_q, block_k):
+    """One traced call of ``kernel`` with this tile (a choice made at
+    trace time has no rate; it has a value)."""
+    _flash_blocks_counter().inc(
+        kernel=kernel, block_q=block_q, block_k=block_k)
+
+
+def flash_blocks():
+    """{(kernel, block_q, block_k): traced calls} (test/diagnostic
+    accessor)."""
+    return {
+        (labels["kernel"], int(labels["block_q"]), int(labels["block_k"])):
+        child.value
+        for labels, child in _flash_blocks_counter()._series()
+    }

@@ -845,8 +845,11 @@ def _pallas_attention_eligible(query, key, value, attn_mask, dropout_p,
         return False
     if d > 256 or d % 8 != 0:
         return False
-    # below the crossover, XLA's fused attention beats the kernel
-    # (measured: 130ms vs 155ms full-model step at seq 1024 on v5e)
+    # below the crossover, XLA's fused attention beat the kernel: 130ms
+    # vs 155ms full-model step at seq 1024 on v5e, measured against the
+    # kernel's old fixed 128 x 128 tile. The kernel now takes its tile
+    # from the shape and is several times faster at 4096; the crossover
+    # has not been measured again (PERF.md section 7).
     if max(sq, sk) < flags.get_flag("FLAGS_flash_attention_min_seq"):
         return False
     # real-TPU tile constraint: sequence blocks of 128 lanes

@@ -154,6 +154,228 @@ class TestFlashAttention:
 
 
 # ----------------------------------------------------------------------
+# flash attention's tiling: the block chooser, unequal blocks, the causal
+# schedule (clamped index maps, unmasked interior blocks), the series
+# that says which tile a call got
+# ----------------------------------------------------------------------
+from paddle_tpu.kernels.pallas import _compat  # noqa: E402
+from paddle_tpu.kernels.pallas import flash_attention as fa  # noqa: E402
+
+
+def _math_sdpa(q, k, v, causal):
+    """The unfused computation in float32 on the inputs as given (the
+    kernel masks with top-left aligned positions)."""
+    qf, kf, vf = (jnp.swapaxes(x, 1, 2).astype(jnp.float32)
+                  for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) / np.sqrt(q.shape[-1])
+    if causal:
+        ql, kl = s.shape[-2:]
+        s = jnp.where(
+            jnp.arange(ql)[:, None] >= jnp.arange(kl)[None, :], s, -1e30)
+    p = jax.nn.softmax(s, -1)
+    return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vf), 1, 2)
+
+
+def _out_and_grads(fn, q, k, v, w):
+    """Output and the three gradients of sum(fn(q, k, v) * w), float32."""
+    out, vjp = jax.vjp(lambda *a: fn(*a).astype(jnp.float32), q, k, v)
+    return [np.asarray(x, np.float32) for x in (out, *vjp(w))]
+
+
+def _inputs(seed, sq, sk, dtype, heads=2, d=64):
+    rng = np.random.RandomState(seed)
+    mk = lambda s: jnp.asarray(rng.randn(1, s, heads, d), dtype)
+    return mk(sq), mk(sk), mk(sk), jnp.asarray(
+        rng.randn(1, sq, heads, d), jnp.float32)
+
+
+def _worst(got, want):
+    """Largest |got - want| over the largest |want|, per tensor."""
+    return max(float(np.abs(g - w).max() / np.abs(w).max())
+               for g, w in zip(got, want))
+
+
+# float32 inputs: reduction order alone differs from the math form.
+# bf16 inputs: q (with the scale folded in), p and ds enter the MXU
+# rounded to bf16 (2**-9 = 2e-3 relative each) and the results are
+# stored in bf16; against the float32 math on the same bf16 inputs the
+# worst element of an output or gradient lies within 2e-2 of its
+# tensor's largest (8e-3 is the most these cases read).
+_TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+class TestFlashTiling:
+    @pytest.mark.parametrize("kernel", fa.KERNELS)
+    @pytest.mark.parametrize("seq,want", [(2176, 128), (4864, 256)])
+    def test_chooser_takes_a_divisor(self, kernel, seq, want):
+        assert fa.choose_blocks(seq, seq, 128, jnp.bfloat16, kernel) == (
+            want, want)
+
+    @pytest.mark.parametrize("kernel", fa.KERNELS)
+    def test_chooser_gives_4096_the_large_tile(self, kernel):
+        bq, bk = fa.choose_blocks(4096, 4096, 128, jnp.bfloat16, kernel)
+        assert 4096 % bq == 0 and 4096 % bk == 0
+        assert bq * bk >= 512 * 512
+        assert fa._vmem_bytes(kernel, bq, bk, 128, 2) <= fa.VMEM_BUDGET_BYTES
+
+    @pytest.mark.parametrize("d", [64, 128, 256])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_chooser_fits_the_budget_at_every_width(self, d, dtype):
+        size = jnp.dtype(dtype).itemsize
+        for kernel in fa.KERNELS:
+            bq, bk = fa.choose_blocks(4096, 4096, d, dtype, kernel)
+            assert bq % 128 == 0 and bk % 128 == 0
+            assert fa._vmem_bytes(kernel, bq, bk, d, size) \
+                <= fa.VMEM_BUDGET_BYTES
+            # nothing that fits has fewer grid steps
+            assert all(
+                fa._vmem_bytes(kernel, cq, ck, d, size)
+                > fa.VMEM_BUDGET_BYTES
+                for cq in (512, 1024) for ck in (512, 1024)
+                if cq * ck > bq * bk)
+
+    @pytest.mark.parametrize("d", [128, 256])
+    def test_float32_and_the_backward_get_no_larger_tile(self, d):
+        area = lambda dtype, kernel: int(np.prod(
+            fa.choose_blocks(4096, 4096, d, dtype, kernel)))
+        for kernel in fa.KERNELS:
+            assert area(jnp.float32, kernel) <= area(jnp.bfloat16, kernel)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            assert area(dtype, fa.BWD_DQ) <= area(dtype, fa.FWD)
+            assert area(dtype, fa.BWD_DKV) <= area(dtype, fa.FWD)
+        # the backward holds more [bq, bk] tiles than the forward
+        assert fa._vmem_bytes(fa.BWD_DKV, 512, 512, d, 2) \
+            > fa._vmem_bytes(fa.BWD_DQ, 512, 512, d, 2) \
+            > fa._vmem_bytes(fa.FWD, 512, 512, d, 2)
+
+    def test_chooser_breaks_a_tie_as_the_chip_measured(self, monkeypatch):
+        """Where the square does not fit, the forward keeps the wide
+        block_k and the backward the tall block_q."""
+        monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 20 * 2**20)
+        pick = lambda kernel: fa.choose_blocks(
+            4096, 4096, 128, jnp.bfloat16, kernel)
+        assert pick(fa.FWD) == (512, 1024)
+        assert pick(fa.BWD_DQ) == pick(fa.BWD_DKV) == (1024, 512)
+
+    def test_chooser_short_and_unequal_lengths(self):
+        # a length under 128 is one block; each side gets its own divisor
+        assert fa.choose_blocks(64, 64, 64, jnp.float32, fa.FWD) == (64, 64)
+        bq, bk = fa.choose_blocks(1024, 2176, 128, jnp.bfloat16, fa.FWD)
+        assert (bq, bk) == (1024, 128)
+
+    def test_indivisible_length_is_refused(self):
+        q = jnp.zeros((1, 200, 1, 64), jnp.float32)
+        with pytest.raises(ValueError, match="divisible by the block"):
+            flash_attention(q, q, q)
+        q = jnp.zeros((1, 512, 1, 64), jnp.float32)
+        with pytest.raises(ValueError, match="block_q=384"):
+            flash_attention(q, q, q, block_q=384)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128)])
+    def test_unequal_blocks_match_math(self, block_q, block_k, causal,
+                                       dtype):
+        """Four blocks of the larger side: causal calls meet skipped,
+        diagonal and interior blocks."""
+        q, k, v, w = _inputs(10, 1024, 1024, dtype)
+        got = _out_and_grads(
+            lambda *a: flash_attention(
+                *a, causal=causal, block_q=block_q, block_k=block_k),
+            q, k, v, w)
+        want = _out_and_grads(
+            lambda *a: _math_sdpa(*a, causal), q, k, v, w)
+        assert _worst(got, want) < _TOL[dtype]
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128)])
+    def test_unequal_lengths_unequal_blocks(self, block_q, block_k, dtype):
+        q, k, v, w = _inputs(11, 512, 1024, dtype)
+        got = _out_and_grads(
+            lambda *a: flash_attention(
+                *a, causal=False, block_q=block_q, block_k=block_k),
+            q, k, v, w)
+        want = _out_and_grads(
+            lambda *a: _math_sdpa(*a, False), q, k, v, w)
+        assert _worst(got, want) < _TOL[dtype]
+
+    @pytest.mark.parametrize("block_q,block_k",
+                             [(256, 256), (512, 128), (128, 512)])
+    def test_causal_schedule_agrees_with_128_tiles(self, block_q, block_k):
+        """Whole blocks above the diagonal (their index maps clamped to
+        the resident block) and unmasked blocks below it: same answer as
+        the 128 x 128 sweep and as the math form."""
+        q, k, v, w = _inputs(12, 1024, 1024, jnp.float32)
+        run = lambda bq, bk: _out_and_grads(
+            lambda *a: flash_attention(
+                *a, causal=True, block_q=bq, block_k=bk), q, k, v, w)
+        got = run(block_q, block_k)
+        assert _worst(got, run(128, 128)) < 2e-5
+        assert _worst(got, _out_and_grads(
+            lambda *a: _math_sdpa(*a, True), q, k, v, w)) < 2e-5
+
+    def test_causal_with_more_keys_than_queries(self):
+        """k blocks no q block sees get zero gradients; the clamped index
+        maps stay inside the arrays."""
+        q, k, v, w = _inputs(13, 256, 512, jnp.float32)
+        got = _out_and_grads(
+            lambda *a: flash_attention(
+                *a, causal=True, block_q=128, block_k=128), q, k, v, w)
+        want = _out_and_grads(
+            lambda *a: _math_sdpa(*a, True), q, k, v, w)
+        assert _worst(got, want) < 2e-5
+        assert not got[2][:, 256:].any() and not got[3][:, 256:].any()
+
+    @pytest.mark.parametrize("seq", [2176, 4096, 4864])
+    def test_chosen_tiles_lower_to_mosaic(self, seq):
+        """The serving buckets' lengths (17 x 128, 19 x 256) and the
+        training cell's trace and lower as Mosaic kernels with the tiles
+        the chooser gives them (the lowering needs no TPU; what the TPU's
+        compiler then refuses, the described-v5e compile shows)."""
+        from unittest import mock
+
+        q = jax.ShapeDtypeStruct((1, seq, 2, 128), jnp.bfloat16)
+        grad = jax.grad(lambda *a: flash_attention(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+        with mock.patch.object(_compat, "on_tpu", lambda: True):
+            text = jax.jit(grad).trace(q, q, q).lower(
+                lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 3
+
+    def test_flash_blocks_series_moves_once_a_traced_call(self):
+        q, k, v, w = _inputs(14, 256, 256, jnp.float32)
+        chosen = {
+            kernel: (kernel, *fa.choose_blocks(256, 256, 64, q.dtype, kernel))
+            for kernel in fa.KERNELS}
+        moved = lambda before: {
+            key: n - before.get(key, 0)
+            for key, n in _compat.flash_blocks().items()
+            if n != before.get(key, 0)}
+
+        fwd = jax.jit(lambda *a: flash_attention(*a, causal=True))
+        before = _compat.flash_blocks()
+        fwd(q, k, v)
+        fwd(q, k, v)  # compiled: not traced again
+        assert moved(before) == {chosen[fa.FWD]: 1}
+
+        before = _compat.flash_blocks()
+        jax.jit(jax.grad(
+            lambda *a: flash_attention(*a, causal=True).sum()))(q, k, v)
+        assert moved(before) == {chosen[kernel]: 1 for kernel in fa.KERNELS}
+
+        # a caller's blocks win, for all three kernels
+        before = _compat.flash_blocks()
+        jax.grad(lambda *a: flash_attention(
+            *a, causal=True, block_q=128, block_k=64).sum())(q, k, v)
+        assert moved(before) == {
+            (kernel, 128, 64): 1 for kernel in fa.KERNELS}
+        from paddle_tpu.observability import get_registry
+
+        assert 'paddle_tpu_kernels_flash_blocks{block_k="64",block_q="128"' \
+            in get_registry().render_prometheus()
+
+
+# ----------------------------------------------------------------------
 # grouped_matmul: ragged grouped GEMM (interpret-mode kernel vs the
 # ragged_dot fallback vs an explicit numpy oracle)
 # ----------------------------------------------------------------------
