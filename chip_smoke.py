@@ -10,6 +10,11 @@ parameters; weights random from a seed):
   kernels  flash_attention (fwd+bwd), paged_attention (bf16 and int8 pool),
            grouped_matmul (bf16 and int8 rhs): compiled by Mosaic, not
            interpreted, and equal to their XLA fallbacks within a tolerance.
+  hybrid   gated_delta_rule (fwd+bwd kernels) against its jax.numpy chunked
+           form, and an expert layer that holds 8 of 64 experts
+           (incubate.moe.MoELayer(held=), grouped_matmul with its dlhs and
+           drhs kernels) against the same layer on the XLA path, in one
+           pass over its rows and in four.
   train    AdamW(multi_precision) + jit.TrainStep (donation on) fed by the
            forked-worker DataLoader, seq 2048: loss finite and falling,
            traced and compiled once, parameters on the TPU.
@@ -231,6 +236,115 @@ def phase_kernels():
         err = _rel_err(got, ref)
         c.check(f"grouped_matmul ({label}) == XLA segment path", err <= tol,
                 f"rel err {err:.2e} (tol {tol})")
+    c.done()
+
+
+# ---------------------------------------------------------------- hybrid
+def phase_hybrid():
+    """The kernels and the layer that Qwen3-Next's training path brought:
+    through Mosaic, and equal to their jax.numpy forms."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.moe import MoELayer
+    from paddle_tpu.kernels.pallas import gated_delta_rule as gdr
+
+    c = Checks("hybrid")
+    bf16, tol = jnp.bfloat16, 2e-2
+    ks = jax.random.split(jax.random.key(1), 7)
+    b, t, hk, hv, d = 2, 1024, 4, 8, 128
+    q = jax.random.normal(ks[0], (b, t, hk, d))
+    k = jax.random.normal(ks[1], (b, t, hk, d))
+    q = (q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+         ).astype(bf16)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(bf16)
+    v, w = (jax.random.normal(r, (b, t, hv, d), bf16) for r in ks[2:4])
+    g = -jnp.exp(jax.random.uniform(ks[4], (b, t, hv), minval=-4.0,
+                                    maxval=2.5))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (b, t, hv)))
+
+    def loss(impl):
+        def fn(q, k, v, g, beta):
+            out = gdr.gated_delta_rule(q, k, v, g, beta, impl=impl)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        return jax.value_and_grad(fn, argnums=(0, 1, 2, 3, 4), has_aux=True)
+
+    kernel, is_mosaic = _mosaic(loss("pallas"), q, k, v, g, beta)
+    c.check("gated_delta_rule fwd+bwd lowered to tpu_custom_call", is_mosaic)
+    (_, out), grads = kernel(q, k, v, g, beta)
+    (_, ref), ref_grads = jax.jit(loss("xla"))(q, k, v, g, beta)
+    err = _rel_err(out, ref)
+    c.check("gated_delta_rule forward == chunked jax.numpy form",
+            err <= tol, f"rel err {err:.2e} (tol {tol})")
+    for name, a, r in zip(("q", "k", "v", "g", "beta"), grads, ref_grads):
+        err = _rel_err(a, r)
+        c.check(f"gated_delta_rule d{name} == chunked jax.numpy form",
+                err <= tol, f"rel err {err:.2e} (tol {tol})")
+
+    # an expert layer holding 8 of 64 experts: kernel path against the
+    # XLA path of the same layer, outputs and every gradient
+    paddle.seed(0)
+    with paddle.nn.initializer.param_init_override(dtype="bfloat16"):
+        layer = MoELayer(1024, 64, d_ff=512, k=8, held=(16, 8),
+                         router_dtype="float32")
+    x = jax.random.normal(ks[6], (2, 2048, 1024), bf16)
+    names = [n for n, _ in layer.named_parameters()]
+
+    def layer_loss(arrays, x, impl, rows):
+        old = [p._data for p in layer.parameters()]
+        for p, a in zip(layer.parameters(), arrays):
+            p._data = a
+        try:
+            with paddle.no_grad():
+                flat = paddle.to_tensor(x.reshape(-1, 1024))
+                tok, rw, load = paddle.ops.moe_held_dispatch(
+                    flat, paddle.ops.moe_router_logits(
+                        flat, layer.gate.weight),
+                    k=8, start=16, count=8, rows=rows)
+                ex = layer.experts
+                out = paddle.ops.moe_held_experts(
+                    flat, ex.w_gate, ex.w_up, ex.w_down, tok, rw, load,
+                    rows=rows, impl=impl)
+            return jnp.sum(out._data.astype(jnp.float32) ** 2), (
+                out._data, load._data)
+        finally:
+            for p, a in zip(layer.parameters(), old):
+                p._data = a
+
+    arrays = [p._data for p in layer.parameters()]
+    one_pass = layer.held_rows(4096)
+    run = lambda impl, rows=one_pass: jax.value_and_grad(
+        lambda a, x: layer_loss(a, x, impl, rows), argnums=(0, 1),
+        has_aux=True)
+    kernel, is_mosaic = _mosaic(run("pallas"), arrays, x)
+    c.check("held-experts layer lowered to tpu_custom_call", is_mosaic)
+    (_, (out, load)), (dw, dx) = kernel(arrays, x)
+    (_, (ref, _)), (rdw, rdx) = jax.jit(run("xla"))(arrays, x)
+    load = np.asarray(load)
+    c.check("8 of 64 experts held: about an eighth of the assignments",
+            0.08 < load.sum() / (4096 * 8) < 0.18, f"expert_load {load}")
+    err = _rel_err(out, ref)
+    c.check("held-experts layer forward == XLA path", err <= tol,
+            f"rel err {err:.2e} (tol {tol})")
+    err = _rel_err(dx, rdx)
+    c.check("held-experts layer dx == XLA path", err <= tol,
+            f"rel err {err:.2e} (tol {tol})")
+    for name, a, r in zip(names, dw, rdw):
+        err = _rel_err(a, r)
+        c.check(f"held-experts layer d {name} == XLA path", err <= tol,
+                f"rel err {err:.2e} (tol {tol})")
+    # the same step worked off in passes of 1024 rows: what a router does
+    # that sends this rank more than a pass holds
+    passes = -(-int(load.sum()) // 1024)
+    (_, (out_p, _)), (dw_p, dx_p) = jax.jit(run("pallas", 1024))(arrays, x)
+    c.check("held-experts layer: one pass holds the step, 1024 rows do not",
+            int(load.sum()) <= one_pass and passes >= 3,
+            f"{int(load.sum())} rows, one pass {one_pass}, {passes} passes")
+    worst = max(_rel_err(a, b) for a, b in zip(
+        [out_p, dx_p, *dw_p], [out, dx, *dw]))
+    c.check(f"held-experts layer in {passes} passes == in one, output and "
+            "every gradient", worst <= tol, f"rel err {worst:.2e} (tol {tol})")
     c.done()
 
 
@@ -550,6 +664,7 @@ def result_line(device):
 def main():
     device, cache_root = phase_device()
     phase_kernels()
+    phase_hybrid()
     losses = phase_train()
     gc.collect()
     out = phase_serve(cache_root)

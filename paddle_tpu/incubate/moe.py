@@ -18,6 +18,16 @@ the reference launches by hand (global_scatter/global_gather).
 
 Capacity padding (factor 1.25) bounds the wasted expert FLOPs of the
 dense path at ~25%. Step time on the current stack: not measured.
+
+A layer can be told which experts it holds (``held=(start, count)``):
+one expert-parallel rank's share of a layer whose router keeps all its
+outputs. It routes over every expert, keeps the assignments whose expert
+it holds, and computes their part of the result on the ragged path with
+no capacity and no drops; what the absent experts would add is left out
+(their rank adds it). ``shared_expert=`` is computed whole beside it
+behind a sigmoid gate, and ``router_dtype="float32"`` takes the router's
+product in float32. The int32 buffer ``expert_load`` holds the rows each
+held expert was sent in the last step.
 """
 from __future__ import annotations
 
@@ -28,6 +38,11 @@ from ..nn.layer.layers import Layer
 from ..nn.parameter import ParamAttr
 
 __all__ = ["TopKGate", "MoELayer", "SwiGLUExperts"]
+
+
+# one pass over a held layer's kept assignments holds this many times the
+# share a uniform router sends here (MoELayer.held_rows)
+HELD_ROW_SLACK = 2.0
 
 
 class TopKGate(Layer):
@@ -178,7 +193,16 @@ class MoELayer(Layer):
 
     def __init__(self, d_model, num_experts, d_ff=None, k=2,
                  capacity_factor=1.25, gate=None, experts=None,
-                 impl="dense"):
+                 impl="dense", held=None, shared_expert=None,
+                 router_dtype=None):
+        """``held=(start, count)``: the layer holds ``count`` of the
+        ``num_experts`` the router chooses among (its expert weights are
+        ``[count, ...]``) and computes their part alone, on the ragged
+        path. ``shared_expert``: a callable that makes a Layer ``[n, m] ->
+        [n, m]``, added whole behind ``sigmoid(x @ shared_gate)``; it is
+        called after the routed experts exist, so parameters() lists them
+        in that order. ``router_dtype``: the dtype the router's product is
+        taken in (None: the activations')."""
         super().__init__()
         if impl not in ("dense", "ragged"):
             raise ValueError(
@@ -187,11 +211,34 @@ class MoELayer(Layer):
             )
         self.d_model = d_model
         self.num_experts = num_experts
+        self.held = None
+        if held is not None:
+            start, count = (int(v) for v in held)
+            if not (0 <= start and 0 < count
+                    and start + count <= num_experts):
+                raise ValueError(
+                    f"MoELayer held=({start}, {count}) is not a range of "
+                    f"the {num_experts} experts")
+            if gate is not None or experts is not None:
+                raise ValueError(
+                    "MoELayer(held=) routes with the stock TopKGate over "
+                    "its own SwiGLUExperts")
+            self.held, impl = (start, count), "ragged"
+        self.router_dtype = router_dtype
         self.gate = gate or TopKGate(d_model, num_experts, k,
                                      capacity_factor)
         self.experts = experts or SwiGLUExperts(
-            num_experts, d_model, d_ff or 4 * d_model
+            num_experts if held is None else self.held[1], d_model,
+            d_ff or 4 * d_model
         )
+        self.shared_expert = shared_expert and shared_expert()
+        if shared_expert is not None:
+            from ..nn.layer.common import Linear
+
+            self.shared_gate = Linear(d_model, 1, bias_attr=False)
+        if self.held is not None:
+            self.register_buffer(
+                "expert_load", F.zeros([self.held[1]], "int32"))
         # "dense": the capacity-padded [e, c, m] grouped einsum (the
         # bit-reference path). "ragged": dropless sort-by-expert +
         # ragged grouped_matmul over contiguous expert segments — no
@@ -212,6 +259,56 @@ class MoELayer(Layer):
                 )
         self.impl = impl
 
+    def held_rows(self, tokens):
+        """The rows of one pass over the kept assignments (the static
+        length of its buffers): ``HELD_ROW_SLACK`` times the share a uniform
+        router sends here, all of them when every expert is held. A step
+        that is sent more takes further passes; none is dropped."""
+        k, e = self.gate.k, self.num_experts
+        count = self.held[1]
+        if count == e:
+            return tokens * k
+        rows = int(np.ceil(HELD_ROW_SLACK * tokens * k * count / e))
+        return min(tokens * k, -(-rows // 128) * 128)
+
+    def record_load(self, load):
+        """Keep the rows each held expert was sent this step."""
+        self.expert_load._rebind(load.detach()._data)
+
+    def _forward_held(self, flat):
+        """[n, m] -> ([n, m], expert_load): the held experts' part of the
+        routed sum, plus the shared expert."""
+        from ..core.autograd import scope
+        from ..observability import counter
+
+        start, count = self.held
+        k = self.gate.k
+        counter(
+            "paddle_tpu_moe_held",
+            "Traced calls of an expert layer that holds a share",
+            labelnames=("experts", "held", "k"),
+        ).inc(experts=self.num_experts, held=count, k=k)
+        n = flat.shape[0]
+        with scope("moe.router"):
+            if self.router_dtype is None:
+                logits = F.matmul(flat, self.gate.weight)
+            else:
+                logits = F.moe_router_logits(
+                    flat, self.gate.weight, dtype=self.router_dtype)
+            rows = self.held_rows(n)
+            row_token, row_weight, load = F.moe_held_dispatch(
+                flat, logits, k=k, start=start, count=count, rows=rows)
+        with scope("moe.experts"):
+            ex = self.experts
+            out = F.moe_held_experts(
+                flat, ex.w_gate, ex.w_up, ex.w_down, row_token, row_weight,
+                load, rows=rows)
+        if self.shared_expert is not None:
+            with scope("moe.shared_expert"):
+                out = out + F.sigmoid(self.shared_gate(flat)) * (
+                    self.shared_expert(flat))
+        return out, load
+
     def forward(self, x, return_stats=False):
         """[b, s, m] -> ([b, s, m], aux_loss). With return_stats=True a
         third dict carries token-drop counters (host diagnostics; do not
@@ -223,6 +320,17 @@ class MoELayer(Layer):
         called for (dispatch [s,e,c], combine [s,e,c], aux)."""
         b, s, m = x.shape
         flat = F.reshape(x, [b * s, m])
+        if self.held is not None:
+            out, load = self._forward_held(flat)
+            out = F.reshape(out, [b, s, m])
+            aux = F.zeros([], "float32")
+            if return_stats:
+                # inside a recomputed segment the caller carries the load
+                # out and calls record_load there (a buffer written inside
+                # jax.checkpoint would keep a dead tracer)
+                return out, aux, {"expert_load": load}
+            self.record_load(load)
+            return out, aux
         if type(self.gate) is not TopKGate:
             dispatch, combine, aux = self.gate(flat)
             dispatched = F.einsum("sec,sm->ecm", dispatch, flat)

@@ -31,8 +31,19 @@ scales, so no dense float copy of the weights ever exists.
 Fallback: ``grouped_matmul_xla`` — the same contraction as a pure-XLA
 sort/segment program (tile-aligned segment padding + one batched
 matmul). ``impl="auto"`` takes it off-TPU and for dtypes the kernel
-body does not handle. Both paths are differentiable: the custom VJP
-computes the kernel's grads through the fallback's contraction.
+body does not handle. Both paths are differentiable. The kernel's VJP
+is two more kernels over the same staircase: ``grouped_matmul_dlhs``
+(``dlhs[i] = g[i] @ rhs[g(i)]^T``, the forward body contracting the
+weight block's last dim, so no transposed copy of the weights exists)
+and ``grouped_matmul_drhs`` (``drhs[e] = lhs[rows of e]^T @ g[rows of
+e]``, accumulated over an expert's row tiles and stored once an expert;
+an expert without rows is visited once and stores zeros).
+
+Tiles: ``tm`` rows (128), and by default the widest column block whose
+weight block ``[k, tn]`` stays within 2 MiB: an expert of 2048 x 512
+bf16 is one block, fetched once an expert. Operands go to the MXU in
+their own dtype when lhs and rhs share it (bf16 stays bf16), float32
+accumulation always.
 
 Contract: ``sum(group_sizes) == lhs.shape[0]`` — every row belongs to a
 group (the MoE dispatch guarantees this); rows beyond the sum are
@@ -54,25 +65,40 @@ from ._compat import pl_call
 __all__ = ["grouped_matmul", "grouped_matmul_xla"]
 
 DEFAULT_TM = 128
-DEFAULT_TN = 128
+# the weight block [k, tn] (and the drhs accumulator's float32 [tk, tn])
+# that one grid step holds
+_WEIGHT_BLOCK_BYTES = 2 * 2**20
+_VMEM_LIMIT_BYTES = 64 * 2**20
 
 
-def _group_metadata(group_sizes, num_row_tiles, tm):
+def _column_block(width, depth, itemsize):
+    """The widest multiple of 128 dividing ``width`` whose [depth, block]
+    weight block fits _WEIGHT_BLOCK_BYTES (an odd width: the whole)."""
+    if width % 128:
+        return width
+    fits = [c for c in range(128, width + 1, 128)
+            if width % c == 0 and depth * c * itemsize <= _WEIGHT_BLOCK_BYTES]
+    return max(fits) if fits else 128
+
+
+def _group_metadata(group_sizes, num_row_tiles, tm, visit_empty=False):
     """The (group, tile) staircase as four [T] int32 arrays, T =
     num_row_tiles + num_groups (static): per work item its row tile,
     its group, and the [lo, hi) global-row span of that group (lo == hi
     marks an inactive padding item). Computed with XLA ops over
     [e]-sized arrays — cheap, and legal inside a jit (the group sizes
-    are traced data)."""
+    are traced data). ``visit_empty`` gives a group without rows one
+    item of its own (the drhs kernel stores that expert's zeros from
+    it)."""
     e = group_sizes.shape[0]
     sizes = group_sizes.astype(jnp.int32)
     offs = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)]
     )
     start, end = offs[:-1], offs[1:]
-    first = start // tm
+    first = jnp.minimum(start // tm, num_row_tiles - 1)
     last = jnp.where(sizes > 0, (end - 1) // tm, first)
-    count = jnp.where(sizes > 0, last - first + 1, 0)
+    count = jnp.where(sizes > 0, last - first + 1, int(visit_empty))
     istart = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(count)]
     )  # [e+1]; istart[g] = first work item of group g
@@ -96,8 +122,15 @@ def _group_metadata(group_sizes, num_row_tiles, tm):
     return tile_id, gid, lo, hi
 
 
+def _operands(x, w):
+    """Both in their own dtype when they share it, else float32."""
+    if x.dtype == w.dtype:
+        return x, w
+    return x.astype(jnp.float32), w.astype(jnp.float32)
+
+
 def _gmm_kernel(tile_ref, gid_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref,
-                acc_scr, *, tm, n_items, quant):
+                acc_scr, *, tm, n_items, quant, transpose_rhs=False):
     t = pl.program_id(1)
     tile = tile_ref[t]
     prev = tile_ref[jnp.maximum(t - 1, 0)]
@@ -107,10 +140,9 @@ def _gmm_kernel(tile_ref, gid_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref,
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[...].astype(jnp.float32)          # [tm, k]
-    w = w_ref[0].astype(jnp.float32)            # [k, tn]
-    contrib = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
+    x, w = _operands(x_ref[...], w_ref[0])      # [tm, k]; [k, tn] or
+    contrib = jax.lax.dot_general(              # transposed, [tn, k]
+        x, w, (((1,), (1 if transpose_rhs else 0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                           # [tm, tn]
     row = tile * tm + jax.lax.broadcasted_iota(
@@ -154,14 +186,26 @@ def _gmm_kernel_quant(tile_ref, gid_ref, lo_ref, hi_ref, x_ref, w_ref,
         o_ref[...] = acc_scr[:].astype(o_ref.dtype)
 
 
-def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn):
-    n, k = lhs.shape
-    e, _, m = rhs.shape
+def _row_tiles(lhs, tm):
+    """(tm, lhs padded to whole row tiles, their number)."""
+    n = lhs.shape[0]
     tm = max(8, min(tm, -(-n // 8) * 8))
     n_pad = -(-n // tm) * tm
     if n_pad != n:
         lhs = jnp.pad(lhs, ((0, n_pad - n), (0, 0)))
-    num_row_tiles = n_pad // tm
+    return tm, lhs, n_pad // tm
+
+
+def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn,
+                    transpose_rhs=False):
+    """``transpose_rhs``: rhs is [e, m, k] and each expert multiplies by
+    its transpose (the VJP's dlhs, on the forward's own weights)."""
+    n, k = lhs.shape
+    e, m = rhs.shape[0], rhs.shape[1 if transpose_rhs else 2]
+    tm, lhs, num_row_tiles = _row_tiles(lhs, tm)
+    n_pad = num_row_tiles * tm
+    if tn is None:
+        tn = _column_block(m, k, rhs.dtype.itemsize)
     tn = min(tn, m)
     if m % tn:
         tn = m  # odd widths: one block over m (interpret/CPU path)
@@ -176,6 +220,8 @@ def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn):
     in_specs = [
         pl.BlockSpec((tm, k), lambda j, t, tile, gid, lo, hi: (tile[t], 0)),
         pl.BlockSpec(
+            (1, tn, k), lambda j, t, tile, gid, lo, hi: (gid[t], j, 0)
+        ) if transpose_rhs else pl.BlockSpec(
             (1, k, tn), lambda j, t, tile, gid, lo, hi: (gid[t], 0, j)
         ),
     ]
@@ -189,11 +235,12 @@ def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn):
         ))
         operands.append(rhs_scales.astype(jnp.float32)[:, None, :])
 
+    extra = {"transpose_rhs": True} if transpose_rhs else {}
     out = pl_call(
         functools.partial(
-            kernel, tm=tm, n_items=n_items, quant=quant,
+            kernel, tm=tm, n_items=n_items, quant=quant, **extra,
         ),
-        name="grouped_matmul",
+        name="grouped_matmul_dlhs" if transpose_rhs else "grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(num_col_tiles, n_items),
@@ -204,9 +251,78 @@ def _gmm_pallas_raw(lhs, rhs, group_sizes, rhs_scales, tm, tn):
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, m), lhs.dtype),
-        dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
     )(tile_id, gid, lo, hi, *operands)
     return out[:n]
+
+
+def _drhs_kernel(tile_ref, gid_ref, lo_ref, hi_ref, x_ref, g_ref, o_ref,
+                 acc_scr, *, tm, n_items):
+    """One (group, row tile) item of ``drhs[e] = lhs_e^T @ g_e``: the
+    float32 [tk, tn] accumulator carries across an expert's row tiles
+    and is stored when the next item is another expert's. Rows outside
+    the item's span are zeroed in both operands (rows past the last
+    group hold anything, and 0 x NaN is NaN)."""
+    t = pl.program_id(2)
+    gid = gid_ref[t]
+    prev = gid_ref[jnp.maximum(t - 1, 0)]
+    nxt = gid_ref[jnp.minimum(t + 1, n_items - 1)]
+
+    @pl.when((t == 0) | (prev != gid))
+    def _init():
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    row = tile_ref[t] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, (tm, 1), 0)
+    mask = (row >= lo_ref[t]) & (row < hi_ref[t])
+    x, g = _operands(x_ref[...], g_ref[...])
+    x = jnp.where(mask, x, jnp.zeros_like(x))
+    g = jnp.where(mask, g, jnp.zeros_like(g))
+    acc_scr[:] += jax.lax.dot_general(
+        x, g, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                           # [tk, tn]
+
+    @pl.when((t == n_items - 1) | (nxt != gid))
+    def _store():
+        o_ref[0] = acc_scr[:].astype(o_ref.dtype)
+
+
+def _drhs_pallas(lhs, g, group_sizes, num_groups, tm, out_dtype):
+    """[e, k, m]: every expert's ``lhs_e^T @ g_e`` over the staircase."""
+    k, m = lhs.shape[1], g.shape[1]
+    tm, lhs, num_row_tiles = _row_tiles(lhs, tm)
+    g = _row_tiles(g, tm)[1]
+    # float32 accumulator [tk, tn] within _WEIGHT_BLOCK_BYTES x 2
+    tn = _column_block(m, min(k, 512), 4)
+    tk = _column_block(k, tn, 2)
+    n_items = num_row_tiles + num_groups
+    tile_id, gid, lo, hi = _group_metadata(
+        group_sizes, num_row_tiles, tm, visit_empty=True)
+    return pl_call(
+        functools.partial(_drhs_kernel, tm=tm, n_items=n_items),
+        name="grouped_matmul_drhs",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(k // tk, m // tn, n_items),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda i, j, t, tile, gid, lo, hi:
+                             (tile[t], i)),
+                pl.BlockSpec((tm, tn), lambda i, j, t, tile, gid, lo, hi:
+                             (tile[t], j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn), lambda i, j, t, tile, gid, lo, hi:
+                (gid[t], i, j)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((num_groups, k, m), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+    )(tile_id, gid, lo, hi, lhs, g)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -221,18 +337,13 @@ def _gmm_pallas_fwd(lhs, rhs, group_sizes, tm, tn):
 
 
 def _gmm_pallas_bwd(tm, tn, res, g):
-    # grads via the XLA fallback's contraction (dlhs = g @ rhs[gid]^T
-    # per segment, drhs = the segment-wise outer products); a dedicated
-    # Pallas backward kernel is a follow-up — training through the
-    # ragged path stays correct either way
     lhs, rhs, group_sizes = res
     import numpy as np
 
-    _, vjp = jax.vjp(
-        lambda a, b: grouped_matmul_xla(a, b, group_sizes),
-        lhs, rhs,
-    )
-    dlhs, drhs = vjp(g)
+    g = g.astype(lhs.dtype)
+    dlhs = _gmm_pallas_raw(g, rhs, group_sizes, None, tm, None,
+                           transpose_rhs=True)
+    drhs = _drhs_pallas(lhs, g, group_sizes, rhs.shape[0], tm, rhs.dtype)
     # integer primal -> symbolic-zero (float0) tangent
     zero_gs = np.zeros(group_sizes.shape, jax.dtypes.float0)
     return dlhs, drhs, zero_gs
@@ -306,7 +417,7 @@ def _kernel_dtypes(lhs, rhs):
 
 
 def grouped_matmul(lhs, rhs, group_sizes, *, rhs_scales=None,
-                   impl="auto", tm=DEFAULT_TM, tn=DEFAULT_TN):
+                   impl="auto", tm=DEFAULT_TM, tn=None):
     """Ragged grouped GEMM: ``out[i] = lhs[i] @ rhs[g(i)]``.
 
     lhs: [n, k] rows sorted by group; rhs: [e, k, m] stacked expert
@@ -323,8 +434,9 @@ def grouped_matmul(lhs, rhs, group_sizes, *, rhs_scales=None,
         parity-testing path.
       * ``"xla"`` — always the fallback.
 
-    The float path is differentiable (custom VJP, grads via
-    ``ragged_dot``); the int8 path is inference-only.
+    The float path is differentiable (the kernel's custom VJP is the
+    ``grouped_matmul_dlhs`` and ``grouped_matmul_drhs`` kernels; the
+    fallback by construction); the int8 path is inference-only.
     """
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(

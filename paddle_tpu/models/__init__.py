@@ -2,8 +2,10 @@
 configs; vision models live in paddle_tpu.vision.models)."""
 from .dit import DiT, DiTConfig, dit_b_4, dit_xl_2
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel
+from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 
 __all__ = [
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
+    "Qwen3NextConfig", "Qwen3NextForCausalLM",
     "DiT", "DiTConfig", "dit_xl_2", "dit_b_4",
 ]

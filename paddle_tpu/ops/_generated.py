@@ -14,6 +14,7 @@ from .impl import activation as _impl_activation
 from .impl import creation as _impl_creation
 from .impl import fft_ops as _impl_fft_ops
 from .impl import fused_ops as _impl_fused_ops
+from .impl import gdn_ops as _impl_gdn_ops
 from .impl import linalg as _impl_linalg
 from .impl import logic as _impl_logic
 from .impl import manipulation as _impl_manipulation
@@ -2278,6 +2279,36 @@ def grouped_matmul(lhs, rhs, group_sizes, rhs_scales=None, impl="auto", name=Non
     return _call('grouped_matmul', _impl_moe_ops.grouped_matmul, (lhs, rhs, group_sizes, rhs_scales,), {'impl': impl})
 
 
+def moe_router_logits(x, weight, dtype="float32", name=None):
+    """moe_router_logits (generated from ops.yaml)."""
+    return _call('moe_router_logits', _impl_moe_ops.moe_router_logits, (x, weight,), {'dtype': dtype})
+
+
+def moe_held_dispatch(x, gate_logits, k, start, count, rows, renormalize=True, name=None):
+    """moe_held_dispatch (generated from ops.yaml)."""
+    return _call('moe_held_dispatch', _impl_moe_ops.moe_held_dispatch, (x, gate_logits,), {'k': k, 'start': start, 'count': count, 'rows': rows, 'renormalize': renormalize})
+
+
+def moe_held_experts(x, w_gate, w_up, w_down, row_token, row_weight, expert_load, rows, impl="auto", name=None):
+    """moe_held_experts (generated from ops.yaml)."""
+    return _call('moe_held_experts', _impl_moe_ops.moe_held_experts, (x, w_gate, w_up, w_down, row_token, row_weight, expert_load,), {'rows': rows, 'impl': impl})
+
+
+def zero_centered_rms_norm(x, weight, epsilon=1e-6, name=None):
+    """zero_centered_rms_norm (generated from ops.yaml)."""
+    return _call('zero_centered_rms_norm', _impl_gdn_ops.zero_centered_rms_norm, (x, weight,), {'epsilon': epsilon})
+
+
+def partial_rope_qk(q, k, rotary_dim, base=10000.0, name=None):
+    """partial_rope_qk (generated from ops.yaml)."""
+    return _call('partial_rope_qk', _impl_gdn_ops.partial_rope_qk, (q, k,), {'rotary_dim': rotary_dim, 'base': base})
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", name=None):
+    """gated_delta_rule (generated from ops.yaml)."""
+    return _call('gated_delta_rule', _impl_gdn_ops.gated_delta_rule, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl})
+
+
 def fused_linear_cross_entropy(x, weight, labels, chunk_size=4096, ignore_index=-100, name=None):
     """fused_linear_cross_entropy (generated from ops.yaml)."""
     return _call('fused_linear_cross_entropy', _impl_fused_ops.fused_linear_cross_entropy, (x, weight, labels,), {'chunk_size': chunk_size, 'ignore_index': ignore_index})
@@ -2949,6 +2980,7 @@ __all__ = [
     'fused_rotary_position_embedding',
     'gammaincc',
     'gammaln',
+    'gated_delta_rule',
     'gather',
     'gather_nd',
     'gather_tree',
@@ -3068,8 +3100,11 @@ __all__ = [
     'mode',
     'moe_combine',
     'moe_gate_dispatch',
+    'moe_held_dispatch',
+    'moe_held_experts',
     'moe_ragged_combine',
     'moe_ragged_dispatch',
+    'moe_router_logits',
     'moveaxis',
     'mse_loss',
     'multi_dot',
@@ -3097,6 +3132,7 @@ __all__ = [
     'overlap_add',
     'p_norm',
     'pad',
+    'partial_rope_qk',
     'pinv',
     'pixel_shuffle',
     'pixel_unshuffle',
@@ -3227,6 +3263,7 @@ __all__ = [
     'var',
     'vector_norm',
     'where',
+    'zero_centered_rms_norm',
     'zeros',
     'zeros_like',
 ]

@@ -14,6 +14,8 @@ one-hot formulation's O(s*e*c) dispatch/combine tensors.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -167,6 +169,170 @@ def moe_ragged_combine(y_sorted, order, combine_weights):
     w = combine_weights.reshape(-1)[order]            # weight per row
     weighted = y_sorted * w[:, None].astype(y_sorted.dtype)
     return jnp.zeros((s, m), y_sorted.dtype).at[order // k].add(weighted)
+
+
+def moe_router_logits(x, weight, *, dtype="float32"):
+    """x @ weight with both cast to ``dtype`` first. In float32 the
+    product is taken at ``highest`` precision (a TPU's default rounds
+    float32 operands to bf16), so that the experts a token is sent to do
+    not depend on the activations' dtype but for ties."""
+    dt = jnp.dtype(dtype)
+    return jnp.matmul(
+        x.astype(dt), weight.astype(dt),
+        precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None)
+
+
+def moe_held_dispatch(x, gate_logits, *, k, start, count, rows,
+                      renormalize=True):
+    """Routing for an expert layer that holds experts ``[start, start +
+    count)`` of the ``e`` the router chooses among (one expert-parallel
+    rank's share; the whole layer when ``count == e``).
+
+    Routing is over all ``e`` experts in float32: softmax, top-k, the
+    weights normalised over all k chosen (``norm_topk_prob``). The
+    assignments whose expert is held here are kept, every one of them,
+    and sorted by expert: the first ``sum(expert_load)`` entries of the
+    returned lists, each expert's one contiguous segment.
+
+    x: [s, m] (only its length is read); gate_logits: [s, e]. Returns
+    (row_token [n] int32: the token of each sorted assignment, row_weight
+    [n] float32: its combine weight, both 0 past the last kept one, with
+    ``n`` = s * k rounded up to whole passes of ``rows``; expert_load
+    [count] int32: the rows each held expert was sent)."""
+    s = x.shape[0]
+    gates = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+    vals, idx = jax.lax.top_k(gates, k)               # [s, k]
+    if renormalize:
+        vals = vals / vals.sum(-1, keepdims=True)
+    local = idx.reshape(-1).astype(jnp.int32) - start
+    # not held: the key `count`, which sorts behind every held expert
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    load = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    kept = jnp.arange(s * k, dtype=jnp.int32) < load.sum()
+    pad = (-(s * k)) % rows
+    row_token = jnp.pad(jnp.where(kept, order // k, 0), (0, pad))
+    row_weight = jnp.pad(
+        jnp.where(kept, vals.reshape(-1)[order], 0.0), (0, pad))
+    return row_token, row_weight, load
+
+
+def _swiglu_grouped(xs, sizes, w_gate, w_up, w_down, impl):
+    """Each group's SwiGLU expert over its own rows: three grouped
+    matmuls."""
+    from ...kernels.pallas.grouped_matmul import grouped_matmul as _gmm
+
+    g = _gmm(xs, w_gate, sizes, impl=impl)
+    u = _gmm(xs, w_up, sizes, impl=impl)
+    return _gmm(jax.nn.silu(g) * u, w_down, sizes, impl=impl)
+
+
+def _passes(load, rows):
+    """Passes a step takes: at least one (shapes are static, so a pass
+    without rows costs what a full one does, and a step's time does not
+    depend on how few rows the router sent)."""
+    return jnp.maximum(1, -(-load.sum() // rows))
+
+
+def _pass_rows(p, rows, row_token, row_weight, load):
+    """Pass p's window of the sorted assignments: their tokens, weights,
+    which of them are kept, and each expert's rows inside the window."""
+    base = p * rows
+    tok = jax.lax.dynamic_slice(row_token, (base,), (rows,))
+    w = jax.lax.dynamic_slice(row_weight, (base,), (rows,))
+    ends = jnp.cumsum(load)
+    kept = (base + jnp.arange(rows, dtype=jnp.int32)) < ends[-1]
+    sizes = jnp.diff(jnp.clip(ends - base, 0, rows), prepend=0)
+    return base, tok, w, kept[:, None], sizes.astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_experts(x, w_gate, w_up, w_down, row_token, row_weight, load,
+                  rows, impl):
+    n_pass = _passes(load, rows)
+
+    def one_pass(carry):
+        p, out = carry
+        _, tok, w, kept, sizes = _pass_rows(
+            p, rows, row_token, row_weight, load)
+        xs = jnp.where(kept, x[tok], 0)
+        ys = _swiglu_grouped(xs, sizes, w_gate, w_up, w_down, impl)
+        # rows past the last kept one hold anything (a grouped matmul
+        # leaves them unwritten): selected away, not multiplied
+        add = jnp.where(kept, ys.astype(jnp.float32) * w[:, None], 0.0)
+        return p + 1, out.at[tok].add(add)
+
+    _, out = jax.lax.while_loop(
+        lambda c: c[0] < n_pass, one_pass,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
+    return out.astype(x.dtype)
+
+
+def _held_experts_fwd(x, w_gate, w_up, w_down, row_token, row_weight, load,
+                      rows, impl):
+    out = _held_experts(x, w_gate, w_up, w_down, row_token, row_weight,
+                        load, rows, impl)
+    return out, (x, w_gate, w_up, w_down, row_token, row_weight, load)
+
+
+def _held_experts_bwd(rows, impl, res, d_out):
+    """The passes again: each recomputes its rows' expert outputs and
+    takes their vector-Jacobian product (the grouped matmul's own dlhs and
+    drhs kernels); nothing of a pass is kept between the two sweeps."""
+    import numpy as np
+
+    x, w_gate, w_up, w_down, row_token, row_weight, load = res
+    n_pass = _passes(load, rows)
+
+    def one_pass(carry):
+        p, dx, dwg, dwu, dwd, dw_rows = carry
+        base, tok, w, kept, sizes = _pass_rows(
+            p, rows, row_token, row_weight, load)
+        xs = jnp.where(kept, x[tok], 0)
+        ys, vjp = jax.vjp(
+            lambda a, b, c, d: _swiglu_grouped(a, sizes, b, c, d, impl),
+            xs, w_gate, w_up, w_down)
+        d_rows = d_out[tok].astype(jnp.float32)
+        d_ys = jnp.where(kept, d_rows * w[:, None], 0.0).astype(ys.dtype)
+        d_w = jnp.where(
+            kept[:, 0], jnp.sum(d_rows * ys.astype(jnp.float32), -1), 0.0)
+        d_xs, a, b, c = vjp(d_ys)
+        dx = dx.at[tok].add(jnp.where(kept, d_xs.astype(jnp.float32), 0.0))
+        return (p + 1, dx, dwg + a, dwu + b, dwd + c,
+                jax.lax.dynamic_update_slice(dw_rows, d_w, (base,)))
+
+    _, dx, dwg, dwu, dwd, dw_rows = jax.lax.while_loop(
+        lambda c: c[0] < n_pass, one_pass,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros_like(w_gate), jnp.zeros_like(w_up),
+         jnp.zeros_like(w_down), jnp.zeros_like(row_weight)))
+    no_grad = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (dx.astype(x.dtype), dwg, dwu, dwd, no_grad(row_token), dw_rows,
+            no_grad(load))
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def moe_held_experts(x, w_gate, w_up, w_down, row_token, row_weight,
+                     expert_load, *, rows, impl="auto"):
+    """The held experts' part of the routed sum, without drops and
+    without a capacity: ``out[t] = sum of row_weight[r] * E_g(r)(x[t])``
+    over the kept assignments r of token t (``moe_held_dispatch``'s
+    lists), E_g the SwiGLU expert ``(silu(x w_gate) * x w_up) w_down``.
+
+    The sorted assignments are worked off in passes of ``rows`` (a static
+    length: the buffers of one pass), as many as ``sum(expert_load)``
+    needs and never fewer than one: one when the router sends this rank
+    its share or less, more when it sends more. Each pass gathers its rows,
+    runs three grouped matmuls over the experts' segments and adds the
+    weighted rows to their tokens. The backward sweeps the passes again
+    and recomputes them.
+
+    x: [s, m]; w_gate, w_up: [count, m, f]; w_down: [count, f, m].
+    Returns [s, m] in x's dtype."""
+    return _held_experts(x, w_gate, w_up, w_down, row_token, row_weight,
+                         expert_load, int(rows), impl)
 
 
 def grouped_matmul(lhs, rhs, group_sizes, rhs_scales=None, *,

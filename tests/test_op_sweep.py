@@ -64,6 +64,9 @@ SKIP = {
     "moe_ragged_dispatch": "ragged routing contract owned by test_sp_moe",
     "moe_ragged_combine": "int32 order/weights contract owned by test_sp_moe",
     "grouped_matmul": "segment contract owned by test_pallas_kernels",
+    "moe_held_dispatch": "held-share routing owned by test_qwen3_next",
+    "moe_held_experts": "passes over kept rows owned by test_qwen3_next",
+    "gated_delta_rule": "owned by test_gated_delta_rule",
     "fused_linear_cross_entropy": "chunked loss owned by test_fused_loss",
     "fused_rotary_position_embedding": "owned by test_pallas_kernels",
     "rope_qk": "owned by test_pallas_kernels",
@@ -641,6 +644,14 @@ HINTS = {
         inputs=dict(x=_f((3,)),
                     sorted_sequence=np.sort(_f((5,), seed=1))),
         grad=False),
+    # ---- brought by the Qwen3-Next block ------------------------------------
+    "moe_router_logits": dict(inputs=dict(
+        x=_f((4, 8), -1, 1), weight=_f((8, 6), -1, 1, seed=1))),
+    "zero_centered_rms_norm": dict(inputs=dict(
+        x=_f((2, 8), -1, 1), weight=_f((8,), -0.5, 0.5, seed=1))),
+    "partial_rope_qk": dict(inputs=dict(
+        q=_f((1, 4, 2, 8), -1, 1), k=_f((1, 4, 1, 8), -1, 1, seed=1)),
+        attrs=dict(rotary_dim=4), out=0),
 }
 
 
