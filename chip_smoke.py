@@ -248,6 +248,7 @@ def phase_hybrid():
 
     import paddle_tpu as paddle
     from paddle_tpu.incubate.moe import MoELayer
+    from paddle_tpu.kernels.pallas import _compat
     from paddle_tpu.kernels.pallas import gated_delta_rule as gdr
 
     c = Checks("hybrid")
@@ -272,6 +273,11 @@ def phase_hybrid():
 
     kernel, is_mosaic = _mosaic(loss("pallas"), q, k, v, g, beta)
     c.check("gated_delta_rule fwd+bwd lowered to tpu_custom_call", is_mosaic)
+    steps = sorted(_compat.gdr_blocks())
+    c.check("gated_delta_rule's two kernels recorded their grid step "
+            "(kernel, key heads, chunks)",
+            {kernel for kernel, _, _ in steps}
+            == {"gated_delta_rule_fwd", "gated_delta_rule_bwd"}, str(steps))
     (_, out), grads = kernel(q, k, v, g, beta)
     (_, ref), ref_grads = jax.jit(loss("xla"))(q, k, v, g, beta)
     err = _rel_err(out, ref)

@@ -28,7 +28,9 @@ A fourth, smaller one: ``record_flash_blocks()`` — the flash kernels
 choose their tile from the call's shapes at trace time, and each traced
 kernel call bumps ``paddle_tpu_kernels_flash_blocks{kernel,block_q,
 block_k}``, so a test, ``chip_smoke.py`` or a reader of the metrics
-registry can say which tile a shape got.
+registry can say which tile a shape got. ``record_gdr_blocks()`` does the
+same for the two gated-delta-rule kernels' grid step
+(``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``).
 """
 from __future__ import annotations
 
@@ -153,4 +155,32 @@ def flash_blocks():
         (labels["kernel"], int(labels["block_q"]), int(labels["block_k"])):
         child.value
         for labels, child in _flash_blocks_counter()._series()
+    }
+
+
+def _gdr_blocks_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_gdr_blocks",
+        "Traced gated-delta-rule kernel calls by the grid step they were "
+        "given",
+        labelnames=("kernel", "key_heads", "chunks"),
+    )
+
+
+def record_gdr_blocks(kernel, key_heads, chunks):
+    """One traced call of ``kernel`` whose grid step holds ``key_heads``
+    key heads (with their value heads) over ``chunks`` chunks."""
+    _gdr_blocks_counter().inc(
+        kernel=kernel, key_heads=key_heads, chunks=chunks)
+
+
+def gdr_blocks():
+    """{(kernel, key_heads, chunks): traced calls} (test/diagnostic
+    accessor)."""
+    return {
+        (labels["kernel"], int(labels["key_heads"]), int(labels["chunks"])):
+        child.value
+        for labels, child in _gdr_blocks_counter()._series()
     }

@@ -23,19 +23,31 @@ inside a chunk and ``S`` the state the chunk starts from,
     O       = (exp(G) q) S + ((q k^T) * exp(G_t - G_i) [i <= t]) U
     S_next  = exp(G_C) S + (exp(G_C - G) k)^T U
 
-``T`` comes from the nilpotent series ``(I - A)(I + A^2)(I + A^4)...``:
-``log2(C)`` squarings and as many products of C x C float32 matrices, no
-row-by-row substitution. Everything else is matmuls with bf16 operands
-and float32 accumulation; the state and every accumulator are float32.
+Everything is matmuls with bf16 operands and float32 accumulation; the
+state, the gates and every accumulator are float32. ``T`` is handed on in
+the operands' dtype. The kernels make it by doubling the diagonal blocks
+(``_inv_unit_lower_blocks``: X - X L X, ``2 log2(C) - 2`` products of
+C x C float32 matrices, no row-by-row substitution), each product in three
+bf16 passes where the result is rounded to bf16 anyway;
+``chunked_gated_delta_rule`` keeps the nilpotent series ``(I - A)(I + A^2)
+(I + A^4)...`` at ``HIGHEST``.
 
-Kernels: ``gated_delta_rule_fwd`` walks a sequence's chunks in order,
-one (batch, value head) a grid row, the state in VMEM scratch; it reads
-q, k, v in place as ``[B, T, H * d]`` (a head is a 128-lane column
-block, so nothing is transposed or repeated in HBM), and for the backward
-it also writes the state each chunk starts from. ``gated_delta_rule_bwd``
-walks the chunks from the last to the first with the state's cotangent in
-VMEM scratch; a chunk's vector-Jacobian product is ``jax.vjp`` of the
-forward's own chunk function, taken while the kernel is traced.
+Kernels, both over the grid (batch, groups of key heads, blocks of
+chunks) with the last axis sequential; ``choose_tile`` sizes a step from
+the call's shapes against a VMEM budget. A step holds ``key_heads`` key
+heads with all their value heads on a leading batch axis of every
+product, so the heads' dependent chains interleave on the MXU and ``k
+k^T``, ``q k^T`` are taken once a key head; the chunks of a step are a
+``fori_loop``. ``gated_delta_rule_fwd`` walks a sequence's chunks in
+order, the states in VMEM scratch; it reads q, k, v in place as ``[B, T,
+H * d]`` (a head is a 128-lane column block, so nothing is transposed or
+repeated in HBM), and for the backward it also writes the state each
+chunk starts from and the chunk's ``T``. ``gated_delta_rule_bwd`` walks
+the chunks from the last to the first with the states' cotangent in VMEM
+scratch; a chunk's vector-Jacobian product is ``jax.vjp`` of the
+forward's own chunk functions, taken while the kernel is traced, with the
+forward's ``T`` in the inverse's place (no second inverse); ``dq`` and
+``dk`` of a key head's value heads add up in float32 inside it.
 ``chunked_gated_delta_rule`` is the same mathematics in ``jax.numpy``
 (the intra-chunk part for all chunks at once, a ``lax.scan`` over the
 chunk states): the ``"xla"`` path and what the kernels are tested against.
@@ -53,21 +65,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.device import on_tpu
-from ._compat import pl_call
+from ._compat import pl_call, record_gdr_blocks
+# one chip, one VMEM: the budget a step is sized against, the limit asked
+# of Mosaic above half its default scope
+from .flash_attention import (
+    _DEFAULT_SCOPE_BYTES, VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES)
 
-__all__ = ["gated_delta_rule", "chunked_gated_delta_rule"]
+__all__ = ["gated_delta_rule", "chunked_gated_delta_rule", "choose_tile"]
 
 DEFAULT_CHUNK = 64
-# chunks one grid step walks: a [256, 128] block a head moves four times
-# fewer, larger DMAs than a [64, 128] one
-CHUNKS_PER_STEP = 4
+# the most chunks a grid step walks: a [256, 128] block a head moves four
+# times fewer, larger DMAs than a [64, 128] one
+MAX_CHUNKS = 4
+# the most value heads a grid step holds (a key head's all the same): on a
+# v5e 4 / 8 / 16 of 128 take 11.0 / 7.9 / 6.2 ms forward at 4 x 8192 x 32
+# heads; 32 do not fit the budget
+MAX_VALUE_HEADS = 16
 _HI = jax.lax.Precision.HIGHEST
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 
 # ------------------------------------------------------- the mathematics
 def _mm(a, b, dims, precision=None):
     return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
                                preferred_element_type=jnp.float32)
+
+
+def _split(x):
+    """float32 -> (head, tail) in bf16 with head + tail = x to 2^-16."""
+    head = x.astype(jnp.bfloat16)
+    return head, (x - head.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _mm_split(a, b):
+    """a b of two float32 matrices given as ``_split`` pairs, in three
+    single-pass products of bf16 operands with float32 accumulation (head
+    head + head tail + tail head): relative error ~2^-16, where
+    ``HIGHEST`` takes six passes for 2^-24."""
+    (a_head, a_tail), (b_head, b_tail) = a, b
+    return _mm(a_head, b_head, _NN) + (
+        _mm(a_head, b_tail, _NN) + _mm(a_tail, b_head, _NN))
 
 
 def _inv_unit_lower_series(a):
@@ -85,6 +122,94 @@ def _inv_unit_lower_series(a):
         inv = inv + _mm(inv, power, ((1,), (0,)), _HI)
         span *= 2
     return inv
+
+
+def _doubled_blocks(a, row, col, operand, product):
+    """``_inv_unit_lower_blocks``' doublings for ``a`` [..., C, lanes] with
+    the row and column of each lane's entry and the products given."""
+    c = a.shape[-2]
+    # blocks of 2: [[1, 0], [-a, 1]]
+    inv = (row == col).astype(jnp.float32) - jnp.where(
+        row // 2 == col // 2, a, 0.0)
+    size = 2
+    while size < c:
+        below = jnp.where(
+            (row // (2 * size) == col // (2 * size))
+            & (row // size > col // size), a, 0.0)
+        x = operand(inv)
+        inv = inv - product(x, operand(product(operand(below), x)))
+        size *= 2
+    return inv
+
+
+def _inv_unit_lower_blocks(a, split=False):
+    """(I + a)^-1 for a strictly lower triangular [C, C] float32 ``a`` by
+    doubling the diagonal blocks: with X the inverses of the blocks of
+    size s and L the part of ``a`` below them inside the blocks of 2 s,
+    ``[[X1, 0], [-X2 L X1, X2]] = X - X L X``. Two products a doubling,
+    ten at C 64, as the series takes; every factor is an inverse of a
+    block of ``I + a`` or a part of ``a``, where the series' powers grow
+    like binomials before they cancel (unit k rows 0.5 apart: the series
+    at ``HIGHEST`` is off by 1e8 of an entry, this by 1e-7).
+    ``split``: the products by ``_mm_split`` (three bf16 passes, 2e-5)
+    and not at ``HIGHEST`` (six), for a result that is rounded to bf16
+    (2^-9) on the next line."""
+    c = a.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    if split:
+        return _doubled_blocks(a, row, col, _split, _mm_split)
+    return _doubled_blocks(
+        a, row, col, lambda x: x,
+        functools.partial(_mm, dims=_NN, precision=_HI))
+
+
+def _inv_unit_lower_pairs(a, split=False):
+    """``_inv_unit_lower_blocks`` of an even batch [H, C, C], two matrices
+    side by side in the lanes ([H / 2, C, 2 C]: at C 64 a whole 128-lane
+    tile, so the elementwise work and the splits touch half the vregs).
+    A product takes the right operands of a pair as the diagonal blocks
+    of one [2 C, 2 C] matrix: [P1 | P2] diag(Q1, Q2) = [P1 Q1 | P2 Q2],
+    one pass of full tiles where two of quarter tiles stood."""
+    h, c, _ = a.shape
+    a = a.reshape(h // 2, 2, c, c)
+    a = jnp.concatenate([a[:, 0], a[:, 1]], axis=-1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    col = jnp.where(lane >= c, lane - c, lane)
+    on_diagonal = (
+        (jax.lax.broadcasted_iota(jnp.int32, (2 * c, 2 * c), 0) >= c)
+        == (jax.lax.broadcasted_iota(jnp.int32, (2 * c, 2 * c), 1) >= c))
+
+    def diagonal(x):
+        return jnp.where(on_diagonal, jnp.concatenate([x, x], axis=-2),
+                         jnp.zeros((), x.dtype))
+
+    if split:
+        operand = _split
+        product = jax.vmap(lambda p, q: _mm_split(
+            p, jax.tree_util.tree_map(diagonal, q)))
+    else:
+        operand = lambda x: x
+        product = jax.vmap(lambda p, q: _mm(p, diagonal(q), _NN, _HI))
+    inv = _doubled_blocks(a, row, col, operand, product)
+    return jnp.stack([inv[..., :c], inv[..., c:]], axis=1).reshape(h, c, c)
+
+
+def _inv_heads(split):
+    """The kernels' inverse of one head's chunk; under ``jax.vmap`` over
+    an even number of heads it is ``_inv_unit_lower_pairs`` of them all
+    where two fit the lanes."""
+    single = functools.partial(_inv_unit_lower_blocks, split=split)
+    inverse = jax.custom_batching.custom_vmap(single)
+
+    @inverse.def_vmap
+    def _(axis_size, in_batched, a):
+        if axis_size % 2 == 0 and 2 * a.shape[-1] <= 128:
+            return _inv_unit_lower_pairs(a, split), True
+        return jax.vmap(single)(a), True
+
+    return inverse
 
 
 @jax.custom_vjp
@@ -106,7 +231,39 @@ def _inv_bwd(inv, d_inv):
 _inv_unit_lower.defvjp(_inv_fwd, _inv_bwd)
 
 
-def _chunk_parts(q, k, v, grow, brow, inverse, op=None):
+@jax.custom_vjp
+def _inv_saved(a, t):
+    """(I + a)^-1 where the forward kernel has left it: ``t``, in the
+    operands' dtype. The backward kernel's inverse; its cotangent needs no
+    series."""
+    return t
+
+
+def _inv_saved_fwd(a, t):
+    return t, t
+
+
+def _inv_saved_bwd(t, d_t):
+    # d(I + a)^-1 = -(I + a)^-1 da (I + a)^-1; d_t comes in t's dtype
+    if t.dtype == jnp.bfloat16:
+        left = _split(_mm(t, d_t, _TN))
+        d_a = -(_mm(left[0], t, _NT) + _mm(left[1], t, _NT))
+    else:
+        d_a = -_mm(_mm(t, d_t, _TN, _HI), t, _NT, _HI)
+    return d_a, jnp.zeros_like(t)
+
+
+_inv_saved.defvjp(_inv_saved_fwd, _inv_saved_bwd)
+
+
+def _chunk_products(q, k):
+    """(k k^T, q k^T) of a chunk of one key head, [C, C] float32: the same
+    for every value head it serves."""
+    return _mm(k, k, _NT), _mm(q, k, _NT)
+
+
+def _chunk_parts(q, k, v, grow, brow, inverse, op=None, products=None,
+                 with_inverse=False):
     """What one chunk needs that does not depend on the state. q, k
     [C, d_k] and v [C, d_v]; ``op`` is the dtype of the matmuls' operands
     (q's own unless given: the backward kernel hands float32 copies over
@@ -115,7 +272,10 @@ def _chunk_parts(q, k, v, grow, brow, inverse, op=None):
     column would be padded to 128 lanes in HBM; the columns are made
     here, through the diagonal). Returns (w_v [C, d_v] f32, w_k [C, d_k],
     q_g [C, d_k], attn [C, C], k_d [C, d_k] in the operands' dtype, decay
-    [1, d_v] f32)."""
+    [1, d_v] f32). ``products``: ``_chunk_products`` of q and k in the
+    operands' dtype, where the caller shares them between value heads.
+    ``with_inverse``: returns (those, the inverse [C, C] in the operands'
+    dtype)."""
     c = q.shape[0]
     op = op or q.dtype
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
@@ -130,19 +290,24 @@ def _chunk_parts(q, k, v, grow, brow, inverse, op=None):
     decay = jnp.exp(jnp.where(row >= col, gcol - grow, -jnp.inf))
     kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
     q, k = q.astype(op), k.astype(op)
-    a = jnp.where(row > col,
-                  bcol * decay * _mm(k, k, ((1,), (1,))), 0.0)
+    kk, qk = products if products is not None else _chunk_products(q, k)
+    a = jnp.where(row > col, bcol * decay * kk, 0.0)
     t = inverse(a).astype(op)
     e_g = jnp.exp(gcol)
     w_v = _mm(t, (bcol * vf).astype(op), ((1,), (0,)))
     w_k = _mm(t, (bcol * e_g * kf).astype(op), ((1,), (0,))).astype(op)
     q_g = (e_g * q.astype(jnp.float32)).astype(op)
-    attn = (decay * _mm(q, k, ((1,), (1,)))).astype(op)
+    attn = (decay * qk).astype(op)
     g_last = gcol[c - 1:c, :]
     k_d = (jnp.exp(g_last - gcol) * kf).astype(op)
-    # [1, d_v], not [1, 1]: Mosaic broadcasts along lanes, then sublanes
-    decay_last = jnp.exp(jnp.broadcast_to(g_last, (1, v.shape[1])))
-    return w_v, w_k, q_g, attn, k_d, decay_last
+    # [1, d_v], not [1, 1]: Mosaic broadcasts along lanes, then sublanes.
+    # The column is widened before its last row is cut out: the cotangent
+    # of a widened [1, 1] passes, with a batch of heads in front, through
+    # a rank-1 array, which Mosaic refuses
+    decay_last = jax.lax.broadcast_in_dim(
+        e_g, (c, v.shape[1]), (0, 1))[c - 1:c, :]
+    parts = w_v, w_k, q_g, attn, k_d, decay_last
+    return (parts, t) if with_inverse else parts
 
 
 def _chunk_apply(parts, state):
@@ -213,65 +378,176 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK):
 
 
 # ----------------------------------------------------------- the kernels
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, chunk,
-                chunks):
-    """One block of ``chunks`` chunks of one (batch, value head); the grid's
-    last axis walks the sequence and carries the state in ``s_scr``. With a
-    seventh ref, the state each chunk starts from is written there (what
-    the backward kernel reads)."""
-    states_ref, s_scr = rest if len(rest) == 2 else (None, rest[0])
+def _vmem_bytes(key_heads, rep, dk, dv, chunk, chunks, itemsize):
+    """Estimated VMEM of one grid step of the backward kernel, the larger
+    of the two: every block double-buffered, the state's cotangent, and
+    what is alive inside a chunk."""
+    heads, block = key_heads * rep, chunk * chunks
+    lanes = lambda width: -(-width // 128) * 128
+    # q, k, dq, dk by key head; v, do, dv by value head
+    sequences = block * itemsize * (
+        4 * lanes(key_heads * dk) + 3 * lanes(heads * dv))
+    rows = 4 * chunks * -(-heads // 8) * 8 * lanes(chunk) * 4
+    states = chunks * heads * dk * lanes(dv) * 4
+    inverses = chunks * heads * chunk * lanes(chunk) * itemsize
+    scratch = heads * dk * lanes(dv) * 4
+    # a value head's float32 [C, C] and [C, d] tiles, the state's copies
+    alive = heads * 4 * (8 * chunk * lanes(chunk)
+                         + 8 * chunk * lanes(max(dk, dv))
+                         + 3 * dk * lanes(dv))
+    return 2 * (sequences + rows + states + inverses) + scratch + alive
+
+
+def choose_tile(t, hk, rep, dk, dv, chunk, dtype):
+    """(key heads, chunks) of one grid step of both kernels, from the
+    call's shapes and dtype alone. Chunks: ``MAX_CHUNKS`` or the whole
+    sequence. Key heads: a divisor of ``hk`` that gives Mosaic whole
+    128-lane column blocks (or is all the heads) and fits
+    ``VMEM_BUDGET_BYTES`` by ``_vmem_bytes``' estimate; of those the most
+    that keep the step's value heads at ``MAX_VALUE_HEADS``, else the
+    fewest. Where none fits, fewer chunks, and at last one key head and
+    one chunk on the same kernels."""
+    itemsize = jnp.dtype(dtype).itemsize
+    chunks = min(MAX_CHUNKS, -(-t // chunk))
+    while chunks:
+        fits = [
+            n for n in range(1, hk + 1)
+            if hk % n == 0
+            and (n == hk or (n * dk % 128 == 0 and n * rep * dv % 128 == 0))
+            and _vmem_bytes(n, rep, dk, dv, chunk, chunks, itemsize)
+            <= VMEM_BUDGET_BYTES]
+        if fits:
+            paying = [n for n in fits if n * rep <= MAX_VALUE_HEADS]
+            return (paying[-1] if paying else fits[0]), chunks
+        chunks //= 2
+    return 1, 1
+
+
+def _load_heads(ref, rows, count, dtype=None):
+    """[count, C, d]: the heads of a [1, block, count * d] block, each a
+    column block of its own."""
+    width = ref.shape[2] // count
+    heads = [ref[0, rows, h * width:(h + 1) * width] for h in range(count)]
+    return jnp.stack(heads).astype(dtype or ref.dtype)
+
+
+def _store_heads(ref, rows, x):
+    width = ref.shape[2] // x.shape[0]
+    for h in range(x.shape[0]):
+        ref[0, rows, h * width:(h + 1) * width] = x[h].astype(ref.dtype)
+
+
+def _load_rows(ref, c):
+    """[heads, 1, C]: a chunk's rows of a [1, chunks, 1, heads, C] block."""
+    return jnp.stack(
+        [ref[0, c, 0, h:h + 1, :] for h in range(ref.shape[3])])
+
+
+def _store_rows(ref, c, x):
+    for h in range(x.shape[0]):
+        ref[0, c, 0, h:h + 1, :] = x[h]
+
+
+def _heads_parts(q, k, v, grow, brow, rep, op, inverse=None, saved=None):
+    """``_chunk_parts`` of one chunk for all of a grid step's heads at once:
+    q, k [n, C, d_k] of its key heads; v [n * rep, C, d_v] and grow, brow
+    [n * rep, 1, C] of their value heads. The heads' chains are independent
+    and ride a leading batch axis, so their products interleave on the MXU;
+    ``k k^T`` and ``q k^T`` are taken once a key head. With ``saved``
+    [n * rep, C, C] the inverses are the forward kernel's (the backward
+    kernel: no series); without, ``inverse`` makes them and they are
+    returned beside the parts."""
+    shared = jax.vmap(_chunk_products)(q.astype(op), k.astype(op))
+    if rep > 1:
+        q, k, shared = jax.tree_util.tree_map(
+            lambda x: jnp.repeat(x, rep, axis=0), (q, k, shared))
+    def one_head(q, k, v, g, b, kk_qk, t=None):
+        if t is None:
+            return _chunk_parts(q, k, v, g, b, inverse, op, kk_qk,
+                                with_inverse=True)
+        return _chunk_parts(q, k, v, g, b, lambda a: _inv_saved(a, t), op,
+                            kk_qk)
+
+    heads = (q, k, v, grow, brow, shared)
+    return jax.vmap(one_head)(*heads + (() if saved is None else (saved,)))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, tile,
+                chunk):
+    """``chunks`` chunks of ``key_heads`` key heads with their value heads;
+    the grid's last axis walks the sequence and carries the states in
+    ``s_scr``. With two more refs, the state each chunk starts from and
+    the chunk's inverse are written there (what the backward kernel
+    reads)."""
+    key_heads, rep, chunks = tile
+    states_ref, t_ref, s_scr = rest if len(rest) == 3 else (None, None,
+                                                             rest[0])
+    op = q_ref.dtype
+    inverse = _inv_heads(split=op == jnp.bfloat16)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        s_scr[:] = jnp.zeros_like(s_scr)
+        s_scr[...] = jnp.zeros_like(s_scr)
 
-    state = s_scr[:]
-    for c in range(chunks):
-        rows = pl.ds(c * chunk, chunk)
+    def walk(c, _):
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        state = s_scr[...]
+        parts, t = _heads_parts(
+            _load_heads(q_ref, rows, key_heads),
+            _load_heads(k_ref, rows, key_heads),
+            _load_heads(v_ref, rows, key_heads * rep),
+            _load_rows(g_ref, c), _load_rows(b_ref, c), rep, op, inverse)
         if states_ref is not None:
-            states_ref[0, 0, c] = state
-        parts = _chunk_parts(
-            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
-            g_ref[0, 0, c], b_ref[0, 0, c], _inv_unit_lower_series)
-        o, state = _chunk_apply(parts, state)
-        o_ref[0, rows, :] = o.astype(o_ref.dtype)
-    s_scr[:] = state
+            states_ref[0, c] = state
+            t_ref[0, c] = t
+        o, state = jax.vmap(_chunk_apply)(parts, state)
+        _store_heads(o_ref, rows, o)
+        s_scr[...] = state
+
+    jax.lax.fori_loop(0, chunks, walk, None)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr, *, chunk,
-                chunks):
-    """The blocks of one (batch, value head) from the last to the first,
-    the state's cotangent carried in ``ds_scr``. A chunk's
-    vector-Jacobian product is taken by ``jax.vjp`` of the forward's own
-    chunk function while the kernel is traced, so the two cannot drift
-    apart: Mosaic is handed the transposed matmuls as plain operations."""
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        ds_scr[:] = jnp.zeros_like(ds_scr)
-
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, t_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr, *, tile,
+                chunk):
+    """The same blocks from the last to the first, the states' cotangent
+    carried in ``ds_scr``. A chunk's vector-Jacobian product is taken by
+    ``jax.vjp`` of the forward's own functions while the kernel is traced,
+    so the two cannot drift apart; the inverse is the one the forward
+    wrote, and ``dq``, ``dk`` of a key head's value heads add up in
+    float32 inside the product rule."""
+    key_heads, rep, chunks = tile
     op = q_ref.dtype
     f32 = jnp.float32
 
-    def chunk_fn(q, k, v, grow, brow, state):
-        return _chunk_apply(
-            _chunk_parts(q, k, v, grow, brow, _inv_unit_lower, op), state)
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
 
-    d_state = ds_scr[:]
-    for c in reversed(range(chunks)):
-        rows = pl.ds(c * chunk, chunk)
+    def walk(i, _):
+        c = chunks - 1 - i
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        saved = t_ref[0, c]
+
+        def chunk_fn(q, k, v, grow, brow, state):
+            parts = _heads_parts(q, k, v, grow, brow, rep, op, saved=saved)
+            return jax.vmap(_chunk_apply)(parts, state)
+
         _, vjp = jax.vjp(
-            chunk_fn, q_ref[0, rows, :].astype(f32),
-            k_ref[0, rows, :].astype(f32), v_ref[0, rows, :].astype(f32),
-            g_ref[0, 0, c], b_ref[0, 0, c], states_ref[0, 0, c])
+            chunk_fn, _load_heads(q_ref, rows, key_heads, f32),
+            _load_heads(k_ref, rows, key_heads, f32),
+            _load_heads(v_ref, rows, key_heads * rep, f32),
+            _load_rows(g_ref, c), _load_rows(b_ref, c), states_ref[0, c])
         dq, dk, dv, dg, db, d_state = vjp(
-            (do_ref[0, rows, :].astype(f32), d_state))
-        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
-        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, rows, :] = dv.astype(dv_ref.dtype)
-        dg_ref[0, 0, c] = dg
-        db_ref[0, 0, c] = db
-    ds_scr[:] = d_state
+            (_load_heads(do_ref, rows, key_heads * rep, f32), ds_scr[...]))
+        _store_heads(dq_ref, rows, dq)
+        _store_heads(dk_ref, rows, dk)
+        _store_heads(dv_ref, rows, dv)
+        _store_rows(dg_ref, c, dg)
+        _store_rows(db_ref, c, db)
+        ds_scr[...] = d_state
+
+    jax.lax.fori_loop(0, chunks, walk, None)
 
 
 def _record_chunk(chunk, dk, dv):
@@ -284,98 +560,99 @@ def _record_chunk(chunk, dk, dv):
     ).inc(chunk=chunk, d_k=dk, d_v=dv)
 
 
-def _specs(dims, walk):
-    """Block specs of the kernels' operands in their HBM layouts; ``walk``
-    maps the grid's last index to the block of the sequence."""
-    rep, dk, dv, block, chunks, chunk = dims
-    return {
-        "qk": pl.BlockSpec(
-            (1, block, dk), lambda i, h, j: (i, walk(j), h // rep)),
-        "qk_by_value_head": pl.BlockSpec(
-            (1, block, dk), lambda i, h, j: (i, walk(j), h)),
-        "v": pl.BlockSpec((1, block, dv), lambda i, h, j: (i, walk(j), h)),
-        "rows": pl.BlockSpec((1, 1, chunks, 1, chunk),
-                             lambda i, h, j: (i, h, walk(j), 0, 0)),
-        "states": pl.BlockSpec((1, 1, chunks, dk, dv),
-                               lambda i, h, j: (i, h, walk(j), 0, 0)),
-    }
+def _call(kernel, name, tile, dims, walk, in_names, out_names, out_shape,
+          operands):
+    """One of the two kernels over the grid (batch, groups of key heads,
+    blocks of chunks); ``walk`` maps the grid's last index to the block of
+    the sequence."""
+    key_heads, rep, chunks = tile
+    b, tp, hk, dk, dv, chunk = dims
+    heads, block = key_heads * rep, chunk * chunks
+
+    def sequence(width):
+        return pl.BlockSpec((1, block, width),
+                            lambda i, h, j: (i, walk(j), h))
+
+    def by_chunk(*tail):
+        return pl.BlockSpec((1, chunks) + tail,
+                            lambda i, h, j: (i, walk(j), h, 0, 0))
+
+    sp = {"qk": sequence(key_heads * dk), "v": sequence(heads * dv),
+          "rows": by_chunk(1, heads, chunk),
+          "states": by_chunk(heads, dk, dv),
+          "inverses": by_chunk(heads, chunk, chunk)}
+    record_gdr_blocks(name, key_heads, chunks)
+    need = _vmem_bytes(key_heads, rep, dk, dv, chunk, chunks,
+                       operands[0].dtype.itemsize)
+    return pl_call(
+        functools.partial(kernel, tile=tile, chunk=chunk), name=name,
+        grid=(b, hk // key_heads, tp // block),
+        in_specs=[sp[n] for n in in_names],
+        out_specs=[sp[n] for n in out_names], out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=(
+                VMEM_LIMIT_BYTES if need > _DEFAULT_SCOPE_BYTES // 2
+                else None)),
+    )(*operands)
 
 
-def _fwd_call(qf, kf, vf, grow, brow, rep, with_states):
+def _dims(qf, vf, grow, tile):
+    """(B, T, H_k, d_k, d_v, C) of a call in the kernels' layouts."""
+    key_heads, rep, _ = tile
     b, tp, _ = vf.shape
-    hv, nc, chunk = grow.shape[1], grow.shape[2], grow.shape[4]
-    dk, dv = qf.shape[2] * rep // hv, vf.shape[2] // hv
-    chunks = min(CHUNKS_PER_STEP, nc)
-    block = chunk * chunks
-    sp = _specs((rep, dk, dv, block, chunks, chunk), lambda j: j)
+    hv, chunk = grow.shape[2] * grow.shape[3], grow.shape[4]
+    return b, tp, hv // rep, qf.shape[2] * rep // hv, vf.shape[2] // hv, chunk
+
+
+def _fwd_call(qf, kf, vf, grow, brow, tile, with_states):
+    dims = b, tp, hk, dk, dv, chunk = _dims(qf, vf, grow, tile)
+    hv, nc = hk * tile[1], tp // chunk
     _record_chunk(chunk, dk, dv)
-    out_specs = [sp["v"]]
+    out_names = ["v"]
     out_shape = [jax.ShapeDtypeStruct(vf.shape, vf.dtype)]
     if with_states:
-        out_specs.append(sp["states"])
-        out_shape.append(
-            jax.ShapeDtypeStruct((b, hv, nc, dk, dv), jnp.float32))
-    return pl_call(
-        functools.partial(_fwd_kernel, chunk=chunk, chunks=chunks),
-        name="gated_delta_rule_fwd",
-        grid=(b, hv, tp // block),
-        in_specs=[sp["qk"], sp["qk"], sp["v"], sp["rows"], sp["rows"]],
-        out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )(qf, kf, vf, grow, brow)
+        out_names += ["states", "inverses"]
+        out_shape += [
+            jax.ShapeDtypeStruct((b, nc, hv, dk, dv), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, hv, chunk, chunk), qf.dtype)]
+    return _call(_fwd_kernel, "gated_delta_rule_fwd", tile, dims,
+                 lambda j: j, ["qk", "qk", "v", "rows", "rows"], out_names,
+                 out_shape, (qf, kf, vf, grow, brow))
 
 
-def _bwd_call(qf, kf, vf, grow, brow, states, do, rep):
-    b, tp, _ = vf.shape
-    hv, nc, chunk = grow.shape[1], grow.shape[2], grow.shape[4]
-    dk, dv = qf.shape[2] * rep // hv, vf.shape[2] // hv
-    chunks = min(CHUNKS_PER_STEP, nc)
-    block = chunk * chunks
-    last = tp // block - 1
-    sp = _specs((rep, dk, dv, block, chunks, chunk), lambda j: last - j)
-    by_head = jax.ShapeDtypeStruct((b, tp, hv * dk), qf.dtype)
-    rows = jax.ShapeDtypeStruct(grow.shape, jnp.float32)
-    return pl_call(
-        functools.partial(_bwd_kernel, chunk=chunk, chunks=chunks),
-        name="gated_delta_rule_bwd",
-        grid=(b, hv, tp // block),
-        in_specs=[sp["qk"], sp["qk"], sp["v"], sp["rows"], sp["rows"],
-                  sp["states"], sp["v"]],
-        out_specs=[sp["qk_by_value_head"], sp["qk_by_value_head"], sp["v"],
-                   sp["rows"], sp["rows"]],
-        out_shape=[by_head, by_head,
-                   jax.ShapeDtypeStruct(vf.shape, vf.dtype), rows, rows],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )(qf, kf, vf, grow, brow, states, do)
+def _bwd_call(qf, kf, vf, grow, brow, states, inverses, do, tile):
+    dims = _, tp, _, _, _, chunk = _dims(qf, vf, grow, tile)
+    last = tp // (chunk * tile[2]) - 1
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return _call(_bwd_kernel, "gated_delta_rule_bwd", tile, dims,
+                 lambda j: last - j,
+                 ["qk", "qk", "v", "rows", "rows", "states", "inverses",
+                  "v"],
+                 ["qk", "qk", "v", "rows", "rows"],
+                 [like(qf), like(kf), like(vf), like(grow), like(brow)],
+                 (qf, kf, vf, grow, brow, states, inverses, do))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _gdr_core(qf, kf, vf, grow, brow, rep):
+def _gdr_core(qf, kf, vf, grow, brow, tile):
     """The kernels' own layouts: qf, kf [B, T, H_k * d_k] and vf
     [B, T, H_v * d_v] with T a multiple of the block; grow, brow
-    [B, H_v, T / C, 1, C] float32 (running sums of g inside a chunk,
-    beta). Returns o like vf."""
-    return _fwd_call(qf, kf, vf, grow, brow, rep, False)[0]
+    [B, T / C, H_v / heads, heads, C] float32 (running sums of g inside a
+    chunk, beta; ``heads`` the value heads of a grid step); ``tile`` =
+    (key heads a step, value heads a key head, chunks a step). Returns o
+    like vf."""
+    return _fwd_call(qf, kf, vf, grow, brow, tile, False)[0]
 
 
-def _gdr_core_fwd(qf, kf, vf, grow, brow, rep):
-    o, states = _fwd_call(qf, kf, vf, grow, brow, rep, True)
-    return o, (qf, kf, vf, grow, brow, states)
+def _gdr_core_fwd(qf, kf, vf, grow, brow, tile):
+    o, states, inverses = _fwd_call(qf, kf, vf, grow, brow, tile, True)
+    return o, (qf, kf, vf, grow, brow, states, inverses)
 
 
-def _gdr_core_bwd(rep, res, do):
-    qf, kf, vf, grow, brow, states = res
-    dq, dk, dv, dg, db = _bwd_call(qf, kf, vf, grow, brow, states, do, rep)
-    b, tp, width = qf.shape
-    d_k = width * rep // grow.shape[1]
-
-    def over_key_heads(x):      # the value heads that share a key head
-        x = x.reshape(b, tp, -1, rep, d_k).astype(jnp.float32).sum(3)
-        return x.reshape(qf.shape).astype(qf.dtype)
-
-    return over_key_heads(dq), over_key_heads(dk), dv, dg, db
+def _gdr_core_bwd(tile, res, do):
+    return _bwd_call(*res, do, tile)
 
 
 _gdr_core.defvjp(_gdr_core_fwd, _gdr_core_bwd)
@@ -384,14 +661,20 @@ _gdr_core.defvjp(_gdr_core_fwd, _gdr_core_bwd)
 def _gdr_pallas(q, k, v, g, beta, chunk):
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    block = chunk * min(CHUNKS_PER_STEP, -(-t // chunk))
-    pad = (-t) % block
+    rep = hv // hk
+    key_heads, chunks = choose_tile(t, hk, rep, dk, dv, chunk, q.dtype)
+    pad = (-t) % (chunk * chunks)
     q, k, v, g, beta = (_pad_time(x, pad) for x in (q, k, v, g, beta))
     tp = t + pad
-    grow = _chunk_sums(g, chunk)[:, :, :, None, :]   # [B, H_v, NC, 1, C]
-    brow = jnp.moveaxis(beta.astype(jnp.float32), 1, 2).reshape(grow.shape)
+
+    def rows(x):                 # [B, T, H_v] -> [B, NC, groups, heads, C]
+        x = x.astype(jnp.float32).reshape(
+            b, tp // chunk, chunk, hk // key_heads, key_heads * rep)
+        return jnp.moveaxis(x, 2, 4)
+
     o = _gdr_core(q.reshape(b, tp, hk * dk), k.reshape(b, tp, hk * dk),
-                  v.reshape(b, tp, hv * dv), grow, brow, hv // hk)
+                  v.reshape(b, tp, hv * dv), jnp.cumsum(rows(g), -1),
+                  rows(beta), (key_heads, rep, chunks))
     return o.reshape(b, tp, hv, dv)[:, :t]
 
 

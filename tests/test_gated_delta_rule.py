@@ -144,6 +144,200 @@ def test_a_traced_kernel_call_is_counted_by_its_sizes():
     assert total().get(key, 0) == before.get(key, 0) + 1
 
 
+# (t, chunk, hk, hv, d): what the tile should be, and why the case is here
+TILES = {
+    "rep1_all_key_heads": ((64, 16, 8, 8, 32), (8, 4)),
+    "rep2_two_groups": ((64, 16, 16, 32, 32), (8, 4)),
+    "rep4_two_groups": ((64, 16, 8, 32, 32), (4, 4)),
+    # 8 key heads would hold 16 value heads, and 12 is no multiple of 8
+    "key_heads_the_widest_step_does_not_divide": ((64, 16, 12, 24, 32),
+                                                  (4, 4)),
+    "one_chunk": ((16, 16, 2, 4, 32), (2, 1)),
+    # 100 = 6.25 chunks: padded to two blocks of 4
+    "padded_to_the_block": ((100, 16, 2, 4, 32), (2, 4)),
+}
+
+
+def _tile_case(name):
+    (t, chunk, hk, hv, d), tile = TILES[name]
+    chosen = G.choose_tile(t, hk, hv // hk, d, d, chunk, jnp.float32)
+    return (t, chunk, hk, hv, d), tile, chosen
+
+
+@pytest.mark.parametrize("name", sorted(TILES))
+def test_the_tile_is_chosen_from_the_shapes(name):
+    (t, chunk, hk, hv, d), tile, chosen = _tile_case(name)
+    assert chosen == tile
+    key_heads, chunks = chosen
+    assert hk % key_heads == 0 and chunks * chunk <= -(-t // chunk) * chunk
+    assert G._vmem_bytes(key_heads, hv // hk, d, d, chunk, chunks,
+                         4) <= G.VMEM_BUDGET_BYTES
+
+
+def test_the_cells_shape_gets_several_key_heads_under_the_budget():
+    """qwen3next.pretrain-8k: 16 key and 32 value heads of 128 over 8192
+    tokens in bf16."""
+    key_heads, chunks = G.choose_tile(8192, 16, 2, 128, 128, 64,
+                                      jnp.bfloat16)
+    assert key_heads > 1 and 16 % key_heads == 0 and chunks > 1
+    assert key_heads * 2 <= G.MAX_VALUE_HEADS
+    need = G._vmem_bytes(key_heads, 2, 128, 128, 64, chunks, 2)
+    assert need <= G.VMEM_BUDGET_BYTES < G.VMEM_LIMIT_BYTES
+
+
+def test_a_shape_no_wider_step_fits_falls_back_to_one_key_head(monkeypatch):
+    monkeypatch.setattr(G, "VMEM_BUDGET_BYTES", 2**20)
+    assert G.choose_tile(8192, 16, 2, 128, 128, 64, jnp.bfloat16) == (1, 1)
+
+
+@pytest.mark.parametrize("name", sorted(TILES))
+def test_kernel_forward_matches_the_recurrence_at_each_tile(name):
+    (t, chunk, hk, hv, d), _, _ = _tile_case(name)
+    args = _inputs(1, t, hk, hv, d, d, seed=7)
+    out = G.gated_delta_rule(*args, chunk=chunk, impl="pallas")
+    np.testing.assert_allclose(out, recurrent(*args), atol=2e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("name", sorted(TILES))
+def test_kernel_gradients_match_the_recurrence_at_each_tile(name):
+    (t, chunk, hk, hv, d), _, _ = _tile_case(name)
+    args = _inputs(1, t, hk, hv, d, d, seed=8)
+    w = jax.random.normal(jax.random.key(9), (1, t, hv, d))
+    ref = jax.grad(
+        lambda *a: jnp.sum(recurrent(*a) * w),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    got = jax.grad(
+        lambda *a: jnp.sum(
+            G.gated_delta_rule(*a, chunk=chunk, impl="pallas") * w),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    for label, a, b in zip("q k v g beta".split(), got, ref):
+        scale = float(jnp.abs(b).max())
+        assert float(jnp.abs(a - b).max()) <= 5e-6 * scale, label
+
+
+@pytest.mark.parametrize("rep", [2, 4])
+def test_dq_and_dk_summed_in_the_kernel_equal_the_sum_made_outside(rep):
+    """The value heads of a key head add their dq and dk in float32 inside
+    the kernel: the same as a call with the key heads repeated by hand
+    (a value head each), summed afterwards."""
+    hk, t, d = 2, 48, 16
+    q, k, v, g, beta = _inputs(1, t, hk, hk * rep, d, d, seed=10)
+    w = jax.random.normal(jax.random.key(11), (1, t, hk * rep, d))
+
+    def grads(q, k):
+        return jax.grad(
+            lambda q, k: jnp.sum(G.gated_delta_rule(
+                q, k, v, g, beta, chunk=16, impl="pallas") * w),
+            argnums=(0, 1))(q, k)
+
+    inside = grads(q, k)
+    outside = grads(jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2))
+    for a, b in zip(inside, outside):
+        summed = b.reshape(1, t, hk, rep, d).sum(3)
+        np.testing.assert_allclose(a, summed, atol=1e-6, rtol=1e-5)
+
+
+def test_a_traced_kernel_call_is_counted_by_its_tile():
+    from paddle_tpu.kernels.pallas._compat import gdr_blocks
+
+    before = gdr_blocks()
+    args = _inputs(1, 40, 2, 4, 16, 8, seed=6)
+    jax.grad(lambda *a: jnp.sum(G.gated_delta_rule(
+        *a, chunk=16, impl="pallas")))(*args)
+    after = gdr_blocks()
+    # the forward under jax.grad and the backward, 2 key heads x 3 chunks
+    for kernel in ("gated_delta_rule_fwd", "gated_delta_rule_bwd"):
+        key = (kernel, 2, 3)
+        assert after.get(key, 0) == before.get(key, 0) + 1
+    assert set(after) - set(before) <= {
+        ("gated_delta_rule_fwd", 2, 3), ("gated_delta_rule_bwd", 2, 3)}
+
+
+def _adversarial_system(c, closeness, seed):
+    """A = tril(beta exp(G_t - G_i) k_t . k_i, -1) of a chunk with beta 1,
+    g 0 and unit k rows within ``closeness`` of one direction: the largest
+    entries the inverse can have."""
+    ks = jax.random.split(jax.random.key(seed), 2)
+    base = jax.random.normal(ks[0], (1, 128))
+    k = base + closeness * jax.random.normal(ks[1], (c, 128))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return jnp.tril(k @ k.T, -1).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("closeness", [0.1, 0.5, 1.0, 3.0, 10.0])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_three_pass_inverse_holds_on_adversarial_chunks(closeness, seed):
+    """The bf16 kernels' inverse (doubled diagonal blocks, three
+    single-pass products where ``HIGHEST`` takes six) against the exact
+    inverse: under 1e-4 of its largest entry (1: the diagonal), 2^4 finer
+    than the rounding to bf16 that follows, however close the k rows lie.
+    Against the ``HIGHEST`` series where that is sound itself: rows 0.5
+    apart or closer, its powers outgrow float32 before they cancel."""
+    a = _adversarial_system(64, closeness, seed)
+    exact = np.linalg.inv(np.eye(64) + np.asarray(a, np.float64))
+    scale = np.abs(exact).max()
+    high = np.asarray(G._inv_unit_lower_blocks(a))
+    cheap = np.asarray(G._inv_unit_lower_blocks(a, split=True))
+    assert np.abs(high - exact).max() < 1e-6 * scale
+    assert np.abs(cheap - exact).max() < 1e-4 * scale
+    as_bf16 = np.asarray(jnp.asarray(high).astype(jnp.bfloat16), np.float32)
+    assert np.abs(cheap - exact).max() < np.abs(as_bf16 - high).max() / 16
+    series = np.asarray(G._inv_unit_lower_series(a))
+    if closeness >= 3.0:
+        assert np.abs(series - exact).max() < 1e-5 * scale
+        assert np.abs(cheap - series).max() < 1e-4 * np.abs(series).max()
+    elif closeness <= 1.0:
+        assert np.abs(series - exact).max() > 1e2 * scale
+
+
+@pytest.mark.parametrize("c", [2, 4, 12, 16, 64])
+def test_the_block_inverse_at_other_chunk_sizes_and_in_pairs(c):
+    """Any chunk size (12: the last block of a doubling is partial), one
+    matrix at a time and two side by side in the lanes, which is what a
+    batch of heads under ``jax.vmap`` gets."""
+    a = jnp.stack([_adversarial_system(c, 1.0, seed) for seed in range(4)])
+    exact = np.stack([np.linalg.inv(np.eye(c) + np.asarray(x, np.float64))
+                      for x in a])
+    for split, atol in ((False, 1e-6), (True, 1e-4)):
+        one = jax.vmap(lambda x: G._inv_unit_lower_blocks(x, split))(a)
+        np.testing.assert_allclose(one, exact, atol=atol)
+        pairs = G._inv_unit_lower_pairs(a, split)
+        np.testing.assert_allclose(pairs, exact, atol=atol)
+        np.testing.assert_array_equal(
+            jax.vmap(G._inv_heads(split))(a), pairs)
+        # an odd batch falls back to one at a time
+        np.testing.assert_allclose(
+            jax.vmap(G._inv_heads(split))(a[:3]), exact[:3], atol=atol)
+
+
+def test_bfloat16_gap_is_no_wider_than_the_highest_series_gives():
+    """The kernels' gap to the float32 recurrence, output and gradients,
+    against the gap of the ``jax.numpy`` form (the ``HIGHEST`` series, its
+    own inverse in the backward) on the same bf16 inputs: the cheaper
+    inverse and the kept one eat none of the room."""
+    args = _inputs(1, 128, 2, 4, 32, 16, seed=2, dtype=jnp.bfloat16)
+    wide = tuple(a.astype(jnp.float32) for a in args)
+    w = jax.random.normal(jax.random.key(3), (1, 128, 4, 16))
+
+    def readings(fn, inputs):
+        def loss(*a):
+            o = fn(*a).astype(jnp.float32)
+            return jnp.sum(o * w), o
+        grads, o = jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+            *inputs)
+        return (o,) + tuple(x.astype(jnp.float32) for x in grads)
+
+    ref = readings(recurrent, wide)
+    gaps = {}
+    for impl in ("pallas", "xla"):
+        got = readings(
+            lambda *a: G.gated_delta_rule(*a, chunk=16, impl=impl), args)
+        gaps[impl] = [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+                      for a, b in zip(got, ref)]
+    for kernel, plain in zip(gaps["pallas"], gaps["xla"]):
+        assert kernel <= 1.02 * plain
+
+
 @pytest.mark.parametrize("bad", ["cuda", "interpret"])
 def test_unknown_impl_is_refused(bad):
     args = _inputs(1, 16, 1, 1, 8, 8)
