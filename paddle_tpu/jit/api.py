@@ -618,7 +618,48 @@ class TrainStep:
         params, buffers = self._params, self._buffers
         opt_step_fn = opt._make_step_fn()
         self._built_nan = _nan_check_enabled()
-        outer = self
+        k = self._accum
+
+        def constrain(i, g):
+            # ZeRO stage>=2: constrain the gradient's layout in-program
+            # so XLA reduce-scatters instead of all-reducing. Shardings
+            # were precomputed from concrete payloads in _prepare (params
+            # are tracers here).
+            shardings = self._grad_shardings
+            if shardings is None or shardings[i] is None:
+                return g
+            return jax.lax.with_sharding_constraint(g, shardings[i])
+
+        def fwd_bwd(key, tree):
+            """Forward and backward of one batch; its gradients are left
+            on the parameters."""
+            for p in params:
+                p.grad = None
+                p._grad_node = None
+            net = _nan_net(self._built_nan)
+            with _rng_lift(key) as lift:
+                args, kwargs = tree
+                with net:
+                    loss = loss_fn(model, *args, **kwargs)
+                    loss.backward()
+                new_key = lift.final_key()
+            self._nan_nets[self._cur_nan_key] = net
+            live_idx = [i for i, p in enumerate(params) if p.grad is not None]
+            return loss._data, live_idx, net, new_key
+
+        def split(a):
+            if not hasattr(a, "shape") or a.ndim == 0:
+                raise ValueError(
+                    "accum_steps requires every data input to "
+                    "have a leading batch axis to micro-split; "
+                    f"got {a!r}"
+                )
+            if a.shape[0] % k:
+                raise ValueError(
+                    f"batch axis {a.shape[0]} not divisible by "
+                    f"accum_steps={k}"
+                )
+            return a.reshape((k, a.shape[0] // k) + a.shape[1:])
 
         def staged(param_arrays, buffer_arrays, states, lr, t, found_inf,
                    key, tree_args):
@@ -627,40 +668,60 @@ class TrainStep:
             old_b = _swap_payloads(buffers, buffer_arrays)
             saved = [(p.grad, p._grad_node, p._out_index, p.stop_gradient)
                      for p in params]
-            net = _nan_net(outer._built_nan)
             try:
                 for p in params:
-                    p.grad = None
-                    p._grad_node = None
                     p.stop_gradient = False
-                with _rng_lift(key) as lift:
-                    args, kwargs = tree_args
-                    with net:
-                        loss = loss_fn(model, *args, **kwargs)
-                        loss.backward()
-                    new_key = lift.final_key()
+                if k == 1:
+                    loss_val, live_idx, net, new_key = fwd_bwd(key, tree_args)
+                    live_grads = [constrain(i, params[i].grad._data)
+                                  for i in live_idx]
+                    new_buffer_arrays = [b._data for b in buffers]
+                    flags = net.flags_output
+                else:
+                    # scan k micro-batches: gradients accumulate in fp32
+                    # through the carry (ZeRO layouts constrain it, so the
+                    # running sum stays sharded), buffers ride beside them
+                    keys = jax.random.split(key, k + 1)
+                    new_key = keys[0]
+                    grad_acc0 = []
+                    for i, a in enumerate(param_arrays):
+                        half = a.dtype in (jnp.bfloat16, jnp.float16)
+                        dt = jnp.float32 if half else a.dtype
+                        grad_acc0.append(constrain(i, jnp.zeros(a.shape, dt)))
+                    live_holder = []
 
-                live_idx = [
-                    i for i, p in enumerate(params) if p.grad is not None
-                ]
+                    def body(carry, xs):
+                        grad_acc, bufs = carry
+                        micro, key_i = xs
+                        _swap_payloads(buffers, bufs)
+                        loss_i, live_i, net, _ = fwd_bwd(key_i, micro)
+                        live_holder.append(live_i)
+                        new_acc = list(grad_acc)
+                        for i in live_i:
+                            g = params[i].grad._data.astype(grad_acc[i].dtype)
+                            new_acc[i] = grad_acc[i] + constrain(i, g)
+                        return ((new_acc, [b._data for b in buffers]),
+                                (loss_i, net.flags_output()))
+
+                    ((grad_acc, new_buffer_arrays),
+                     (losses, nan_stack)) = jax.lax.scan(
+                        body, (grad_acc0, list(buffer_arrays)),
+                        (jax.tree_util.tree_map(split, tree_args),
+                         keys[1:]),
+                    )
+                    live_idx = live_holder[0]
+                    live_grads = [
+                        (grad_acc[i] * (1.0 / k)).astype(param_arrays[i].dtype)
+                        for i in live_idx
+                    ]
+                    loss_val = losses.mean()
+                    flags = lambda: nan_stack.any(axis=0)  # noqa: E731
+
                 if self._live_idx is None:
                     self._live_idx = live_idx
                 live = [params[i] for i in live_idx]
                 attrs = tuple(self._attr_for(p) for p in live)
-                live_grads = [p.grad._data for p in live]
-                # ZeRO stage>=2: constrain gradient layout in-program so XLA
-                # reduce-scatters instead of all-reducing. Shardings were
-                # precomputed from concrete payloads in __call__ (params are
-                # tracers here).
-                if self._grad_shardings is not None:
-                    live_grads = [
-                        jax.lax.with_sharding_constraint(g, s)
-                        if (s := self._grad_shardings[i]) is not None else g
-                        for i, g in zip(live_idx, live_grads)
-                    ]
-                targets = tuple(
-                    self._out_shardings[i] for i in live_idx
-                )
+                targets = tuple(self._out_shardings[i] for i in live_idx)
                 with jax.named_scope("optimizer"):
                     new_live, new_states = opt_step_fn(
                         attrs, targets, lr, t, found_inf,
@@ -673,12 +734,9 @@ class TrainStep:
                 for j, i in enumerate(live_idx):
                     new_param_arrays[i] = new_live[j]
                     out_states[i] = new_states[j]
-                new_buffer_arrays = [b._data for b in buffers]
-                loss_val = loss._data
-                nan_flags = net.flags_output()
-                outer._nan_nets[outer._cur_nan_key] = net
+                nan_flags = flags()  # after the update: the plain step's place
             finally:
-                _swap_payloads(params, [s for s in old_p])
+                _swap_payloads(params, old_p)
                 _swap_payloads(buffers, old_b)
                 for p, (g, node, oi, sg) in zip(params, saved):
                     p.grad = g
@@ -688,135 +746,8 @@ class TrainStep:
             return (new_param_arrays, new_buffer_arrays, out_states,
                     loss_val, new_key, nan_flags)
 
-        def staged_accum(param_arrays, buffer_arrays, states, lr, t,
-                         found_inf, key, tree_args):
-            """accum_steps>1: scan k micro-batches, one update."""
-            jit_events.mark_traced()  # compile/retrace event log
-            k = self._accum
-            old_p = _swap_payloads(params, param_arrays)
-            old_b = _swap_payloads(buffers, buffer_arrays)
-            saved = [(p.grad, p._grad_node, p._out_index, p.stop_gradient)
-                     for p in params]
-            try:
-                for p in params:
-                    p.grad = None
-                    p._grad_node = None
-                    p.stop_gradient = False
-
-                def split(a):
-                    if not hasattr(a, "shape") or a.ndim == 0:
-                        raise ValueError(
-                            "accum_steps requires every data input to "
-                            "have a leading batch axis to micro-split; "
-                            f"got {a!r}"
-                        )
-                    if a.shape[0] % k:
-                        raise ValueError(
-                            f"batch axis {a.shape[0]} not divisible by "
-                            f"accum_steps={k}"
-                        )
-                    return a.reshape((k, a.shape[0] // k) + a.shape[1:])
-
-                micro_tree = jax.tree_util.tree_map(split, tree_args)
-                keys = jax.random.split(key, k + 1)
-
-                # fp32 accumulators for every trainable param; ZeRO
-                # layouts constrain the carry so the running sum stays
-                # sharded through the scan
-                def g_init(i, a):
-                    dt = (jnp.float32 if a.dtype in (jnp.bfloat16,
-                                                     jnp.float16)
-                          else a.dtype)
-                    z = jnp.zeros(a.shape, dt)
-                    if (self._grad_shardings is not None
-                            and self._grad_shardings[i] is not None):
-                        z = jax.lax.with_sharding_constraint(
-                            z, self._grad_shardings[i]
-                        )
-                    return z
-
-                grad_acc0 = [g_init(i, a)
-                             for i, a in enumerate(param_arrays)]
-                live_holder = []
-
-                def body(carry, xs):
-                    grad_acc, bufs = carry
-                    mt, key_i = xs
-                    _swap_payloads(buffers, bufs)
-                    for p in params:
-                        p.grad = None
-                        p._grad_node = None
-                    net = _nan_net(outer._built_nan)
-                    with _rng_lift(key_i):
-                        args_i, kwargs_i = mt
-                        with net:
-                            loss = loss_fn(model, *args_i, **kwargs_i)
-                            loss.backward()
-                    li = [i for i, p in enumerate(params)
-                          if p.grad is not None]
-                    if not live_holder:
-                        live_holder.append(li)
-                        outer._nan_nets[outer._cur_nan_key] = net
-                    new_acc = list(grad_acc)
-                    for i in li:
-                        g = params[i].grad._data.astype(grad_acc[i].dtype)
-                        if (self._grad_shardings is not None
-                                and self._grad_shardings[i] is not None):
-                            g = jax.lax.with_sharding_constraint(
-                                g, self._grad_shardings[i]
-                            )
-                        new_acc[i] = grad_acc[i] + g
-                    new_bufs = [b._data for b in buffers]
-                    return ((new_acc, new_bufs),
-                            (loss._data, net.flags_output()))
-
-                (grad_acc, buf_fin), (losses, nan_stack) = jax.lax.scan(
-                    body, (grad_acc0, list(buffer_arrays)),
-                    (micro_tree, keys[1:]),
-                )
-                live_idx = live_holder[0]
-                if self._live_idx is None:
-                    self._live_idx = live_idx
-                live = [params[i] for i in live_idx]
-                attrs = tuple(self._attr_for(p) for p in live)
-                live_grads = [
-                    (grad_acc[i] * (1.0 / k)).astype(
-                        param_arrays[i].dtype
-                    )
-                    for i in live_idx
-                ]
-                targets = tuple(self._out_shardings[i] for i in live_idx)
-                with jax.named_scope("optimizer"):
-                    new_live, new_states = opt_step_fn(
-                        attrs, targets, lr, t, found_inf,
-                        [params[i]._data for i in live_idx],
-                        live_grads,
-                        [states[i] for i in live_idx],
-                    )
-                new_param_arrays = list(param_arrays)
-                out_states = list(states)
-                for j, i in enumerate(live_idx):
-                    new_param_arrays[i] = new_live[j]
-                    out_states[i] = new_states[j]
-                loss_val = losses.mean()
-                nan_flags = (
-                    nan_stack.any(axis=0) if nan_stack.size
-                    else jnp.zeros((0,), jnp.bool_)
-                )
-            finally:
-                _swap_payloads(params, [s for s in old_p])
-                _swap_payloads(buffers, old_b)
-                for p, (g, node, oi, sg) in zip(params, saved):
-                    p.grad = g
-                    p._grad_node = node
-                    p._out_index = oi
-                    p.stop_gradient = sg
-            return (new_param_arrays, list(buf_fin), out_states,
-                    loss_val, keys[0], nan_flags)
-
         donate = (0, 2) if self._donate else ()
-        fn = staged if self._accum == 1 else staged_accum
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(staged, donate_argnums=donate)
 
     def _attr_for(self, p):
         """Per-param static attrs, mirroring Optimizer._collect for one

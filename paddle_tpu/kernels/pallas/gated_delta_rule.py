@@ -25,12 +25,11 @@ inside a chunk and ``S`` the state the chunk starts from,
 
 Everything is matmuls with bf16 operands and float32 accumulation; the
 state, the gates and every accumulator are float32. ``T`` is handed on in
-the operands' dtype. The kernels make it by doubling the diagonal blocks
+the operands' dtype. It is made by doubling the diagonal blocks
 (``_inv_unit_lower_blocks``: X - X L X, ``2 log2(C) - 2`` products of
-C x C float32 matrices, no row-by-row substitution), each product in three
-bf16 passes where the result is rounded to bf16 anyway;
-``chunked_gated_delta_rule`` keeps the nilpotent series ``(I - A)(I + A^2)
-(I + A^4)...`` at ``HIGHEST``.
+C x C float32 matrices, no row-by-row substitution): in the kernels each
+product in three bf16 passes where the result is rounded to bf16 anyway,
+in ``chunked_gated_delta_rule`` at ``HIGHEST``.
 
 Kernels, both over the grid (batch, groups of key heads, blocks of
 chunks) with the last axis sequential; ``choose_tile`` sizes a step from
@@ -107,23 +106,6 @@ def _mm_split(a, b):
         _mm(a_head, b_tail, _NN) + _mm(a_tail, b_head, _NN))
 
 
-def _inv_unit_lower_series(a):
-    """(I + a)^-1 for a strictly lower triangular [C, C] float32 ``a``:
-    (I - a)(I + a^2)(I + a^4)... ; a^C = 0 ends the series."""
-    c = a.shape[-1]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-           ).astype(jnp.float32)
-    power = -a
-    inv = eye + power
-    span = 2
-    while span < c:
-        power = _mm(power, power, ((1,), (0,)), _HI)
-        inv = inv + _mm(inv, power, ((1,), (0,)), _HI)
-        span *= 2
-    return inv
-
-
 def _doubled_blocks(a, row, col, operand, product):
     """``_inv_unit_lower_blocks``' doublings for ``a`` [..., C, lanes] with
     the row and column of each lane's entry and the products given."""
@@ -147,10 +129,11 @@ def _inv_unit_lower_blocks(a, split=False):
     doubling the diagonal blocks: with X the inverses of the blocks of
     size s and L the part of ``a`` below them inside the blocks of 2 s,
     ``[[X1, 0], [-X2 L X1, X2]] = X - X L X``. Two products a doubling,
-    ten at C 64, as the series takes; every factor is an inverse of a
-    block of ``I + a`` or a part of ``a``, where the series' powers grow
-    like binomials before they cancel (unit k rows 0.5 apart: the series
-    at ``HIGHEST`` is off by 1e8 of an entry, this by 1e-7).
+    ten at C 64, as the nilpotent series ``(I - a)(I + a^2)(I + a^4)...``
+    would take; every factor is an inverse of a block of ``I + a`` or a
+    part of ``a``, where the series' powers grow like binomials before
+    they cancel (unit k rows 0.5 apart: the series at ``HIGHEST`` is off
+    by 1e8 of an entry, this by 1e-7).
     ``split``: the products by ``_mm_split`` (three bf16 passes, 2e-5)
     and not at ``HIGHEST`` (six), for a result that is rounded to bf16
     (2^-9) on the next line."""
@@ -214,11 +197,11 @@ def _inv_heads(split):
 
 @jax.custom_vjp
 def _inv_unit_lower(a):
-    return _inv_unit_lower_series(a)
+    return _inv_unit_lower_blocks(a)
 
 
 def _inv_fwd(a):
-    inv = _inv_unit_lower_series(a)
+    inv = _inv_unit_lower_blocks(a)
     return inv, inv
 
 
@@ -234,8 +217,8 @@ _inv_unit_lower.defvjp(_inv_fwd, _inv_bwd)
 @jax.custom_vjp
 def _inv_saved(a, t):
     """(I + a)^-1 where the forward kernel has left it: ``t``, in the
-    operands' dtype. The backward kernel's inverse; its cotangent needs no
-    series."""
+    operands' dtype. The backward kernel's inverse; its cotangent needs
+    only ``t``."""
     return t
 
 

@@ -219,7 +219,7 @@ class FleetMetrics:
         self.scale_downs = 0          # replicas released
         self.scale_errors = 0         # degraded scaling ops (fault/spawn)
         self.requests_migrated = 0    # in-flight moved off a departing replica
-        # failover recovery timing (the bench [fleet] row): stamped at
+        # failover recovery timing: stamped at
         # death detection and at the first token a re-enqueued request
         # produces on its new replica
         self.last_failover_detect_s = None

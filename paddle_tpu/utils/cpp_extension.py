@@ -115,7 +115,7 @@ def _compile(sources, extra_cflags, build_directory, verbose):
                 f.write(blob)
             srcs.append(p)
         # build to a private temp name and publish atomically: concurrent
-        # processes (bench rows run one process per row) must never dlopen
+        # processes (launcher workers, test workers) must never dlopen
         # a half-written .so
         tmp_path = f"{so_path}.{os.getpid()}.tmp"
         cmd = (["g++", "-O2", "-shared", "-fPIC", "-std=c++17"]

@@ -27,9 +27,8 @@ def _run(code_or_path, *, args=(), env=None, cwd=REPO_ROOT, timeout=120):
 
 
 def test_imports_leave_the_backend_uninitialised():
-    """A chip belongs to one process: the launcher parent, the serving
-    CLI and a bench driver import these and then start the process that
-    needs the chip."""
+    """A chip belongs to one process: the launcher parent and the serving
+    CLI import these and then start the process that needs the chip."""
     proc = _run(
         "import jax._src.xla_bridge as xb\n"
         "for m in ('paddle_tpu', 'paddle_tpu.distributed.launch',\n"
@@ -95,13 +94,6 @@ def test_chip_smoke_result_line_has_exactly_the_contract_keys(monkeypatch):
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     assert json.loads(chip_smoke.result_line(device)) == {
         "ok": True, "device": device}
-
-
-def test_bench_refuses_the_cpu_before_any_row():
-    proc = _run(os.path.join(REPO_ROOT, "bench.py"))
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""        # no result line
-    assert "no TPU" in proc.stderr
 
 
 def test_peaks_table_raises_on_unknown_device_kind():

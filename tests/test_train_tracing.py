@@ -92,31 +92,40 @@ def test_recorded_span_keeps_the_times_it_was_given(ring):
 
 
 # ------------------------------------------------------------ device scopes
-def _tiny_step(**config):
+def _tiny_step(accum_steps=1, **config):
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny(**config))
     opt = paddle.optimizer.AdamW(
         learning_rate=1e-3, parameters=model.parameters())
     step = paddle.jit.TrainStep(
-        model, lambda m, ids: m(ids, labels=ids)[1], opt)
+        model, lambda m, ids: m(ids, labels=ids)[1], opt,
+        accum_steps=accum_steps)
     ids = paddle.to_tensor(
         np.random.RandomState(0).randint(0, 128, (2, 32)).astype(np.int32))
     return step, ids
 
 
 def _op_names(step, ids):
-    """The op_name of every operation of the step's lowered module."""
+    """The op_name of every operation of the step's lowered module. The
+    body of the accumulation's scan names its operations from the scope
+    or the transform on: those are handed out under ``body/``."""
     operands = step._prepare((ids,), {})     # builds step._compiled
     lowered = step._compiled.lower(*operands)
     names = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(
         debug_info=True)))
-    return {n for n in names if n.startswith("jit(staged)/")}
+    in_body = re.compile(r"(%s)/|(jvp|transpose)\(" % "|".join(SCOPES))
+    return ({n for n in names if n.startswith("jit(staged)/")}
+            | {"body/" + n for n in names if in_body.match(n)})
 
 
+@pytest.mark.parametrize("accum_steps", [1, 2])
 @pytest.mark.parametrize("fused_loss_chunk", [0, 16])
 def test_train_step_lowers_with_forward_and_backward_under_their_scope(
-        fused_loss_chunk):
-    step, ids = _tiny_step(fused_loss_chunk=fused_loss_chunk)
+        fused_loss_chunk, accum_steps):
+    """Whatever ``accum_steps``, the module is ``jit(staged)`` (the name
+    every ``PROGRAMS`` pattern under benchmarks/kernels/ finds the step
+    by) and the update is one ``optimizer`` scope at its top level."""
+    step, ids = _tiny_step(accum_steps, fused_loss_chunk=fused_loss_chunk)
     with jit_events.suppress():
         names = _op_names(step, ids)
 
@@ -127,6 +136,8 @@ def test_train_step_lowers_with_forward_and_backward_under_their_scope(
         assert under(scope, "/jvp("), scope
         assert under(scope, "/transpose(jvp("), scope
     assert under("optimizer", "")
+    assert all(n.startswith("jit(staged)/optimizer/")
+               for n in under("optimizer", ""))
     # the tape runs every vjp outside the scopes; call_vjp re-enters them
     scoped = re.compile("/(" + "|".join(SCOPES) + ")/")
     assert not [n for n in names
