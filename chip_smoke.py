@@ -287,6 +287,28 @@ def phase_hybrid():
         err = _rel_err(a, r)
         c.check(f"gated_delta_rule d{name} == chunked jax.numpy form",
                 err <= tol, f"rel err {err:.2e} (tol {tol})")
+    # the flat form, [B, T, H d] as the kernels read it and the DeltaNet
+    # mixer hands it over: the same numbers as the four-dimensional call
+    flat = lambda x: x.reshape(x.shape[:2] + (-1,))
+
+    def flat_loss(q, k, v, g, beta):
+        out = gdr.gated_delta_rule(q, k, v, g, beta, impl="pallas",
+                                   num_k_heads=hk)
+        return jnp.sum(out.astype(jnp.float32) * flat(w)), out
+
+    (_, out_f), grads_f = jax.jit(jax.value_and_grad(
+        flat_loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+        flat(q), flat(k), flat(v), g, beta)
+    worst = max(_rel_err(a, flat(b) if b.ndim == 4 else b)
+                for a, b in zip((out_f,) + grads_f, (out,) + grads))
+    forms = _compat.gdr_operands()
+    c.check("gated_delta_rule on flat operands == on [B, T, H, d], output "
+            "and every gradient",
+            worst <= 1e-6 and out_f.shape == flat(v).shape,
+            f"rel err {worst:.2e}; gdr_operands {forms}")
+    c.check("gated_delta_rule recorded both operand forms",
+            forms.get("flat", 0) >= 1 and forms.get("heads", 0) >= 1,
+            f"gdr_operands {forms}, gdr_blocks {sorted(_compat.gdr_blocks())}")
 
     # an expert layer holding 8 of 64 experts: kernel path against the
     # XLA path of the same layer, outputs and every gradient

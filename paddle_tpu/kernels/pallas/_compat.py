@@ -30,7 +30,10 @@ kernel call bumps ``paddle_tpu_kernels_flash_blocks{kernel,block_q,
 block_k}``, so a test, ``chip_smoke.py`` or a reader of the metrics
 registry can say which tile a shape got. ``record_gdr_blocks()`` does the
 same for the two gated-delta-rule kernels' grid step
-(``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``).
+(``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``), and
+``record_gdr_operands()`` for the form a ``gated_delta_rule`` call's q, k
+and v came in (``paddle_tpu_kernels_gdr_operands{form}``: ``flat`` is what
+the kernels read in place, ``heads`` costs a copy of each on a TPU).
 """
 from __future__ import annotations
 
@@ -184,3 +187,26 @@ def gdr_blocks():
         child.value
         for labels, child in _gdr_blocks_counter()._series()
     }
+
+
+def _gdr_operands_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_gdr_operands",
+        "Traced gated_delta_rule calls by the form of q, k and v: flat "
+        "[B, T, H d] or heads [B, T, H, d]",
+        labelnames=("form",),
+    )
+
+
+def record_gdr_operands(form):
+    """One traced ``gated_delta_rule`` call whose q, k and v came as
+    ``"flat"`` [B, T, H d] or as ``"heads"`` [B, T, H, d]."""
+    _gdr_operands_counter().inc(form=form)
+
+
+def gdr_operands():
+    """{form: traced calls} (test/diagnostic accessor)."""
+    return {labels["form"]: child.value
+            for labels, child in _gdr_operands_counter()._series()}

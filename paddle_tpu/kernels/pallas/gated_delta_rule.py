@@ -40,8 +40,10 @@ k^T``, ``q k^T`` are taken once a key head; the chunks of a step are a
 ``fori_loop``. ``gated_delta_rule_fwd`` walks a sequence's chunks in
 order, the states in VMEM scratch; it reads q, k, v in place as ``[B, T,
 H * d]`` (a head is a 128-lane column block, so nothing is transposed or
-repeated in HBM), and for the backward it also writes the state each
-chunk starts from and the chunk's ``T``. ``gated_delta_rule_bwd`` walks
+repeated in HBM; ``gated_delta_rule`` takes operands in that form as they
+are and reshapes ``[B, T, H, d]`` ones to it), and for the backward it
+also writes the state each chunk starts from and the chunk's ``T``.
+``gated_delta_rule_bwd`` walks
 the chunks from the last to the first with the states' cotangent in VMEM
 scratch; a chunk's vector-Jacobian product is ``jax.vjp`` of the
 forward's own chunk functions, taken while the kernel is traced, with the
@@ -63,8 +65,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.device import on_tpu
-from ._compat import pl_call, record_gdr_blocks
+from ...core import device
+from ._compat import pl_call, record_gdr_blocks, record_gdr_operands
 # one chip, one VMEM: the budget a step is sized against, the limit asked
 # of Mosaic above half its default scope
 from .flash_attention import (
@@ -641,13 +643,15 @@ def _gdr_core_bwd(tile, res, do):
 _gdr_core.defvjp(_gdr_core_fwd, _gdr_core_bwd)
 
 
-def _gdr_pallas(q, k, v, g, beta, chunk):
-    b, t, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
-    rep = hv // hk
-    key_heads, chunks = choose_tile(t, hk, rep, dk, dv, chunk, q.dtype)
+def _gdr_pallas(qf, kf, vf, g, beta, chunk, hk):
+    """``_gdr_core`` of flat operands of any length: the grid step chosen,
+    the tail padded, the gates laid out by chunk."""
+    b, t, _ = qf.shape
+    hv = g.shape[2]
+    rep, dk, dv = hv // hk, qf.shape[2] // hk, vf.shape[2] // hv
+    key_heads, chunks = choose_tile(t, hk, rep, dk, dv, chunk, qf.dtype)
     pad = (-t) % (chunk * chunks)
-    q, k, v, g, beta = (_pad_time(x, pad) for x in (q, k, v, g, beta))
+    qf, kf, vf, g, beta = (_pad_time(x, pad) for x in (qf, kf, vf, g, beta))
     tp = t + pad
 
     def rows(x):                 # [B, T, H_v] -> [B, NC, groups, heads, C]
@@ -655,39 +659,64 @@ def _gdr_pallas(q, k, v, g, beta, chunk):
             b, tp // chunk, chunk, hk // key_heads, key_heads * rep)
         return jnp.moveaxis(x, 2, 4)
 
-    o = _gdr_core(q.reshape(b, tp, hk * dk), k.reshape(b, tp, hk * dk),
-                  v.reshape(b, tp, hv * dv), jnp.cumsum(rows(g), -1),
-                  rows(beta), (key_heads, rep, chunks))
-    return o.reshape(b, tp, hv, dv)[:, :t]
+    o = _gdr_core(qf, kf, vf, jnp.cumsum(rows(g), -1), rows(beta),
+                  (key_heads, rep, chunks))
+    return o[:, :t]
+
+
+def _heads(x, count):
+    return x.reshape(x.shape[:2] + (count, x.shape[2] // count))
+
+
+def _flat(x):
+    return x.reshape(x.shape[:2] + (-1,))
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK, *,
-                     impl="auto"):
-    """o [B, T, H_v, d_v] of the gated delta rule, in v's dtype.
+                     impl="auto", num_k_heads=None):
+    """o of the gated delta rule, in v's dtype and v's form.
 
-    q, k: [B, T, H_k, d_k], already normalised and scaled; v:
-    [B, T, H_v, d_v] with ``H_v`` a multiple of ``H_k``; g (log gate,
-    <= 0) and beta: [B, T, H_v]. Any length: the tail is padded with
-    tokens that write nothing (beta 0, g 0).
+    q, k, already normalised and scaled, and v come in one of two forms,
+    told apart by their rank. **Heads**: q, k [B, T, H_k, d_k] and v
+    [B, T, H_v, d_v]. **Flat**, with ``num_k_heads`` given: q, k
+    [B, T, H_k * d_k] and v [B, T, H_v * d_v], a head a block of
+    consecutive columns: what the kernels read in place, where the heads
+    form is reshaped to it and o back (on a TPU a copy of each: the tiles
+    differ). ``H_v``, a multiple of ``H_k``, is the last axis of g (log
+    gate, <= 0) and beta, both [B, T, H_v]. Any length: the tail is
+    padded with tokens that write nothing (beta 0, g 0).
 
     impl: ``"auto"`` is the kernel on a TPU (FLAGS_use_pallas_kernels)
-    and the ``jax.numpy`` chunked form elsewhere; ``"pallas"`` is always
-    the kernel (the interpreter off the TPU); ``"xla"`` always the
+    and the ``jax.numpy`` chunked form elsewhere (which takes the heads
+    form, so there the flat form is the one reshaped); ``"pallas"`` is
+    always the kernel (the interpreter off the TPU); ``"xla"`` always the
     ``jax.numpy`` form. All three are differentiable."""
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(
             f'gated_delta_rule impl must be "auto", "pallas" or "xla", '
             f"got {impl!r}")
-    if v.shape[2] % q.shape[2]:
+    flat = q.ndim == 3
+    if flat and num_k_heads is None:
         raise ValueError(
-            f"gated_delta_rule: {v.shape[2]} value heads over "
-            f"{q.shape[2]} key heads")
+            "gated_delta_rule: q, k, v as [B, T, H * d] need num_k_heads")
+    hk, hv = (num_k_heads if flat else q.shape[2]), g.shape[-1]
+    if hv % hk:
+        raise ValueError(
+            f"gated_delta_rule: {hv} value heads over {hk} key heads")
+    record_gdr_operands("flat" if flat else "heads")
     if impl == "auto":
         from ...core import flags
 
         impl = "pallas" if (
-            on_tpu() and flags.get_flag("FLAGS_use_pallas_kernels")
+            device.on_tpu()
+            and flags.get_flag("FLAGS_use_pallas_kernels")
         ) else "xla"
     if impl == "xla":
-        return chunked_gated_delta_rule(q, k, v, g, beta, chunk)
-    return _gdr_pallas(q, k, v, g, beta, chunk)
+        if flat:
+            q, k, v = _heads(q, hk), _heads(k, hk), _heads(v, hv)
+        o = chunked_gated_delta_rule(q, k, v, g, beta, chunk)
+        return _flat(o) if flat else o
+    if flat:
+        return _gdr_pallas(q, k, v, g, beta, chunk, hk)
+    return _gdr_pallas(_flat(q), _flat(k), _flat(v), g, beta, chunk,
+                       hk).reshape(v.shape)

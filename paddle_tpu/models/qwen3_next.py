@@ -16,10 +16,12 @@ layer's MLP is the expert layer. Final ZNorm, untied head, no bias.
     first ``partial_rotary_factor`` of each head, the core is
     ``F.scaled_dot_product_attention`` (the flash kernels on a TPU), and
     ``out = (o * sigmoid(gate)) W_o``.
-  * Gated DeltaNet: two projections in HF's per-key-head interleaved
-    layout, a depthwise causal convolution with SiLU over q|k|v, the gated
-    delta rule (``F.gated_delta_rule``: the chunked Pallas kernel on a
-    TPU), RMSNorm gated by ``silu(z)``, an output projection.
+  * Gated DeltaNet: two projections whose weights keep HF's per-key-head
+    interleaved columns (undone on the weight, ``_head_major``, so every
+    activation is [b, t, heads * d] as the kernel reads it), a depthwise
+    causal convolution with SiLU over q|k|v, the gated delta rule
+    (``F.gated_delta_rule``: the chunked Pallas kernel on a TPU), RMSNorm
+    gated by ``silu(z)``, an output projection.
   * MoE: ``incubate.moe.MoELayer`` told which experts it holds
     (``held_experts=(start, count)``: one expert-parallel rank's share;
     all of them by default), router in float32, top-k weights normalised
@@ -202,21 +204,46 @@ def _remat(fn):
     return wrapped
 
 
-def _unpack(qkvz, ba, *, num_k_heads, num_v_heads, head_k_dim, head_v_dim):
-    """The mixer's two projections in HF's per-key-head interleaved
-    layout (``fix_query_key_value_ordering``) -> (qkv [b, t, 2 H_k d_k +
-    H_v d_v] as the convolution takes it, z [b, t, H_v, d_v], b and a
-    [b, t, H_v])."""
-    bsz, t = qkvz.shape[:2]
-    rep = num_v_heads // num_k_heads
-    x = qkvz.reshape(bsz, t, num_k_heads, -1)
-    q, k, v, z = jnp.split(
-        x, [head_k_dim, 2 * head_k_dim, 2 * head_k_dim + rep * head_v_dim],
-        axis=-1)
-    b, a = jnp.split(ba.reshape(bsz, t, num_k_heads, 2 * rep), 2, axis=-1)
-    flat = lambda y: y.reshape(bsz, t, -1)
-    return (jnp.concatenate([flat(q), flat(k), flat(v)], -1),
-            z.reshape(bsz, t, num_v_heads, head_v_dim), flat(b), flat(a))
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _head_major(weight, *, num_k_heads, widths):
+    """A projection's weight [hidden, H_k * sum(widths)] with its columns
+    in HF's per-key-head interleave (``fix_query_key_value_ordering``: per
+    key head ``widths[0]`` columns of the first part, ``widths[1]`` of the
+    next, ...) -> [hidden, H_k widths[0] | H_k widths[1] | ...]: part by
+    part, each part's heads in order. The interleave is undone here, on
+    the weight, once a forward (50 MB at the published widths, where every
+    boundary is a multiple of 128 columns and whole lane blocks move), so
+    that no activation is ever split by key head; the transpose puts the
+    weight's gradient back in HF's order."""
+    hidden = weight.shape[0]
+    per_head = weight.reshape(hidden, num_k_heads, sum(widths))
+    bounds = np.cumsum((0,) + tuple(widths))
+    return jnp.concatenate(
+        [per_head[:, :, lo:hi].reshape(hidden, -1)
+         for lo, hi in zip(bounds, bounds[1:])], axis=-1)
+
+
+def _head_indicator(heads, d):
+    """[heads * d, heads] float32: 1 where the column is the head's."""
+    return (jnp.arange(heads * d)[:, None] // d
+            == jnp.arange(heads)).astype(jnp.float32)
+
+
+def _head_sums(x, heads):
+    """x [..., heads * d] float32 -> each head's sum [..., heads], as a
+    product with the heads' indicator at ``HIGHEST``: a reshape to
+    [..., heads, d] is a copy on a TPU (the tiles differ)."""
+    return jnp.matmul(
+        x, _head_indicator(heads, x.shape[-1] // heads), precision=_HI)
+
+
+def _head_spread(x, d):
+    """x [..., heads] float32 -> [..., heads * d], each head's value over
+    its d columns: ``_head_sums``' transpose, exact at ``HIGHEST``."""
+    return jnp.matmul(
+        x, _head_indicator(x.shape[-1], d).T, precision=_HI)
 
 
 @_remat
@@ -231,53 +258,54 @@ def _causal_conv_silu(x, weight):
 
 
 @_remat
-def _delta_rule_inputs(qkv, b, a, a_log, dt_bias, *, num_k_heads,
-                       num_v_heads, head_k_dim, head_v_dim):
-    """The convolved channels and the gate projections -> what the delta
-    rule takes: q, k [b, t, H_k, d_k] L2-normalised over d_k (eps 1e-6,
-    as the source's ``l2norm``) with q scaled by d_k^-1/2, v
-    [b, t, H_v, d_v], g = -exp(A_log) softplus(a + dt_bias) and beta =
-    sigmoid(b), both float32 [b, t, H_v]."""
-    bsz, t = qkv.shape[:2]
-    kd = num_k_heads * head_k_dim
-    q, k, v = jnp.split(qkv, [kd, 2 * kd], axis=-1)
+def _delta_rule_inputs(qk, b, a, a_log, dt_bias, *, num_k_heads, head_k_dim):
+    """The convolved q | k channels and the gate projections -> what the
+    delta rule takes beside v: q, k [b, t, H_k d_k] (its flat form)
+    L2-normalised over each head's d_k (eps 1e-6, as the source's
+    ``l2norm``) with q scaled by d_k^-1/2, g = -exp(A_log) softplus(a +
+    dt_bias) and beta = sigmoid(b), both float32 [b, t, H_v]."""
+    q, k = jnp.split(qk, 2, axis=-1)
 
     def l2norm(y):
-        yf = y.reshape(bsz, t, num_k_heads, head_k_dim).astype(jnp.float32)
-        return yf * jax.lax.rsqrt(
-            jnp.sum(yf * yf, -1, keepdims=True) + 1e-6)
+        yf = y.astype(jnp.float32)
+        return yf * _head_spread(jax.lax.rsqrt(
+            _head_sums(yf * yf, num_k_heads) + 1e-6), head_k_dim)
 
     f32 = jnp.float32
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
         a.astype(f32) + dt_bias.astype(f32))
-    return ((l2norm(q) * head_k_dim ** -0.5).astype(qkv.dtype),
-            l2norm(k).astype(qkv.dtype),
-            v.reshape(bsz, t, num_v_heads, head_v_dim), g,
-            jax.nn.sigmoid(b.astype(f32)))
+    return ((l2norm(q) * head_k_dim ** -0.5).astype(qk.dtype),
+            l2norm(k).astype(qk.dtype), g, jax.nn.sigmoid(b.astype(f32)))
 
 
 @_remat
 def _gated_rms_norm(x, weight, gate, *, epsilon):
-    """RMSNorm(x; weight) * silu(gate) over the last axis, in float32
-    (plain weight, initialised at 1): the mixer's output norm."""
+    """RMSNorm(x; weight) * silu(gate) over each head's columns, in
+    float32 (plain weight [d], initialised at 1): the mixer's output norm.
+    x and gate [b, t, heads * d], as the delta rule hands o over."""
+    d = weight.shape[0]
+    heads = x.shape[-1] // d
     xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), -1, keepdims=True)
-    out = weight.astype(jnp.float32) * (xf * jax.lax.rsqrt(var + epsilon))
+    var = _head_sums(jnp.square(xf), heads) / d
+    out = jnp.tile(weight.astype(jnp.float32), heads) * (
+        xf * _head_spread(jax.lax.rsqrt(var + epsilon), d))
     return (out * jax.nn.silu(gate.astype(jnp.float32))).astype(x.dtype)
 
 
 class Qwen3NextGatedDeltaNet(Layer):
+    """From ``in_proj_qkvz`` to ``out_proj`` every activation is [b, t,
+    heads * d], a head a block of consecutive columns: the form the
+    delta-rule kernels read in place. The parameters keep HF's shapes and
+    column order."""
+
     def __init__(self, config: Qwen3NextConfig):
         super().__init__()
         h = config.hidden_size
-        self.heads = dict(
-            num_k_heads=config.linear_num_key_heads,
-            num_v_heads=config.linear_num_value_heads,
-            head_k_dim=config.linear_key_head_dim,
-            head_v_dim=config.linear_value_head_dim)
-        hk, hv = self.heads["num_k_heads"], self.heads["num_v_heads"]
-        key_dim = hk * self.heads["head_k_dim"]
-        value_dim = hv * self.heads["head_v_dim"]
+        self.num_k_heads = hk = config.linear_num_key_heads
+        self.num_v_heads = hv = config.linear_num_value_heads
+        self.head_k_dim = config.linear_key_head_dim
+        self.head_v_dim = config.linear_value_head_dim
+        key_dim, value_dim = hk * self.head_k_dim, hv * self.head_v_dim
         self._epsilon = config.rms_norm_eps
         # the layer's own parameters before its sublayers': creation order
         # is then the order parameters() lists them in
@@ -292,25 +320,38 @@ class Qwen3NextGatedDeltaNet(Layer):
                 np.random.default_rng(0).uniform(1e-3, 16.0, hv)
             ).astype("float32"))))
         self.norm_weight = self.create_parameter(
-            shape=[self.heads["head_v_dim"]],
+            shape=[self.head_v_dim],
             attr=ParamAttr(initializer=I.Constant(1.0)))
+        # columns per key head: q | k | v of its value heads | z of them,
+        # and b of them | a of them
         self.in_proj_qkvz = _linear(config, h, 2 * key_dim + 2 * value_dim)
         self.in_proj_ba = _linear(config, h, 2 * hv)
         self.out_proj = _linear(config, value_dim, h)
 
     def forward(self, hidden):
-        b, s = hidden.shape[0], hidden.shape[1]
-        qkv, z, beta_in, a = _op(
-            _unpack, self.in_proj_qkvz(hidden), self.in_proj_ba(hidden),
-            **self.heads)
-        qkv = _op(_causal_conv_silu, qkv, self.conv_weight)
-        q, k, v, g, beta = _op(
-            _delta_rule_inputs, qkv, beta_in, a, self.A_log, self.dt_bias,
-            **self.heads)
-        o = F.gated_delta_rule(q, k, v, g, beta)
-        o = _op(_gated_rms_norm, o, self.norm_weight, z,
+        hk, hv = self.num_k_heads, self.num_v_heads
+        dk, dv = self.head_k_dim, self.head_v_dim
+        rep, keys, channels = hv // hk, 2 * hk * dk, 2 * hk * dk + hv * dv
+        qkvz = F.linear(hidden, _op(
+            _head_major, self.in_proj_qkvz.weight, num_k_heads=hk,
+            widths=(dk, dk, rep * dv, rep * dv)))
+        ba = F.linear(hidden, _op(
+            _head_major, self.in_proj_ba.weight, num_k_heads=hk,
+            widths=(rep, rep)))
+        # q | k and v convolved apart (the convolution is depthwise): v is
+        # then an array of its own, as a kernel's operand has to be, and
+        # not a slice that is copied out
+        qk = _op(_causal_conv_silu, qkvz[:, :, :keys],
+                 self.conv_weight[:, :keys])
+        v = _op(_causal_conv_silu, qkvz[:, :, keys:channels],
+                self.conv_weight[:, keys:])
+        q, k, g, beta = _op(
+            _delta_rule_inputs, qk, ba[:, :, :hv], ba[:, :, hv:],
+            self.A_log, self.dt_bias, num_k_heads=hk, head_k_dim=dk)
+        o = F.gated_delta_rule(q, k, v, g, beta, num_k_heads=hk)
+        o = _op(_gated_rms_norm, o, self.norm_weight, qkvz[:, :, channels:],
                 epsilon=self._epsilon)
-        return self.out_proj(F.reshape(o, [b, s, -1]))
+        return self.out_proj(o)
 
 
 class Qwen3NextMLP(Layer):

@@ -2304,9 +2304,9 @@ def partial_rope_qk(q, k, rotary_dim, base=10000.0, name=None):
     return _call('partial_rope_qk', _impl_gdn_ops.partial_rope_qk, (q, k,), {'rotary_dim': rotary_dim, 'base': base})
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", name=None):
+def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", num_k_heads=None, name=None):
     """gated_delta_rule (generated from ops.yaml)."""
-    return _call('gated_delta_rule', _impl_gdn_ops.gated_delta_rule, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl})
+    return _call('gated_delta_rule', _impl_gdn_ops.gated_delta_rule, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl, 'num_k_heads': num_k_heads})
 
 
 def fused_linear_cross_entropy(x, weight, labels, chunk_size=4096, ignore_index=-100, name=None):

@@ -41,9 +41,11 @@ def partial_rope_qk(q, k, *, rotary_dim, base=10000.0):
             jnp.concatenate([kr, k[..., rotary_dim:]], -1))
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk=64, impl="auto"):
+def gated_delta_rule(q, k, v, g, beta, *, chunk=64, impl="auto",
+                     num_k_heads=None):
     """The public op face of ``kernels.pallas.gated_delta_rule`` (Pallas
-    imports stay function-scoped, the nn_ops pattern)."""
+    imports stay function-scoped, the nn_ops pattern): q, k, v as
+    [b, t, heads, d], or as [b, t, heads * d] with ``num_k_heads``."""
     from ...kernels.pallas.gated_delta_rule import gated_delta_rule as _gdr
 
-    return _gdr(q, k, v, g, beta, chunk, impl=impl)
+    return _gdr(q, k, v, g, beta, chunk, impl=impl, num_k_heads=num_k_heads)

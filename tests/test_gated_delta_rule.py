@@ -253,6 +253,62 @@ def test_a_traced_kernel_call_is_counted_by_its_tile():
         ("gated_delta_rule_fwd", 2, 3), ("gated_delta_rule_bwd", 2, 3)}
 
 
+def _flat(x):
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_flat_operands_equal_the_heads_form(impl):
+    """q, k [B, T, H_k d_k] and v [B, T, H_v d_v] with ``num_k_heads``
+    (what the kernels read in place, and what the DeltaNet mixer hands
+    over) against the four-dimensional call: o comes back in v's form,
+    and it and all five gradients are the same numbers. 40 tokens: the
+    tail is padded in either form."""
+    q, k, v, g, beta = _inputs(2, 40, 2, 4, 16, 8, seed=12)
+    w = jax.random.normal(jax.random.key(13), v.shape)
+
+    def run(w, *args, **kwargs):
+        def loss(*a):
+            o = G.gated_delta_rule(*a, chunk=16, impl=impl, **kwargs)
+            return jnp.sum(o * w), o
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+
+    heads, o_heads = run(w, q, k, v, g, beta)
+    flat, o_flat = run(_flat(w), _flat(q), _flat(k), _flat(v), g, beta,
+                       num_k_heads=2)
+    assert o_flat.shape == (2, 40, 4 * 8) and o_flat.dtype == v.dtype
+    np.testing.assert_array_equal(o_flat, _flat(o_heads))
+    for name, a, b in zip("q k v g beta".split(), flat, heads):
+        assert a.shape == (b.shape if b.ndim == 3 else _flat(b).shape), name
+        np.testing.assert_array_equal(a, b.reshape(a.shape), err_msg=name)
+
+
+def test_a_traced_call_is_counted_by_the_form_of_its_operands():
+    from paddle_tpu.kernels.pallas._compat import gdr_operands
+
+    q, k, v, g, beta = _inputs(1, 32, 1, 2, 16, 8, seed=6)
+    before = gdr_operands()
+    G.gated_delta_rule(q, k, v, g, beta, chunk=16, impl="xla")
+    once = gdr_operands()
+    assert once.get("heads", 0) == before.get("heads", 0) + 1
+    assert once.get("flat", 0) == before.get("flat", 0)
+    G.gated_delta_rule(_flat(q), _flat(k), _flat(v), g, beta, chunk=16,
+                       impl="pallas", num_k_heads=1)
+    twice = gdr_operands()
+    assert twice.get("flat", 0) == once.get("flat", 0) + 1
+    assert twice.get("heads", 0) == once.get("heads", 0)
+
+
+def test_flat_operands_need_the_number_of_key_heads():
+    q, k, v, g, beta = _inputs(1, 16, 2, 4, 8, 8)
+    with pytest.raises(ValueError, match="num_k_heads"):
+        G.gated_delta_rule(_flat(q), _flat(k), _flat(v), g, beta)
+    # and the value heads, read from g, are held to them in this form too
+    with pytest.raises(ValueError, match="value heads"):
+        G.gated_delta_rule(_flat(q), _flat(k), _flat(v), g, beta,
+                           num_k_heads=3)
+
+
 def _adversarial_system(c, closeness, seed):
     """A = tril(beta exp(G_t - G_i) k_t . k_i, -1) of a chunk with beta 1,
     g 0 and unit k rows within ``closeness`` of one direction: the largest

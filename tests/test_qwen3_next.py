@@ -83,51 +83,113 @@ def test_causal_conv_sees_the_past_only():
     np.testing.assert_array_equal(got2[:, :5], got[:, :5])
 
 
-def test_unpack_follows_the_per_key_head_layout():
-    """HF's fix_query_key_value_ordering: per key head [q | k | v of its
-    value heads | z of them] and [b of them | a of them]."""
-    hk, hv, dk, dv = 2, 4, 3, 5
+def _hf_unpack(y, hk, hv, dk, dv):
+    """HF's fix_query_key_value_ordering on the last axis of a projection
+    (per key head: q | k | v of its value heads | z of them) -> the parts
+    [q of all heads | k | v | z]: what the mixer did on activations before
+    its weight took the interleave."""
     rep = hv // hk
-    per = 2 * dk + 2 * rep * dv
-    qkvz = np.arange(hk * per, dtype="float32").reshape(1, 1, hk * per)
-    ba = np.arange(hk * 2 * rep, dtype="float32").reshape(1, 1, -1)
-    qkv, z, b, a = Q._op(
-        Q._unpack, _t(qkvz), _t(ba), num_k_heads=hk, num_v_heads=hv, head_k_dim=dk,
-        head_v_dim=dv)
-    heads = qkvz.reshape(hk, per)
-    want_q = heads[:, :dk].reshape(-1)
-    want_k = heads[:, dk:2 * dk].reshape(-1)
-    want_v = heads[:, 2 * dk:2 * dk + rep * dv].reshape(-1)
+    heads = y.reshape(y.shape[:-1] + (hk, 2 * dk + 2 * rep * dv))
+    bounds = [0, dk, 2 * dk, 2 * dk + rep * dv, 2 * dk + 2 * rep * dv]
+    return np.concatenate(
+        [heads[..., lo:hi].reshape(y.shape[:-1] + (-1,))
+         for lo, hi in zip(bounds, bounds[1:])], -1)
+
+
+@pytest.mark.parametrize("hk,hv,dk,dv", [(2, 4, 3, 5), (2, 4, 128, 128)],
+                         ids=["tiny_widths", "whole_lane_blocks"])
+def test_unpack_follows_the_per_key_head_layout(hk, hv, dk, dv):
+    """The interleave undone on the weight: the product with the permuted
+    weight is, bit for bit, the unpacked product with the stored one
+    (eighths of small integers: every sum is exact, whatever its order),
+    and the gradient comes back in HF's column order."""
+    rng = np.random.default_rng(10)
+    rep, hidden = hv // hk, 16
+    widths = (dk, dk, rep * dv, rep * dv)
+    cols = hk * sum(widths)
+    w = rng.integers(-8, 9, (hidden, cols)).astype("float32") / 8
+    x = rng.integers(-8, 9, (2, 3, hidden)).astype("float32") / 8
+    flat = Q._op(Q._head_major, _t(w), num_k_heads=hk, widths=widths)
+    order = _hf_unpack(np.arange(cols), hk, hv, dk, dv)
+    np.testing.assert_array_equal(flat.numpy(), w[:, order])
     np.testing.assert_array_equal(
-        qkv.numpy()[0, 0], np.concatenate([want_q, want_k, want_v]))
+        F.linear(_t(x), flat).numpy(),
+        _hf_unpack(F.linear(_t(x), _t(w)).numpy(), hk, hv, dk, dv))
+    # q of key head 1 stands after q of key head 0, k after all of q
+    assert order[dk] == sum(widths) and order[hk * dk] == dk
+    cot = rng.integers(-8, 9, (2, 3, cols)).astype("float32") / 8
+    grad = jax.grad(lambda w: jnp.sum(jnp.matmul(x, Q._head_major(
+        w, num_k_heads=hk, widths=widths)) * cot))(jnp.asarray(w))
+    want = np.zeros_like(w)
+    want[:, order] = np.einsum("bth,btc->hc", x, cot)
+    np.testing.assert_array_equal(np.asarray(grad), want)
+
+
+def test_the_gate_projection_is_unpacked_on_its_weight_too():
+    """[b of a key head's value heads | a of them] -> [b of all | a]."""
+    hk, rep = 2, 2
+    ba = np.arange(3 * hk * 2 * rep, dtype="float32").reshape(3, -1)
+    flat = Q._op(Q._head_major, _t(ba), num_k_heads=hk,
+                 widths=(rep, rep)).numpy()
+    heads = ba.reshape(3, hk, 2 * rep)
     np.testing.assert_array_equal(
-        z.numpy()[0, 0], heads[:, 2 * dk + rep * dv:].reshape(hv, dv))
-    np.testing.assert_array_equal(
-        b.numpy()[0, 0], ba.reshape(hk, 2 * rep)[:, :rep].reshape(-1))
-    np.testing.assert_array_equal(
-        a.numpy()[0, 0], ba.reshape(hk, 2 * rep)[:, rep:].reshape(-1))
+        flat, np.concatenate([heads[:, :, :rep].reshape(3, -1),
+                              heads[:, :, rep:].reshape(3, -1)], -1))
 
 
 def test_prepare_normalises_scales_and_gates():
     rng = np.random.default_rng(5)
-    hk, hv, dk, dv = 2, 4, 4, 3
-    qkv = rng.normal(size=(1, 5, 2 * hk * dk + hv * dv)).astype("float32")
+    hk, hv, dk = 2, 4, 4
+    qk = rng.normal(size=(1, 5, 2 * hk * dk)).astype("float32")
     b, a = (rng.normal(size=(1, 5, hv)).astype("float32") for _ in "ba")
     a_log = np.log(rng.uniform(0.1, 16, hv)).astype("float32")
     dt = np.ones(hv, "float32")
-    q, k, v, g, beta = (x.numpy() for x in Q._op(
-        Q._delta_rule_inputs, _t(qkv), _t(b), _t(a), _t(a_log), _t(dt),
-        num_k_heads=hk,
-        num_v_heads=hv, head_k_dim=dk, head_v_dim=dv))
-    np.testing.assert_allclose(np.linalg.norm(k, axis=-1), 1.0, atol=1e-4)
-    np.testing.assert_allclose(np.linalg.norm(q, axis=-1), dk ** -0.5,
-                               atol=1e-4)
-    np.testing.assert_array_equal(
-        v, qkv[..., 2 * hk * dk:].reshape(1, 5, hv, dv))
+    q, k, g, beta = (x.numpy() for x in Q._op(
+        Q._delta_rule_inputs, _t(qk), _t(b), _t(a), _t(a_log), _t(dt),
+        num_k_heads=hk, head_k_dim=dk))
+    # the delta rule's flat form: a head is dk consecutive columns
+    assert q.shape == k.shape == (1, 5, hk * dk)
+    np.testing.assert_allclose(
+        np.linalg.norm(k.reshape(1, 5, hk, dk), axis=-1), 1.0, atol=1e-4)
+    np.testing.assert_allclose(
+        np.linalg.norm(q.reshape(1, 5, hk, dk), axis=-1), dk ** -0.5,
+        atol=1e-4)
     np.testing.assert_allclose(
         g, -np.exp(a_log) * np.log1p(np.exp(a + dt)), rtol=1e-5)
     np.testing.assert_allclose(beta, 1 / (1 + np.exp(-b)), rtol=1e-5)
     assert (g < 0).all() and g.dtype == np.float32
+
+
+@pytest.mark.parametrize("which", ["l2norm", "gated_norm"])
+def test_the_flat_norms_equal_the_per_head_form(which):
+    """The per-head sums taken on [b, t, heads * d] (a product with the
+    heads' indicator, no reshape to heads) against plain numpy over
+    [b, t, heads, d] in float64: float32 rounding apart."""
+    rng = np.random.default_rng(11)
+    heads, d = 4, 128
+    x = rng.normal(size=(2, 6, heads * d)).astype("float32")
+    by_head = x.astype("float64").reshape(2, 6, heads, d)
+    if which == "l2norm":
+        zeros = np.zeros((2, 6, heads), "float32")
+        q, k, _, _ = Q._op(
+            Q._delta_rule_inputs, _t(np.concatenate([x, x], -1)),
+            _t(zeros), _t(zeros), _t(np.zeros(heads, "float32")),
+            _t(np.zeros(heads, "float32")), num_k_heads=heads, head_k_dim=d)
+        want = (by_head / np.sqrt(
+            (by_head ** 2).sum(-1, keepdims=True) + 1e-6)).reshape(x.shape)
+        np.testing.assert_allclose(q.numpy(), want * d ** -0.5, rtol=1e-6,
+                                   atol=1e-8)
+        got = k.numpy()
+    else:
+        z = rng.normal(size=x.shape).astype("float32")
+        w = rng.normal(size=(d,)).astype("float32")
+        zf = z.astype("float64").reshape(by_head.shape)
+        want = (w * by_head / np.sqrt(
+            (by_head ** 2).mean(-1, keepdims=True) + 1e-6) * (
+            zf / (1 + np.exp(-zf)))).reshape(x.shape)
+        got = Q._op(Q._gated_rms_norm, _t(x), _t(w), _t(z),
+                    epsilon=1e-6).numpy()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
 
 
 def test_router_logits_in_float32_do_not_round_the_operands():
@@ -397,6 +459,26 @@ def test_parameters_are_listed_in_the_order_they_are_created():
     assert tuple(model.model.layers[0].mlp.gate.weight.shape) == (32, 16)
 
 
+def test_the_mixers_parameters_keep_hfs_names_and_shapes():
+    """The interleave is undone inside the forward: what a checkpoint, the
+    optimizer and benchmarks/weights_qwen3_next.py see is HF's."""
+    model = Qwen3NextForCausalLM(Qwen3NextConfig.tiny())
+    mixer = model.model.layers[0].linear_attn
+    assert [(n, tuple(p.shape)) for n, p in mixer.named_parameters()] == [
+        ("conv_weight", (4, 64)), ("dt_bias", (4,)), ("A_log", (4,)),
+        ("norm_weight", (8,)), ("in_proj_qkvz.weight", (32, 96)),
+        ("in_proj_ba.weight", (32, 8)), ("out_proj.weight", (32, 32))]
+    keys = set(model.state_dict())
+    assert {"model.layers.0.linear_attn.in_proj_qkvz.weight",
+            "model.layers.0.linear_attn.in_proj_ba.weight"} <= keys
+    ids = _t(np.random.default_rng(4).integers(0, 128, (1, 16)).astype(
+        "int32"))
+    model(ids, labels=ids)[1].backward()
+    for proj in (mixer.in_proj_qkvz, mixer.in_proj_ba):
+        assert tuple(proj.weight.grad.shape) == tuple(proj.weight.shape)
+        assert np.abs(proj.weight.grad.numpy()).max() > 0
+
+
 @pytest.mark.parametrize("recompute", [False, True])
 def test_trains_through_train_step_and_carries_the_load(recompute):
     paddle.seed(0)
@@ -467,6 +549,7 @@ def test_the_model_is_causal():
 def test_scopes_and_counters_of_a_traced_step():
     """The device scopes of PR 25's vocabulary and the registry counter
     bumped once a traced call."""
+    from paddle_tpu.kernels.pallas._compat import gdr_operands
     from paddle_tpu.observability import counter
 
     paddle.seed(0)
@@ -480,7 +563,7 @@ def test_scopes_and_counters_of_a_traced_step():
         return sum(child.value for labels, child in series._series()
                    if labels == key)
 
-    before = count()
+    before, forms = count(), gdr_operands()
     params = [p._data for p in model.parameters()]
 
     def loss(arrays, ids):
@@ -500,6 +583,9 @@ def test_scopes_and_counters_of_a_traced_step():
     ids = jnp.zeros((1, 32), jnp.int32)
     text = jax.jit(loss).trace(params, ids).lower().as_text(debug_info=True)
     assert count() == before + 4                  # one a layer
+    # every DeltaNet layer hands the delta rule its flat form, none heads
+    assert gdr_operands().get("flat", 0) == forms.get("flat", 0) + 3
+    assert gdr_operands().get("heads", 0) == forms.get("heads", 0)
     for name in ("embedding", "linear_attention", "attention", "moe",
                  "moe.router", "moe.experts", "moe.shared_expert",
                  "lm_head_loss"):
