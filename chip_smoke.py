@@ -15,6 +15,10 @@ parameters; weights random from a seed):
            (incubate.moe.MoELayer(held=), grouped_matmul with its dlhs and
            drhs kernels) against the same layer on the XLA path, in one
            pass over its rows and in four.
+  state_space  mamba2_ssd (fwd+bwd kernels) at the shape the Granite cell
+           runs it ([2, 8192, 4096], 64 heads of 64, state 128) against its
+           jax.numpy chunked form and against the recurrence token by
+           token: the output and the gradients of all six operands.
   train    AdamW(multi_precision) + jit.TrainStep (donation on) fed by the
            forked-worker DataLoader, seq 2048: loss finite and falling,
            traced and compiled once, parameters on the TPU.
@@ -376,6 +380,61 @@ def phase_hybrid():
     c.done()
 
 
+# ----------------------------------------------------------- state space
+def phase_state_space():
+    """The Mamba-2 kernels that granite-4.0-h's training path brought:
+    through Mosaic at the cell's own shape, equal to the jax.numpy chunked
+    form and to the recurrence."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.pallas import _compat
+    from paddle_tpu.kernels.pallas import mamba2_ssd as ssd
+
+    c = Checks("state_space")
+    bf16, tol = jnp.bfloat16, 2e-2
+    b, t, h, p, n = 2, 8192, 64, 64, 128
+    ks = jax.random.split(jax.random.key(2), 7)
+    # the sizes a seeded model hands the kernel: x, B, C after silu of a
+    # convolution, dt = softplus(1 + small), A = -(1 .. 64), D = 1
+    x, w = (jax.random.normal(r, (b, t, h * p), bf16) for r in ks[:2])
+    bm, cm = (0.5 * jax.random.normal(r, (b, t, n), bf16) for r in ks[2:4])
+    dt = jax.nn.softplus(1.0 + 0.5 * jax.random.normal(ks[4], (b, t, h)))
+    a = -jnp.arange(1, h + 1, dtype=jnp.float32)
+    d = jnp.ones((h,), jnp.float32) + 0.1 * jax.random.normal(ks[5], (h,))
+    ops = (x, dt, a, bm, cm, d)
+
+    def loss(fn):
+        def run(*ops):
+            out = fn(*ops)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        return jax.value_and_grad(run, argnums=tuple(range(6)), has_aux=True)
+
+    kernel, is_mosaic = _mosaic(
+        loss(lambda *o: ssd.mamba2_ssd(*o, impl="pallas")), *ops)
+    c.check("mamba2_ssd fwd+bwd lowered to tpu_custom_call", is_mosaic)
+    steps, chunks = sorted(_compat.ssd_blocks()), sorted(_compat.ssd_chunks())
+    c.check("mamba2_ssd's two kernels recorded their grid step (kernel, "
+            "heads, chunks) and the call its chunk",
+            {k for k, _, _ in steps} == {"mamba2_ssd_fwd", "mamba2_ssd_bwd"}
+            and bool(chunks), f"{steps} {chunks}")
+    (_, out), grads = kernel(*ops)
+    names = ("x", "dt", "A", "B", "C", "D")
+    for what, fn in (
+            ("chunked jax.numpy form",
+             lambda *o: ssd.mamba2_ssd(*o, impl="xla")),
+            ("recurrence token by token", ssd.recurrent_mamba2_ssd)):
+        (_, ref), ref_grads = jax.jit(loss(fn))(*ops)
+        err = _rel_err(out, ref)
+        c.check(f"mamba2_ssd forward == {what}", err <= tol,
+                f"rel err {err:.2e} (tol {tol})")
+        for name, got, want in zip(names, grads, ref_grads):
+            err = _rel_err(got, want)
+            c.check(f"mamba2_ssd d{name} == {what}", err <= tol,
+                    f"rel err {err:.2e} (tol {tol})")
+    c.done()
+
+
 # ----------------------------------------------------------------- train
 def _peak_gib(dev):
     return dev.memory_stats()["peak_bytes_in_use"] / 2**30
@@ -693,6 +752,7 @@ def main():
     device, cache_root = phase_device()
     phase_kernels()
     phase_hybrid()
+    phase_state_space()
     losses = phase_train()
     gc.collect()
     out = phase_serve(cache_root)

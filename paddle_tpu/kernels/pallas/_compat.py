@@ -34,6 +34,11 @@ same for the two gated-delta-rule kernels' grid step
 ``record_gdr_operands()`` for the form a ``gated_delta_rule`` call's q, k
 and v came in (``paddle_tpu_kernels_gdr_operands{form}``: ``flat`` is what
 the kernels read in place, ``heads`` costs a copy of each on a TPU).
+``record_ssd_chunk()`` and ``record_ssd_blocks()`` do it for the Mamba-2
+kernels: ``paddle_tpu_kernels_ssd_chunk{chunk,d_head,d_state,groups}`` once
+a traced ``mamba2_ssd`` kernel call, and ``paddle_tpu_kernels_ssd_blocks
+{kernel,heads,chunks}`` once a traced kernel (the heads of a grid step, the
+chunks a sequence is walked in).
 """
 from __future__ import annotations
 
@@ -210,3 +215,57 @@ def gdr_operands():
     """{form: traced calls} (test/diagnostic accessor)."""
     return {labels["form"]: child.value
             for labels, child in _gdr_operands_counter()._series()}
+
+
+def _ssd_chunk_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_ssd_chunk",
+        "Traced mamba2_ssd kernel calls by chunk, head and state sizes",
+        labelnames=("chunk", "d_head", "d_state", "groups"),
+    )
+
+
+def record_ssd_chunk(chunk, d_head, d_state, groups):
+    """One traced ``mamba2_ssd`` call through the kernels, with the chunk
+    its sequence is cut in."""
+    _ssd_chunk_counter().inc(
+        chunk=chunk, d_head=d_head, d_state=d_state, groups=groups)
+
+
+def ssd_chunks():
+    """{(chunk, d_head, d_state, groups): traced calls} (test/diagnostic
+    accessor)."""
+    return {
+        tuple(int(labels[n]) for n in ("chunk", "d_head", "d_state",
+                                       "groups")): child.value
+        for labels, child in _ssd_chunk_counter()._series()
+    }
+
+
+def _ssd_blocks_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_ssd_blocks",
+        "Traced mamba2_ssd kernels by the heads of a grid step and the "
+        "chunks a sequence is walked in",
+        labelnames=("kernel", "heads", "chunks"),
+    )
+
+
+def record_ssd_blocks(kernel, heads, chunks):
+    """One traced call of ``kernel`` whose grid step holds ``heads`` heads
+    of one chunk, over ``chunks`` chunks a sequence."""
+    _ssd_blocks_counter().inc(kernel=kernel, heads=heads, chunks=chunks)
+
+
+def ssd_blocks():
+    """{(kernel, heads, chunks): traced calls} (test/diagnostic
+    accessor)."""
+    return {
+        (labels["kernel"], int(labels["heads"]), int(labels["chunks"])):
+        child.value
+        for labels, child in _ssd_blocks_counter()._series()
+    }

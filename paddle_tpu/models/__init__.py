@@ -1,11 +1,13 @@
 """Flagship model definitions (Llama-family decoder for the BASELINE
 configs; vision models live in paddle_tpu.vision.models)."""
 from .dit import DiT, DiTConfig, dit_b_4, dit_xl_2
+from .granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel
 from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 
 __all__ = [
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
     "Qwen3NextConfig", "Qwen3NextForCausalLM",
+    "GraniteHybridConfig", "GraniteHybridForCausalLM",
     "DiT", "DiTConfig", "dit_xl_2", "dit_b_4",
 ]

@@ -25,6 +25,7 @@ from .impl import random as _impl_random
 from .impl import reduction as _impl_reduction
 from .impl import rnn_ops as _impl_rnn_ops
 from .impl import search as _impl_search
+from .impl import ssm_ops as _impl_ssm_ops
 
 _call = _dispatch.call
 
@@ -2309,6 +2310,11 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", num_k_heads=None, 
     return _call('gated_delta_rule', _impl_gdn_ops.gated_delta_rule, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl, 'num_k_heads': num_k_heads})
 
 
+def mamba2_ssd(x, dt, a, b, c, d, chunk=256, n_groups=1, impl="auto", name=None):
+    """mamba2_ssd (generated from ops.yaml)."""
+    return _call('mamba2_ssd', _impl_ssm_ops.mamba2_ssd, (x, dt, a, b, c, d,), {'chunk': chunk, 'n_groups': n_groups, 'impl': impl})
+
+
 def fused_linear_cross_entropy(x, weight, labels, chunk_size=4096, ignore_index=-100, name=None):
     """fused_linear_cross_entropy (generated from ops.yaml)."""
     return _call('fused_linear_cross_entropy', _impl_fused_ops.fused_linear_cross_entropy, (x, weight, labels,), {'chunk_size': chunk_size, 'ignore_index': ignore_index})
@@ -3073,6 +3079,7 @@ __all__ = [
     'lstsq',
     'lu',
     'lu_unpack',
+    'mamba2_ssd',
     'margin_ranking_loss',
     'masked_fill',
     'masked_fill_',
