@@ -568,6 +568,17 @@ class TrainStep:
     XLA sees the complete step, fuses across the fwd/bwd boundary, and
     writes parameter updates in place via donation.
 
+    One boundary it may NOT fuse across: each gradient crosses from the
+    backward pass to the optimizer through a
+    ``jax.lax.optimization_barrier`` of its own (the identity; one a leaf,
+    so no two gradients are held live together for it). Without it XLA
+    makes the optimizer's update of a weight the epilogue of the matmul
+    that produces the weight's gradient, ``dW = x^T dy``: every output
+    tile then reads both moments and the master weight in float32, and
+    the matmul runs at half the speed of the step's others: on a v5e the
+    barrier took a Granite-4.0-h training step from 1,129 to 990 ms and a
+    Mistral-7B step from 513 to 479 ms (PERF.md, PR 32).
+
         step = paddle.jit.TrainStep(model, loss_fn, optimizer)
         loss = step(x, y)      # loss_fn(model, x, y) -> scalar loss
 
@@ -673,8 +684,13 @@ class TrainStep:
                     p.stop_gradient = False
                 if k == 1:
                     loss_val, live_idx, net, new_key = fwd_bwd(key, tree_args)
-                    live_grads = [constrain(i, params[i].grad._data)
-                                  for i in live_idx]
+                    # the seam (class docstring): one barrier a leaf, not
+                    # one over the list, which would hold every gradient
+                    # live at once
+                    live_grads = [
+                        jax.lax.optimization_barrier(
+                            constrain(i, params[i].grad._data))
+                        for i in live_idx]
                     new_buffer_arrays = [b._data for b in buffers]
                     flags = net.flags_output
                 else:
