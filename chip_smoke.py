@@ -435,6 +435,90 @@ def phase_state_space():
     c.done()
 
 
+# ----------------------------------------------------- latent attention
+def phase_latent():
+    """What the DeepSeek-V3 block's training path brought: the MLA
+    kernels through Mosaic at the cell's widths (32 heads of 128 + 64
+    under values of 128, one shared rotary key), equal to plain attention
+    forward and backward, and the sigmoid router's held dispatch."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.moe import MoELayer
+    from paddle_tpu.kernels.pallas import _compat
+    from paddle_tpu.kernels.pallas import flash_attention as fa
+
+    c = Checks("latent")
+    bf16, tol = jnp.bfloat16, 2e-2
+    b, t, h = 2, 2048, 32
+    ks = jax.random.split(jax.random.key(3), 8)
+    shapes = ((b, t, h, 128), (b, t, h, 64), (b, t, h, 128), (b, t, 1, 64),
+              (b, t, h, 128))
+    ops = [jax.random.normal(r, s, bf16) for r, s in zip(ks, shapes)]
+    w = jax.random.normal(ks[5], shapes[-1], bf16)
+
+    def loss(fn):
+        def run(*ops):
+            out = fn(*ops, scale=192 ** -0.5, causal=True)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        return jax.value_and_grad(run, argnums=(0, 1, 2, 3, 4), has_aux=True)
+
+    kernel, is_mosaic = _mosaic(loss(
+        lambda *a, **kw: fa.mla_attention(*a, impl="pallas", **kw)), *ops)
+    c.check("mla_attention fwd+bwd lowered to tpu_custom_call", is_mosaic)
+    tiles = sorted(_compat.mla_blocks())
+    c.check("the three MLA kernels recorded their tile",
+            {k for k, _, _ in tiles} == set(fa.MLA_KERNELS), str(tiles))
+    (_, out), grads = kernel(*ops)
+    (_, ref), ref_grads = jax.jit(loss(fa.mla_attention_xla))(*ops)
+    err = _rel_err(out, ref)
+    c.check("mla_attention forward == plain attention", err <= tol,
+            f"rel err {err:.2e} (tol {tol})")
+    for name, got, want in zip(("q_nope", "q_rope", "k_nope",
+                                "k_rope (summed over the heads)", "v"),
+                               grads, ref_grads):
+        err = _rel_err(got, want)
+        c.check(f"mla_attention d {name} == plain attention", err <= tol,
+                f"rel err {err:.2e} (tol {tol})")
+
+    # an expert layer holding 16 of 128 behind the sigmoid router with a
+    # selection bias: kernel path against the XLA path, output and load
+    paddle.seed(0)
+    with paddle.nn.initializer.param_init_override(dtype="bfloat16"):
+        layer = MoELayer(2048, 128, d_ff=768, k=6, held=(16, 16),
+                         router_dtype="float32", scoring="sigmoid",
+                         routed_scaling_factor=2.448)
+    bias = 0.1 * jax.random.normal(ks[6], (128,))
+    layer.gate.e_score_correction_bias._rebind(bias)
+    x = paddle.to_tensor(jax.random.normal(ks[7], (4096, 2048), bf16))
+    rows = layer.held_rows(4096)
+
+    def routed(impl):
+        with paddle.no_grad():
+            tok, rw, load = paddle.ops.moe_held_dispatch(
+                x, paddle.ops.moe_router_logits(x, layer.gate.weight),
+                k=6, start=16, count=16, rows=rows, scoring="sigmoid",
+                bias=layer.gate.e_score_correction_bias, scale=2.448)
+            ex = layer.experts
+            out = paddle.ops.moe_held_experts(
+                x, ex.w_gate, ex.w_up, ex.w_down, tok, rw, load, rows=rows,
+                impl=impl)
+        return out._data, np.asarray(load._data), np.asarray(rw._data)
+
+    out, load, weights = routed("pallas")
+    ref, _, _ = routed("xla")
+    kept = int(load.sum())
+    c.check("sigmoid held dispatch: 16 of 128 held, about an eighth of "
+            "the assignments, weights of a token at most 2.448",
+            0.09 < kept / (4096 * 6) < 0.16 and weights[:kept].min() > 0
+            and weights.max() < 2.448, f"expert_load {load}")
+    err = _rel_err(out, ref)
+    c.check("sigmoid held experts forward == XLA path", err <= tol,
+            f"rel err {err:.2e} (tol {tol})")
+    c.done()
+
+
 # ----------------------------------------------------------------- train
 def _peak_gib(dev):
     return dev.memory_stats()["peak_bytes_in_use"] / 2**30
@@ -753,6 +837,7 @@ def main():
     phase_kernels()
     phase_hybrid()
     phase_state_space()
+    phase_latent()
     losses = phase_train()
     gc.collect()
     out = phase_serve(cache_root)

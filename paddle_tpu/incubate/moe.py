@@ -24,10 +24,13 @@ one expert-parallel rank's share of a layer whose router keeps all its
 outputs. It routes over every expert, keeps the assignments whose expert
 it holds, and computes their part of the result on the ragged path with
 no capacity and no drops; what the absent experts would add is left out
-(their rank adds it). ``shared_expert=`` is computed whole beside it
-behind a sigmoid gate, and ``router_dtype="float32"`` takes the router's
-product in float32. The int32 buffer ``expert_load`` holds the rows each
-held expert was sent in the last step.
+(their rank adds it). ``shared_expert=`` is computed whole beside it,
+behind a sigmoid gate or (``shared_gate=False``) added as it is, and
+``router_dtype="float32"`` takes the router's product in float32.
+``scoring="sigmoid"`` is DeepSeek-V3's router in place of softmax top-k:
+the float32 buffer ``gate.e_score_correction_bias`` chooses the experts
+and does not weigh them. The int32 buffer ``expert_load`` holds the rows
+each held expert was sent in the last step.
 """
 from __future__ import annotations
 
@@ -194,15 +197,24 @@ class MoELayer(Layer):
     def __init__(self, d_model, num_experts, d_ff=None, k=2,
                  capacity_factor=1.25, gate=None, experts=None,
                  impl="dense", held=None, shared_expert=None,
-                 router_dtype=None):
+                 router_dtype=None, scoring="softmax", norm_topk_prob=True,
+                 routed_scaling_factor=1.0, shared_gate=True,
+                 shared_expert_name="shared_expert"):
         """``held=(start, count)``: the layer holds ``count`` of the
         ``num_experts`` the router chooses among (its expert weights are
         ``[count, ...]``) and computes their part alone, on the ragged
         path. ``shared_expert``: a callable that makes a Layer ``[n, m] ->
         [n, m]``, added whole behind ``sigmoid(x @ shared_gate)``; it is
         called after the routed experts exist, so parameters() lists them
-        in that order. ``router_dtype``: the dtype the router's product is
-        taken in (None: the activations')."""
+        in that order; ``shared_gate=False`` adds it ungated, and
+        ``shared_expert_name`` is the attribute (so the parameters' names)
+        it is kept under. ``router_dtype``: the dtype the router's product
+        is taken in (None: the activations'). ``scoring`` (a held layer's):
+        ``"softmax"``, or ``"sigmoid"`` with the selection bias
+        ``gate.e_score_correction_bias`` (a float32 buffer [num_experts]
+        at 0, no gradient; its update rule is the trainer's), the chosen
+        scores normalised over the k if ``norm_topk_prob`` and multiplied
+        by ``routed_scaling_factor``."""
         super().__init__()
         if impl not in ("dense", "ragged"):
             raise ValueError(
@@ -224,6 +236,21 @@ class MoELayer(Layer):
                     "MoELayer(held=) routes with the stock TopKGate over "
                     "its own SwiGLUExperts")
             self.held, impl = (start, count), "ragged"
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f'MoELayer scoring must be "softmax" or "sigmoid", got '
+                f"{scoring!r}")
+        if scoring != "sigmoid" and (
+                not norm_topk_prob or routed_scaling_factor != 1.0):
+            raise ValueError(
+                "MoELayer: norm_topk_prob and routed_scaling_factor are "
+                'the sigmoid router\'s (scoring="sigmoid")')
+        if held is None and scoring != "softmax":
+            raise ValueError(
+                f"MoELayer: scoring={scoring!r} is the held path's (held=)")
+        self.scoring = scoring
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.routed_scaling_factor = float(routed_scaling_factor)
         self.router_dtype = router_dtype
         self.gate = gate or TopKGate(d_model, num_experts, k,
                                      capacity_factor)
@@ -231,11 +258,18 @@ class MoELayer(Layer):
             num_experts if held is None else self.held[1], d_model,
             d_ff or 4 * d_model
         )
-        self.shared_expert = shared_expert and shared_expert()
+        if scoring == "sigmoid":
+            self.gate.register_buffer(
+                "e_score_correction_bias",
+                F.zeros([num_experts], "float32"))
+        self._shared_name = shared_expert and shared_expert_name
+        self.shared_gate = None
         if shared_expert is not None:
-            from ..nn.layer.common import Linear
+            setattr(self, shared_expert_name, shared_expert())
+            if shared_gate:
+                from ..nn.layer.common import Linear
 
-            self.shared_gate = Linear(d_model, 1, bias_attr=False)
+                self.shared_gate = Linear(d_model, 1, bias_attr=False)
         if self.held is not None:
             self.register_buffer(
                 "expert_load", F.zeros([self.held[1]], "int32"))
@@ -288,6 +322,11 @@ class MoELayer(Layer):
             "Traced calls of an expert layer that holds a share",
             labelnames=("experts", "held", "k"),
         ).inc(experts=self.num_experts, held=count, k=k)
+        counter(
+            "paddle_tpu_moe_router",
+            "Traced calls of a held expert layer's router, by its scoring",
+            labelnames=("scoring", "experts", "k"),
+        ).inc(scoring=self.scoring, experts=self.num_experts, k=k)
         n = flat.shape[0]
         with scope("moe.router"):
             if self.router_dtype is None:
@@ -296,17 +335,28 @@ class MoELayer(Layer):
                 logits = F.moe_router_logits(
                     flat, self.gate.weight, dtype=self.router_dtype)
             rows = self.held_rows(n)
-            row_token, row_weight, load = F.moe_held_dispatch(
-                flat, logits, k=k, start=start, count=count, rows=rows)
+            if self.scoring == "softmax":
+                row_token, row_weight, load = F.moe_held_dispatch(
+                    flat, logits, k=k, start=start, count=count, rows=rows)
+            else:
+                row_token, row_weight, load = F.moe_held_dispatch(
+                    flat, logits, k=k, start=start, count=count, rows=rows,
+                    renormalize=self.norm_topk_prob, scoring=self.scoring,
+                    bias=self.gate.e_score_correction_bias,
+                    scale=self.routed_scaling_factor)
         with scope("moe.experts"):
             ex = self.experts
             out = F.moe_held_experts(
                 flat, ex.w_gate, ex.w_up, ex.w_down, row_token, row_weight,
                 load, rows=rows)
-        if self.shared_expert is not None:
+        if self._shared_name:
             with scope("moe.shared_expert"):
-                out = out + F.sigmoid(self.shared_gate(flat)) * (
-                    self.shared_expert(flat))
+                shared = getattr(self, self._shared_name)
+                if self.shared_gate is None:
+                    out = out + shared(flat)
+                else:
+                    out = out + F.sigmoid(self.shared_gate(flat)) * (
+                        shared(flat))
         return out, load
 
     def forward(self, x, return_stats=False):

@@ -28,8 +28,9 @@ A fourth, smaller one: ``record_flash_blocks()`` — the flash kernels
 choose their tile from the call's shapes at trace time, and each traced
 kernel call bumps ``paddle_tpu_kernels_flash_blocks{kernel,block_q,
 block_k}``, so a test, ``chip_smoke.py`` or a reader of the metrics
-registry can say which tile a shape got. ``record_gdr_blocks()`` does the
-same for the two gated-delta-rule kernels' grid step
+registry can say which tile a shape got. ``record_mla_blocks()`` is the
+same count for the latent-attention kernels (``paddle_tpu_kernels_mla_blocks{kernel,block_q,block_k}``).
+``record_gdr_blocks()`` does the same for the two gated-delta-rule kernels' grid step
 (``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``), and
 ``record_gdr_operands()`` for the form a ``gated_delta_rule`` call's q, k
 and v came in (``paddle_tpu_kernels_gdr_operands{form}``: ``flat`` is what
@@ -163,6 +164,32 @@ def flash_blocks():
         (labels["kernel"], int(labels["block_q"]), int(labels["block_k"])):
         child.value
         for labels, child in _flash_blocks_counter()._series()
+    }
+
+
+def _mla_blocks_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_mla_blocks",
+        "Traced latent-attention kernel calls by the tile they were given",
+        labelnames=("kernel", "block_q", "block_k"),
+    )
+
+
+def record_mla_blocks(kernel, block_q, block_k):
+    """One traced call of a latent-attention ``kernel`` with this tile."""
+    _mla_blocks_counter().inc(
+        kernel=kernel, block_q=block_q, block_k=block_k)
+
+
+def mla_blocks():
+    """{(kernel, block_q, block_k): traced calls} (test/diagnostic
+    accessor)."""
+    return {
+        (labels["kernel"], int(labels["block_q"]), int(labels["block_k"])):
+        child.value
+        for labels, child in _mla_blocks_counter()._series()
     }
 
 

@@ -37,6 +37,19 @@ block needs; the first, for `dk`/`dv`), so no DMA is issued for it
 either. Only a block the diagonal crosses builds the iota mask; blocks
 wholly below it run the unmasked body.
 
+Latent attention (MLA). The kernels take the score as a sum of products
+into one tile (``parts``): ``mla_attention`` gives them ``q_nope k_nope^T``
+over each head's own 128 columns and ``q_rope k_rope^T`` over 64 rotary
+columns whose key is ONE head read by all, under values of their own
+width. The shared key is an operand of its own, [batch, seq, 64], whose
+index map drops the head: it is never broadcast to the heads in HBM, and
+no head is padded to a common width. `dk`/`dv` then walks (batch, k block,
+head, q block): the shared key's block stays resident for all heads and
+its cotangent is summed over the head axis in VMEM (on a v5e at [2,
+8192, 32 heads] forward + backward take 56.2 ms so, 56.9 with a float32
+[batch * heads, seq, 64] output summed by XLA). The same three kernels,
+under names of their own (``MLA_KERNELS``).
+
 On non-TPU backends the kernels run in interpreter mode so the numerics
 are testable on the 8-device CPU mesh (conftest).
 """
@@ -50,13 +63,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from ._compat import current_spmd_axes, pl_call, record_flash_blocks
+from ._compat import (current_spmd_axes, pl_call, record_flash_blocks,
+                      record_mla_blocks)
 
 NEG_INF = -1e30
 
 KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
            "flash_attention_bwd_dkv")
 FWD, BWD_DQ, BWD_DKV = KERNELS
+MLA_KERNELS = ("mla_attention_fwd", "mla_attention_bwd_dq",
+               "mla_attention_bwd_dkv")
 
 # ---------------------------------------------------------------- tiling
 BLOCK_CANDIDATES = tuple(range(1024, 0, -128))
@@ -118,11 +134,11 @@ def choose_blocks(sq, sk, head_dim, dtype, kernel):
     return max(fits, key=lambda b: (b[0] * b[1], b[wider]))
 
 
-def _compiler_params(kernel, block_q, block_k, head_dim, dtype):
+def _compiler_params(kernel, block_q, block_k, head_dim, dtype, sweeps=1):
     need = _vmem_bytes(kernel, block_q, block_k, head_dim,
                        jnp.dtype(dtype).itemsize)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel") + sweeps * ("arbitrary",),
         vmem_limit_bytes=(
             VMEM_LIMIT_BYTES if need > _DEFAULT_SCOPE_BYTES // 2 else None),
     )
@@ -175,23 +191,50 @@ def _column(row_ref):
     return jnp.broadcast_to(col, (col.shape[0], 128))
 
 
+
+
+def _score(pairs):
+    """The score tile: the sum over the parts' (a, b) of a @ b^T,
+    float32."""
+    s = None
+    for a, b in pairs:
+        part = _nt(a, b)
+        s = part if s is None else s + part
+    return s
+
+
+def _split(refs, *counts):
+    """``refs`` cut into consecutive groups of ``counts`` refs."""
+    out, at = [], 0
+    for n in counts:
+        out.append(refs[at:at + n])
+        at += n
+    assert at == len(refs)
+    return out
+
+
 # ---------------------------------------------------------------- forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
-                acc_scr, *, scale, causal, block_q, block_k):
+def _fwd_kernel(*refs, parts, scale, causal, block_q, block_k):
+    """refs: ``parts`` q and ``parts`` k operands (the score is the sum
+    of their products), v; o, lse; scratch."""
+    q_refs, k_refs, (v_ref, o_ref, lse_ref), qs_scrs, (
+        m_scr, l_scr, acc_scr) = _split(refs, parts, parts, 3, parts, 3)
     kb = pl.program_id(2)
     qb = pl.program_id(1)
     nk = pl.num_programs(2)
 
     @pl.when(kb == 0)
     def _init():
-        qs_scr[:] = _scaled(q_ref[0], scale)
+        for qs_scr, q_ref in zip(qs_scrs, q_refs):
+            qs_scr[:] = _scaled(q_ref[0], scale)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _visit(masked):
         v = v_ref[0]
-        s = _nt(qs_scr[:], k_ref[0])  # [bq, bk]
+        s = _score((qs_scr[:], k_ref[0])             # [bq, bk]
+                   for qs_scr, k_ref in zip(qs_scrs, k_refs))
         if masked:
             s = jnp.where(
                 _causal_mask(qb * block_q, kb * block_k, s.shape, 0),
@@ -235,62 +278,89 @@ def _kv_index(causal, block_q, block_k, nk):
     return lambda b, i, j: (b, jnp.minimum(j, last(i)), 0)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+def _k_specs(ks, block_k, index, heads):
+    """A [block_k, d] block of every key part at ``index``; with
+    ``heads``, the last part is one head shared by that many, [batch,
+    seq, d], and its index drops the head."""
+    specs = [pl.BlockSpec((1, block_k, k.shape[-1]), index) for k in ks]
+    if heads:
+        specs[-1] = pl.BlockSpec(
+            (1, block_k, ks[-1].shape[-1]),
+            lambda b, *ij: (b // heads,) + tuple(index(b, *ij)[1:]))
+    return specs
+
+
+def _record(name, block_q, block_k):
+    record = record_mla_blocks if name in MLA_KERNELS else record_flash_blocks
+    record(name, block_q, block_k)
+
+
+def _flash_fwd(qs, ks, v, scale, causal, block_q, block_k, name=FWD,
+               heads=0):
+    """qs, ks: the score's parts, tuples of [bh, s, d_part] (``heads``:
+    the last key part is [b, s, d_part], shared by that many heads)."""
+    bh, sq, _ = qs[0].shape
+    sk, dv = ks[0].shape[1], v.shape[-1]
+    d = sum(q.shape[-1] for q in qs)
     nq, nk = sq // block_q, sk // block_k
-    record_flash_blocks(FWD, block_q, block_k)
-    kv = pl.BlockSpec((1, block_k, d), _kv_index(causal, block_q, block_k, nk))
+    _record(name, block_q, block_k)
+    at_q = lambda b, i, j: (b, i, 0)
+    at_k = _kv_index(causal, block_q, block_k, nk)
 
     out, lse = pl_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal,
+            _fwd_kernel, parts=len(qs), scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         ),
-        name=FWD,
+        name=name,
         grid=(bh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            kv, kv,
+            *(pl.BlockSpec((1, block_q, q.shape[-1]), at_q) for q in qs),
+            *_k_specs(ks, block_k, at_k, heads),
+            pl.BlockSpec((1, block_k, dv), at_k),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), at_q),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(v.shape[:1] + (sq, dv), v.dtype),
             jax.ShapeDtypeStruct((bh, 8, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), q.dtype),
+            *(pltpu.VMEM((block_q, q.shape[-1]), q.dtype) for q in qs),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=_compiler_params(FWD, block_q, block_k, d, q.dtype),
-    )(q, k, v)
+        compiler_params=_compiler_params(FWD, block_q, block_k, d,
+                                         v.dtype),
+    )(*qs, *ks, v)
     return out, lse
 
 
 # --------------------------------------------------------------- backward
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   qs_scr, lse_scr, delta_scr, acc_scr, *, scale, causal,
-                   block_q, block_k):
+def _bwd_dq_kernel(*refs, parts, scale, causal, block_q, block_k):
+    q_refs, k_refs, (v_ref, do_ref, lse_ref, delta_ref), dq_refs, qs_scrs, (
+        lse_scr, delta_scr), acc_scrs = _split(
+            refs, parts, parts, 4, parts, parts, 2, parts)
     kb = pl.program_id(2)
     qb = pl.program_id(1)
     nk = pl.num_programs(2)
 
     @pl.when(kb == 0)
     def _init():
-        qs_scr[:] = _scaled(q_ref[0], scale)
+        for qs_scr, q_ref in zip(qs_scrs, q_refs):
+            qs_scr[:] = _scaled(q_ref[0], scale)
         # the rows' statistics, turned into columns once a sweep
         lse_scr[:] = _column(lse_ref)
         delta_scr[:] = _column(delta_ref)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        for acc_scr in acc_scrs:
+            acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _visit(masked):
-        k = k_ref[0]
-        s = _nt(qs_scr[:], k)
+        ks = [k_ref[0] for k_ref in k_refs]
+        s = _score((qs_scr[:], k) for qs_scr, k in zip(qs_scrs, ks))
         if masked:
             s = jnp.where(
                 _causal_mask(qb * block_q, kb * block_k, s.shape, 0),
@@ -298,35 +368,56 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         p = jnp.exp(s - lse_scr[:, :1])
         dp = _nt(do_ref[0], v_ref[0])
         ds = p * (dp - delta_scr[:, :1])  # scale: at the sweep's end
-        acc_scr[:] += _nn(ds.astype(k.dtype), k)
+        for acc_scr, k in zip(acc_scrs, ks):
+            acc_scr[:] += _nn(ds.astype(k.dtype), k)
 
     _visit_block(_visit, causal, qb, kb, block_q, block_k)
 
     @pl.when(kb == nk - 1)
     def _fin():
-        dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
+        for dq_ref, acc_scr in zip(dq_refs, acc_scrs):
+            dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, ks_scr, dk_scr, dv_scr, *, scale,
-                    causal, block_q, block_k):
+def _bwd_dkv_kernel(*refs, parts, heads, scale, causal, block_q, block_k):
     """The tiles are held transposed, [bk, bq]: the rows' statistics then
     broadcast along sublanes as the [1, bq] rows they are stored as, and
-    both accumulating matmuls are plain [bk, bq] x [bq, d]."""
-    qb = pl.program_id(2)
+    both accumulating matmuls are plain [bk, bq] x [bq, d].
+
+    Grid (b * h, k block, q block). With ``heads``: (b, k block, head, q
+    block), and the last key part is one head shared by that many: its
+    block, scaled copy and accumulator live through all the heads' sweeps,
+    so its cotangent leaves summed over them."""
+    q_refs, k_refs, (v_ref, do_ref, lse_ref, delta_ref), dk_refs, (
+        dv_ref,), ks_scrs, dk_scrs, (dv_scr,) = _split(
+            refs, parts, parts, 4, parts, 1, parts, parts, 1)
+    sweep = 3 if heads else 2
+    qb = pl.program_id(sweep)
     kb = pl.program_id(1)
-    nq = pl.num_programs(2)
+    nq = pl.num_programs(sweep)
+    own = parts - 1 if heads else parts     # parts of this head alone
 
     @pl.when(qb == 0)
     def _init():
-        ks_scr[:] = _scaled(k_ref[0], scale)
-        dk_scr[:] = jnp.zeros_like(dk_scr)
+        for ks_scr, k_ref in zip(ks_scrs[:own], k_refs):
+            ks_scr[:] = _scaled(k_ref[0], scale)
+        for dk_scr in dk_scrs[:own]:
+            dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
+    if heads:
+        head = pl.program_id(2)
+
+        @pl.when(jnp.logical_and(qb == 0, head == 0))
+        def _init_shared():
+            ks_scrs[-1][:] = _scaled(k_refs[-1][0], scale)
+            dk_scrs[-1][:] = jnp.zeros_like(dk_scrs[-1])
+
     def _visit(masked):
-        q = q_ref[0]
+        qs = [q_ref[0] for q_ref in q_refs]
         do = do_ref[0]
-        st = _nt(ks_scr[:], q)  # [bk, bq]
+        st = _score((ks_scr[:], q)                    # [bk, bq]
+                    for ks_scr, q in zip(ks_scrs, qs))
         if masked:
             st = jnp.where(
                 _causal_mask(qb * block_q, kb * block_k, st.shape, 1),
@@ -335,54 +426,72 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] += _nn(pt.astype(do.dtype), do)
         dpt = _nt(v_ref[0], do)
         dst = pt * (dpt - delta_ref[0, :1, :])  # scale: at the sweep's end
-        dk_scr[:] += _nn(dst.astype(q.dtype), q)
+        for dk_scr, q in zip(dk_scrs, qs):
+            dk_scr[:] += _nn(dst.astype(q.dtype), q)
 
     _visit_block(_visit, causal, qb, kb, block_q, block_k)
 
     @pl.when(qb == nq - 1)
     def _fin():
-        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        for dk_ref, dk_scr in zip(dk_refs[:own], dk_scrs):
+            dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
+    if heads:
+        @pl.when(jnp.logical_and(qb == nq - 1, head == heads - 1))
+        def _fin_shared():
+            dk_refs[-1][0] = (dk_scrs[-1][:] * scale).astype(
+                dk_refs[-1].dtype)
 
-def _flash_bwd(q, k, v, out, lse, do, scale, causal, dq_blocks, dkv_blocks):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+
+def _flash_bwd(qs, ks, v, out, lse, do, scale, causal, dq_blocks,
+               dkv_blocks, names=KERNELS, heads=0):
+    """-> (dqs, dks, dv), the parts' cotangents as tuples."""
+    bh, sq, _ = qs[0].shape
+    sk, dv = ks[0].shape[1], v.shape[-1]
+    d = sum(q.shape[-1] for q in qs)
+    parts = len(qs)
     delta_row = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )  # [bh, sq]
     # sublane-replicated like lse (TPU block tiling rule)
     delta = jnp.broadcast_to(delta_row[:, None, :], (bh, 8, sq))
 
+    def widths(block, arrays, index):
+        return [pl.BlockSpec((1, block, a.shape[-1]), index) for a in arrays]
+
     block_q, block_k = dq_blocks
     nq, nk = sq // block_q, sk // block_k
-    record_flash_blocks(BWD_DQ, block_q, block_k)
-    qd = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv = pl.BlockSpec((1, block_k, d), _kv_index(causal, block_q, block_k, nk))
+    _record(names[1], block_q, block_k)
+    at_q = lambda b, i, j: (b, i, 0)
+    at_k = _kv_index(causal, block_q, block_k, nk)
     row = pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i))
-    dq = pl_call(
+    dqs = pl_call(
         functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
+            _bwd_dq_kernel, parts=parts, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         ),
-        name=BWD_DQ,
+        name=names[1],
         grid=(bh, nq, nk),
-        in_specs=[qd, kv, kv, qd, row, row],
-        out_specs=qd,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[*widths(block_q, qs, at_q),
+                  *_k_specs(ks, block_k, at_k, heads),
+                  *widths(block_k, (v,), at_k),
+                  *widths(block_q, (do,), at_q), row, row],
+        out_specs=widths(block_q, qs, at_q),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), q.dtype),
+            *(pltpu.VMEM((block_q, q.shape[-1]), q.dtype) for q in qs),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            *(pltpu.VMEM((block_q, q.shape[-1]), jnp.float32) for q in qs),
         ],
         compiler_params=_compiler_params(BWD_DQ, block_q, block_k, d,
-                                         q.dtype),
-    )(q, k, v, do, lse, delta)
+                                         v.dtype),
+    )(*qs, *ks, v, do, lse, delta)
 
     block_q, block_k = dkv_blocks
     nq, nk = sq // block_q, sk // block_k
-    record_flash_blocks(BWD_DKV, block_q, block_k)
+    _record(names[2], block_q, block_k)
     if causal:
         # a step above the diagonal names the first q block that sees any
         # of this k block: fetched once, before the sweep reaches it
@@ -392,48 +501,66 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, dq_blocks, dkv_blocks):
     else:
         def q_of(j, i):
             return i
-    qd = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, q_of(j, i), 0))
-    kv = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    row = pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, q_of(j, i)))
-    dk, dv = pl_call(
+    if heads:
+        # (batch, k block, head, q block): the head's own operands at
+        # batch * heads + head, the shared key's at batch
+        grid = (bh // heads, nk, heads, nq)
+        at_q = lambda b, j, h, i: (b * heads + h, q_of(j, i), 0)
+        at_k = lambda b, j, h, i: (b * heads + h, j, 0)
+        at_row = lambda b, j, h, i: (b * heads + h, 0, q_of(j, i))
+    else:
+        grid = (bh, nk, nq)
+        at_q = lambda b, j, i: (b, q_of(j, i), 0)
+        at_k = lambda b, j, i: (b, j, 0)
+        at_row = lambda b, j, i: (b, 0, q_of(j, i))
+    k_specs = widths(block_k, ks, at_k)
+    if heads:
+        k_specs[-1] = pl.BlockSpec((1, block_k, ks[-1].shape[-1]),
+                                   lambda b, j, h, i: (b, j, 0))
+    row = pl.BlockSpec((1, 8, block_q), at_row)
+    *dks, dv_out = pl_call(
         functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
+            _bwd_dkv_kernel, parts=parts, heads=heads, scale=scale,
+            causal=causal, block_q=block_q, block_k=block_k,
         ),
-        name=BWD_DKV,
-        grid=(bh, nk, nq),
-        in_specs=[qd, kv, kv, qd, row, row],
-        out_specs=[kv, kv],
+        name=names[2],
+        grid=grid,
+        in_specs=[*widths(block_q, qs, at_q), *k_specs,
+                  *widths(block_k, (v,), at_k),
+                  *widths(block_q, (do,), at_q), row, row],
+        out_specs=[*k_specs, *widths(block_k, (v,), at_k)],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            *(jax.ShapeDtypeStruct(k.shape, k.dtype) for k in ks),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), k.dtype),
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            *(pltpu.VMEM((block_k, k.shape[-1]), k.dtype) for k in ks),
+            *(pltpu.VMEM((block_k, k.shape[-1]), jnp.float32) for k in ks),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(BWD_DKV, block_q, block_k, d,
-                                         q.dtype),
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+                                         v.dtype, sweeps=len(grid) - 2),
+    )(*qs, *ks, v, do, lse, delta)
+    return tuple(dqs), tuple(dks), dv_out
 
 
 # ------------------------------------------------------------- public op
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_core(q, k, v, scale, causal, blocks):
-    out, _ = _flash_fwd(q, k, v, scale, causal, *blocks[0])
+    out, _ = _flash_fwd((q,), (k,), v, scale, causal, *blocks[0])
     return out
 
 
 def _flash_core_fwd(q, k, v, scale, causal, blocks):
-    out, lse = _flash_fwd(q, k, v, scale, causal, *blocks[0])
+    out, lse = _flash_fwd((q,), (k,), v, scale, causal, *blocks[0])
     return out, (q, k, v, out, lse)
 
 
 def _flash_core_bwd(scale, causal, blocks, res, do):
     q, k, v, out, lse = res
-    return _flash_bwd(q, k, v, out, lse, do, scale, causal, *blocks[1:])
+    (dq,), (dk,), dv = _flash_bwd(
+        (q,), (k,), v, out, lse, do, scale, causal, *blocks[1:])
+    return dq, dk, dv
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -448,6 +575,26 @@ def _flash_4d(q, k, v, scale, causal, blocks):
 
     out = _flash_core(_merge(q), _merge(k), _merge(v), scale, causal, blocks)
     return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2)
+
+
+def _blocks_for(what, sq, sk, d, dtype, block_q, block_k):
+    """(block_q, block_k) of the three kernels: ``choose_blocks``', or
+    the caller's where given. The kernels have no padding mask for a
+    partial tail block (out-of-range rows and columns would silently
+    attend to block padding), so a length no block divides is refused."""
+    blocks = []
+    for kernel in KERNELS:
+        bq, bk = choose_blocks(sq, sk, d, dtype, kernel)
+        bq = bq if block_q is None else min(int(block_q), sq)
+        bk = bk if block_k is None else min(int(block_k), sk)
+        if sq % bq or sk % bk:
+            raise ValueError(
+                f"{what} requires seq lengths divisible by the "
+                f"block sizes: got sq={sq}, sk={sk} with block_q={bq}, "
+                f"block_k={bk}; pad the sequence or use the math sdpa"
+            )
+        blocks.append((bq, bk))
+    return tuple(blocks)
 
 
 def flash_attention(q, k, v, *, causal=True, scale=None,
@@ -466,25 +613,12 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
     rule, and attention is independent per batch row and per head."""
     sq, d = q.shape[1], q.shape[3]
     sk = k.shape[1]
-    blocks = []
-    for kernel in KERNELS:
-        bq, bk = choose_blocks(sq, sk, d, q.dtype, kernel)
-        bq = bq if block_q is None else min(int(block_q), sq)
-        bk = bk if block_k is None else min(int(block_k), sk)
-        # The kernel has no padding mask for partial tail blocks;
-        # out-of-range rows/cols would silently attend to block padding.
-        if sq % bq or sk % bk:
-            raise ValueError(
-                f"flash_attention requires seq lengths divisible by the "
-                f"block sizes: got sq={sq}, sk={sk} with block_q={bq}, "
-                f"block_k={bk}; pad the sequence or use the math sdpa"
-            )
-        blocks.append((bq, bk))
+    blocks = _blocks_for("flash_attention", sq, sk, d, q.dtype, block_q,
+                         block_k)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     fn = functools.partial(
-        _flash_4d, scale=float(scale), causal=bool(causal),
-        blocks=tuple(blocks),
+        _flash_4d, scale=float(scale), causal=bool(causal), blocks=blocks,
     )
     axes = current_spmd_axes()
     if axes is not None:
@@ -501,3 +635,96 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
             check_vma=False,
         )
     return fn(q, k, v)
+
+
+# ------------------------------------------------------- latent attention
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _mla_core(qn, qr, kn, kr, v, heads, scale, causal, blocks):
+    out, _ = _flash_fwd((qn, qr), (kn, kr), v, scale, causal, *blocks[0],
+                        name=MLA_KERNELS[0], heads=heads)
+    return out
+
+
+def _mla_core_fwd(qn, qr, kn, kr, v, heads, scale, causal, blocks):
+    out, lse = _flash_fwd((qn, qr), (kn, kr), v, scale, causal, *blocks[0],
+                          name=MLA_KERNELS[0], heads=heads)
+    return out, (qn, qr, kn, kr, v, out, lse)
+
+
+def _mla_core_bwd(heads, scale, causal, blocks, res, do):
+    qn, qr, kn, kr, v, out, lse = res
+    dqs, dks, dv = _flash_bwd(
+        (qn, qr), (kn, kr), v, out, lse, do, scale, causal, *blocks[1:],
+        names=MLA_KERNELS, heads=heads)
+    return (*dqs, *dks, dv)
+
+
+_mla_core.defvjp(_mla_core_fwd, _mla_core_bwd)
+
+
+def mla_attention_xla(q_nope, q_rope, k_nope, k_rope, v, *, scale,
+                      causal=True):
+    """``mla_attention`` in plain ``jax.numpy``, float32: the path off
+    the TPU, and what the kernels are tested against."""
+    f32 = jnp.float32
+    s = scale * (
+        jnp.einsum("bqhd,bkhd->bhqk", q_nope.astype(f32), k_nope.astype(f32))
+        + jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(f32),
+                     k_rope[:, :, 0].astype(f32)))
+    if causal:
+        sq, sk = s.shape[-2:]
+        s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool)), s, -jnp.inf)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                     v.astype(f32))
+    return out.astype(v.dtype)
+
+
+def mla_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale, causal=True,
+                  block_q=None, block_k=None, impl="auto"):
+    """Attention whose score is ``(q_nope . k_nope + q_rope . k_rope) *
+    scale`` with the rotary key one head shared by all: q_nope, k_nope
+    [batch, seq, heads, d_nope], q_rope [batch, seq, heads, d_rope],
+    k_rope [batch, seq, 1, d_rope], v [batch, seq, heads, d_v] -> [batch,
+    seq, heads, d_v]. Differentiable in all five; k_rope's cotangent is
+    the sum over the heads.
+
+    impl: ``"auto"`` is the kernels on a TPU (FLAGS_use_pallas_kernels)
+    at lengths 128 divides and ``mla_attention_xla`` elsewhere;
+    ``"pallas"`` is always the kernels (the interpreter off the TPU);
+    ``"xla"`` always the ``jax.numpy`` form. Tiles as ``flash_attention``'s,
+    chosen at the score's whole width."""
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(
+            f'mla_attention impl must be "auto", "pallas" or "xla", got '
+            f"{impl!r}")
+    b, sq, heads, _ = q_nope.shape
+    sk = k_nope.shape[1]
+    if k_rope.shape != (b, sk, 1, q_rope.shape[-1]):
+        raise ValueError(
+            f"mla_attention: k_rope {k_rope.shape} is not one head "
+            f"[{b}, {sk}, 1, {q_rope.shape[-1]}] for q_rope {q_rope.shape}")
+    if impl == "auto":
+        from ...core import device, flags
+
+        impl = "pallas" if (
+            device.on_tpu() and flags.get_flag("FLAGS_use_pallas_kernels")
+            and sq % 128 == 0 and sk % 128 == 0
+        ) else "xla"
+    if impl == "xla":
+        return mla_attention_xla(q_nope, q_rope, k_nope, k_rope, v,
+                                 scale=scale, causal=causal)
+    if current_spmd_axes() is not None:
+        raise NotImplementedError(
+            "mla_attention: the kernels have no shard_map wrapper yet; "
+            "a sharded program takes impl='xla'")
+    width = q_nope.shape[-1] + q_rope.shape[-1]
+    blocks = _blocks_for("mla_attention", sq, sk, width, v.dtype, block_q,
+                         block_k)
+
+    def merge(x):           # [b, s, h, d] -> the kernels' [b*h, s, d]
+        return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], x.shape[3])
+
+    out = _mla_core(merge(q_nope), merge(q_rope), merge(k_nope),
+                    k_rope[:, :, 0], merge(v), heads, float(scale),
+                    bool(causal), blocks)
+    return jnp.swapaxes(out.reshape(b, heads, sq, v.shape[-1]), 1, 2)

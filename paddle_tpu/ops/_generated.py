@@ -2285,9 +2285,9 @@ def moe_router_logits(x, weight, dtype="float32", name=None):
     return _call('moe_router_logits', _impl_moe_ops.moe_router_logits, (x, weight,), {'dtype': dtype})
 
 
-def moe_held_dispatch(x, gate_logits, k, start, count, rows, renormalize=True, name=None):
+def moe_held_dispatch(x, gate_logits, k, start, count, rows, renormalize=True, scoring="softmax", bias=None, scale=1.0, name=None):
     """moe_held_dispatch (generated from ops.yaml)."""
-    return _call('moe_held_dispatch', _impl_moe_ops.moe_held_dispatch, (x, gate_logits,), {'k': k, 'start': start, 'count': count, 'rows': rows, 'renormalize': renormalize})
+    return _call('moe_held_dispatch', _impl_moe_ops.moe_held_dispatch, (x, gate_logits, bias,), {'k': k, 'start': start, 'count': count, 'rows': rows, 'renormalize': renormalize, 'scoring': scoring, 'scale': scale})
 
 
 def moe_held_experts(x, w_gate, w_up, w_down, row_token, row_weight, expert_load, rows, impl="auto", name=None):
@@ -2303,6 +2303,11 @@ def zero_centered_rms_norm(x, weight, epsilon=1e-6, name=None):
 def partial_rope_qk(q, k, rotary_dim, base=10000.0, name=None):
     """partial_rope_qk (generated from ops.yaml)."""
     return _call('partial_rope_qk', _impl_gdn_ops.partial_rope_qk, (q, k,), {'rotary_dim': rotary_dim, 'base': base})
+
+
+def mla_attention(q_nope, q_rope, k_nope, k_rope, v, scale, causal=True, impl="auto", name=None):
+    """mla_attention (generated from ops.yaml)."""
+    return _call('mla_attention', _impl_nn_ops.mla_attention, (q_nope, q_rope, k_nope, k_rope, v,), {'scale': scale, 'causal': causal, 'impl': impl})
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", num_k_heads=None, name=None):
@@ -3104,6 +3109,7 @@ __all__ = [
     'min',
     'minimum',
     'mish',
+    'mla_attention',
     'mode',
     'moe_combine',
     'moe_gate_dispatch',

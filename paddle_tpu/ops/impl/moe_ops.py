@@ -182,17 +182,23 @@ def moe_router_logits(x, weight, *, dtype="float32"):
         precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None)
 
 
-def moe_held_dispatch(x, gate_logits, *, k, start, count, rows,
-                      renormalize=True):
+def moe_held_dispatch(x, gate_logits, bias=None, *, k, start, count, rows,
+                      renormalize=True, scoring="softmax", scale=1.0):
     """Routing for an expert layer that holds experts ``[start, start +
     count)`` of the ``e`` the router chooses among (one expert-parallel
     rank's share; the whole layer when ``count == e``).
 
     Routing is over all ``e`` experts in float32: softmax, top-k, the
-    weights normalised over all k chosen (``norm_topk_prob``). The
-    assignments whose expert is held here are kept, every one of them,
-    and sorted by expert: the first ``sum(expert_load)`` entries of the
-    returned lists, each expert's one contiguous segment.
+    weights normalised over all k chosen (``norm_topk_prob``). With
+    ``scoring="sigmoid"`` (DeepSeek-V3's router, one group) the scores
+    are ``sigmoid(gate_logits)``, the k experts are chosen by ``scores +
+    bias`` (``e_score_correction_bias`` [e], float32, no gradient: it
+    chooses and does not weigh), and the weights are the chosen scores,
+    normalised over the k (+ 1e-20) when ``renormalize``, times ``scale``
+    (``routed_scaling_factor``). The assignments whose expert is held
+    here are kept, every one of them, and sorted by expert: the first
+    ``sum(expert_load)`` entries of the returned lists, each expert's one
+    contiguous segment.
 
     x: [s, m] (only its length is read); gate_logits: [s, e]. Returns
     (row_token [n] int32: the token of each sorted assignment, row_weight
@@ -200,10 +206,24 @@ def moe_held_dispatch(x, gate_logits, *, k, start, count, rows,
     ``n`` = s * k rounded up to whole passes of ``rows``; expert_load
     [count] int32: the rows each held expert was sent)."""
     s = x.shape[0]
-    gates = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    vals, idx = jax.lax.top_k(gates, k)               # [s, k]
-    if renormalize:
-        vals = vals / vals.sum(-1, keepdims=True)
+    if scoring == "softmax":
+        gates = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+        vals, idx = jax.lax.top_k(gates, k)           # [s, k]
+        if renormalize:
+            vals = vals / vals.sum(-1, keepdims=True)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+        choose = scores if bias is None else scores + jax.lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, idx = jax.lax.top_k(choose, k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+        if renormalize:
+            vals = vals / (vals.sum(-1, keepdims=True) + 1e-20)
+        vals = vals * scale
+    else:
+        raise ValueError(
+            f'moe_held_dispatch scoring must be "softmax" or "sigmoid", '
+            f"got {scoring!r}")
     local = idx.reshape(-1).astype(jnp.int32) - start
     # not held: the key `count`, which sorts behind every held expert
     key = jnp.where((local >= 0) & (local < count), local, count)

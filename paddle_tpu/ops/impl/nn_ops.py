@@ -897,6 +897,19 @@ def scaled_dot_product_attention(
     return jnp.swapaxes(out, 1, 2).astype(query.dtype)
 
 
+def mla_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale, causal=True,
+                  impl="auto"):
+    """Latent attention's core: the score is ``(q_nope . k_nope + q_rope .
+    k_rope) * scale`` with the rotary key [b, s, 1, d_rope] one head read
+    by all, the values of their own width. The public op face of
+    ``kernels.pallas.flash_attention.mla_attention`` (its kernels on a
+    TPU, its ``jax.numpy`` form elsewhere)."""
+    from ...kernels.pallas.flash_attention import mla_attention as _mla
+
+    return _mla(q_nope, q_rope, k_nope, k_rope, v, scale=scale,
+                causal=causal, impl=impl)
+
+
 def batch_norm(x, running_mean, running_var, weight=None, bias=None, *,
                training=False, momentum=0.9, epsilon=1e-5,
                data_format="NCHW", use_global_stats=False):
