@@ -19,6 +19,11 @@ parameters; weights random from a seed):
            runs it ([2, 8192, 4096], 64 heads of 64, state 128) against its
            jax.numpy chunked form and against the recurrence token by
            token: the output and the gradients of all six operands.
+  latent   mla_attention (fwd+bwd kernels) at 32 heads of 128 + 64 against
+           plain attention, the sigmoid router's held dispatch, and one MLA
+           block under distributed.recompute: the segment keeps the forward
+           kernel's output and log-sum-exp, so its value and gradient
+           compile to one mla_attention_fwd call.
   train    AdamW(multi_precision) + jit.TrainStep (donation on) fed by the
            forked-worker DataLoader, seq 2048: loss finite and falling,
            traced and compiled once, parameters on the TPU.
@@ -47,6 +52,7 @@ import gc
 import http.client
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -440,7 +446,8 @@ def phase_latent():
     """What the DeepSeek-V3 block's training path brought: the MLA
     kernels through Mosaic at the cell's widths (32 heads of 128 + 64
     under values of 128, one shared rotary key), equal to plain attention
-    forward and backward, and the sigmoid router's held dispatch."""
+    forward and backward, the sigmoid router's held dispatch, and what a
+    recomputed MLA block keeps (`_compat.recompute_kept()`)."""
     import jax
     import jax.numpy as jnp
 
@@ -516,6 +523,55 @@ def phase_latent():
     err = _rel_err(out, ref)
     c.check("sigmoid held experts forward == XLA path", err <= tol,
             f"rel err {err:.2e} (tol {tol})")
+
+    # one MLA block at the published widths under `recompute`: the segment
+    # keeps the forward kernel's output and log-sum-exp, so value and
+    # gradient compile to ONE forward kernel call (two with nothing kept)
+    from paddle_tpu.distributed import recompute
+    from paddle_tpu.jit.api import _rng_lift
+    from paddle_tpu.models import DeepseekV3Config
+    from paddle_tpu.models.deepseek_v3 import DeepseekV3Attention
+
+    paddle.seed(0)
+    with paddle.nn.initializer.param_init_override(dtype="bfloat16"):
+        block = DeepseekV3Attention(DeepseekV3Config())
+    params = list(block.parameters())
+    hidden = jax.random.normal(ks[7], (b, t, 2048), bf16)
+
+    def block_loss(rc):
+        def run(arrays, x):
+            old = [p._data for p in params]
+            for p, a in zip(params, arrays):
+                p._data = a
+            try:
+                # the segment's key is drawn from a key of this trace's
+                with paddle.no_grad(), _rng_lift(jax.random.key(0)):
+                    x = paddle.to_tensor(x)
+                    out = recompute(block, x) if rc else block(x)
+                return jnp.sum(out._data.astype(jnp.float32))
+            finally:
+                for p, a in zip(params, old):
+                    p._data = a
+        return jax.jit(jax.value_and_grad(run, argnums=(0, 1)))
+
+    arrays = [p._data for p in params]
+    before = _compat.recompute_kept().get("mla_attention", 0)
+    compiled = block_loss(True).lower(arrays, hidden).compile()
+    kept = _compat.recompute_kept().get("mla_attention", 0) - before
+    forwards = len(re.findall(
+        r"^\s*(?:ROOT )?%\w*?mla_attention_fwd[_.\d]* = .*custom-call\(",
+        compiled.as_text(), re.M))
+    c.check("a recomputed MLA block keeps the forward kernel's output and "
+            "log-sum-exp: one mla_attention_fwd call in the compiled "
+            "value-and-gradient", kept == 1 and forwards == 1,
+            f"recompute_kept {_compat.recompute_kept()}, "
+            f"{forwards} forward kernel call(s)")
+    (value, (grads, dx)) = compiled(arrays, hidden)
+    (want, (want_grads, want_dx)) = block_loss(False)(arrays, hidden)
+    err = max(_rel_err(g, w) for g, w in
+              zip([value, dx, *grads], [want, want_dx, *want_grads]))
+    c.check("recomputed block == plain block, value and every gradient",
+            err <= tol, f"rel err {err:.2e} (tol {tol})")
     c.done()
 
 

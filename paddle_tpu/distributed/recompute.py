@@ -10,6 +10,16 @@ segment's internal residuals and recomputes them in backward, trading
 FLOPs for HBM exactly like the reference's RecomputeFunction, but the
 recompute schedule is compiled into the XLA program instead of re-running
 Python.
+
+Two residuals are kept, where the segment has them: the output and the
+log-sum-exp that an attention forward kernel wrote (the flash and MLA
+kernels name them ``ATTENTION_OUT`` / ``ATTENTION_LSE`` in their forward
+rules). The attention backward needs nothing else beyond q, k and v, which
+are cheap projections of the segment's input, so the backward pass does not
+run the step's most expensive kernel a second time for two arrays the
+forward pass already wrote ([B, T, H d_v] and its row sums a layer). A
+segment without such a kernel (or one that reaches attention through the
+``jax.numpy`` form) has nothing under those names and keeps nothing.
 """
 from __future__ import annotations
 
@@ -64,7 +74,8 @@ def recompute(function, *args, use_reentrant=True,
     Tensor args (and any Layer parameters/buffers the function closes
     over) become inputs of the checkpointed segment so their gradients
     flow; everything computed inside is recomputed during backward instead
-    of being saved."""
+    of being saved, but for an attention kernel's output and log-sum-exp
+    (the module's docstring)."""
     # Collect params/buffers the function depends on so their gradients
     # flow: Layer instances directly, bound Layer methods, and Layers /
     # Parameters captured in a lambda's closure (the reference pattern
@@ -99,6 +110,8 @@ def recompute(function, *args, use_reentrant=True,
     # from this key (same dropout mask), and the global generator never
     # retains a sub-trace tracer (that leak breaks later ops).
     from ..core import random as random_mod
+    from ..kernels.pallas import _compat
+    from ..kernels.pallas.flash_attention import ATTENTION_LSE, ATTENTION_OUT
 
     seg_key = random_mod.split_key()
 
@@ -116,7 +129,7 @@ def recompute(function, *args, use_reentrant=True,
             for i, a in zip(slots, in_arrays):
                 rebuilt[i] = Tensor(a, stop_gradient=True)
             a2, k2 = jax.tree_util.tree_unflatten(treedef, rebuilt)
-            with autograd.no_grad():
+            with autograd.no_grad(), _compat.recompute_segment():
                 out = fn(*a2, **k2)
         finally:
             for t, a in zip(state, old):
@@ -130,7 +143,9 @@ def recompute(function, *args, use_reentrant=True,
             o._data if isinstance(o, Tensor) else o for o in out_flat
         )
 
-    ckpt = jax.checkpoint(pure)
+    ckpt = jax.checkpoint(
+        pure, policy=jax.checkpoint_policies.save_only_these_names(
+            ATTENTION_OUT, ATTENTION_LSE))
     tensor_inputs = tuple(state) + tuple(flat_in[i] for i in slots)
     results = dispatch.call("recompute", ckpt, tensor_inputs, {})
     results = (

@@ -40,6 +40,14 @@ kernels: ``paddle_tpu_kernels_ssd_chunk{chunk,d_head,d_state,groups}`` once
 a traced ``mamba2_ssd`` kernel call, and ``paddle_tpu_kernels_ssd_blocks
 {kernel,heads,chunks}`` once a traced kernel (the heads of a grid step, the
 chunks a sequence is walked in).
+
+A fifth: ``recompute_segment()`` — ``distributed.recompute`` marks the
+extent in which its segment is traced, and a ``flash_attention`` or
+``mla_attention`` call traced inside it through the kernels bumps
+``paddle_tpu_recompute_kept{kernel}`` (``record_recompute_kept()``, read by
+``recompute_kept()``): that call's forward rule named the kernel's output
+and log-sum-exp, which the segment keeps instead of running the forward
+kernel again in the backward pass.
 """
 from __future__ import annotations
 
@@ -100,6 +108,20 @@ def current_spmd_axes():
     """``(mesh, batch_axis, head_axis)`` of the enclosing ``spmd_axes``,
     or None in a single-device program."""
     return getattr(_spmd, "axes", None)
+
+
+_recompute = threading.local()
+
+
+@contextlib.contextmanager
+def recompute_segment():
+    """The code traced inside is a ``distributed.recompute`` segment."""
+    prev = getattr(_recompute, "inside", False)
+    _recompute.inside = True
+    try:
+        yield
+    finally:
+        _recompute.inside = prev
 
 
 # (kernel, reason) pairs already warned about — the counter moves on
@@ -296,3 +318,29 @@ def ssd_blocks():
         child.value
         for labels, child in _ssd_blocks_counter()._series()
     }
+
+
+def _recompute_kept_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_recompute_kept",
+        "Traced attention calls through the kernels inside a recompute "
+        "segment: the forward kernel's output and log-sum-exp are kept",
+        labelnames=("kernel",),
+    )
+
+
+def record_recompute_kept(kernel):
+    """One traced call of ``kernel`` (``"flash_attention"`` or
+    ``"mla_attention"``) through its kernels; counted inside a
+    ``recompute_segment()`` only."""
+    if getattr(_recompute, "inside", False):
+        _recompute_kept_counter().inc(kernel=kernel)
+
+
+def recompute_kept():
+    """{kernel: traced calls inside a recompute segment} (test/diagnostic
+    accessor)."""
+    return {labels["kernel"]: child.value
+            for labels, child in _recompute_kept_counter()._series()}

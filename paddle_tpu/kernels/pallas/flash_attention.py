@@ -50,6 +50,13 @@ its cotangent is summed over the head axis in VMEM (on a v5e at [2,
 [batch * heads, seq, 64] output summed by XLA). The same three kernels,
 under names of their own (``MLA_KERNELS``).
 
+Residuals. Beside q, k and v the backward kernels read what the forward
+kernel wrote: the output and the per-row logsumexp. The forward rules name
+the two (``ATTENTION_OUT``, ``ATTENTION_LSE``; ``checkpoint_name`` is the
+identity and lowers to nothing), so that a caller's ``jax.checkpoint`` can
+keep them by policy: ``distributed.recompute`` does, and a rematerialised
+layer then runs the forward kernel once a step, not twice.
+
 On non-TPU backends the kernels run in interpreter mode so the numerics
 are testable on the 8-device CPU mesh (conftest).
 """
@@ -59,12 +66,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ._compat import (current_spmd_axes, pl_call, record_flash_blocks,
-                      record_mla_blocks)
+                      record_mla_blocks, record_recompute_kept)
 
 NEG_INF = -1e30
 
@@ -73,6 +81,10 @@ KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
 FWD, BWD_DQ, BWD_DKV = KERNELS
 MLA_KERNELS = ("mla_attention_fwd", "mla_attention_bwd_dq",
                "mla_attention_bwd_dkv")
+# the forward kernel's two outputs, as the forward rules name them for a
+# caller's checkpoint policy (the docstring's Residuals)
+ATTENTION_OUT = "attention_out"
+ATTENTION_LSE = "attention_lse"
 
 # ---------------------------------------------------------------- tiling
 BLOCK_CANDIDATES = tuple(range(1024, 0, -128))
@@ -551,8 +563,16 @@ def _flash_core(q, k, v, scale, causal, blocks):
     return out
 
 
+def _named(out, lse):
+    """The forward kernel's output and log-sum-exp, in the kernels' own
+    [b*h, s, d] / [b*h, 8, s] layout, under their names."""
+    return (checkpoint_name(out, ATTENTION_OUT),
+            checkpoint_name(lse, ATTENTION_LSE))
+
+
 def _flash_core_fwd(q, k, v, scale, causal, blocks):
-    out, lse = _flash_fwd((q,), (k,), v, scale, causal, *blocks[0])
+    out, lse = _named(
+        *_flash_fwd((q,), (k,), v, scale, causal, *blocks[0]))
     return out, (q, k, v, out, lse)
 
 
@@ -617,6 +637,7 @@ def flash_attention(q, k, v, *, causal=True, scale=None,
                          block_k)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    record_recompute_kept("flash_attention")
     fn = functools.partial(
         _flash_4d, scale=float(scale), causal=bool(causal), blocks=blocks,
     )
@@ -646,8 +667,9 @@ def _mla_core(qn, qr, kn, kr, v, heads, scale, causal, blocks):
 
 
 def _mla_core_fwd(qn, qr, kn, kr, v, heads, scale, causal, blocks):
-    out, lse = _flash_fwd((qn, qr), (kn, kr), v, scale, causal, *blocks[0],
-                          name=MLA_KERNELS[0], heads=heads)
+    out, lse = _named(
+        *_flash_fwd((qn, qr), (kn, kr), v, scale, causal, *blocks[0],
+                    name=MLA_KERNELS[0], heads=heads))
     return out, (qn, qr, kn, kr, v, out, lse)
 
 
@@ -720,6 +742,7 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale, causal=True,
     width = q_nope.shape[-1] + q_rope.shape[-1]
     blocks = _blocks_for("mla_attention", sq, sk, width, v.dtype, block_q,
                          block_k)
+    record_recompute_kept("mla_attention")
 
     def merge(x):           # [b, s, h, d] -> the kernels' [b*h, s, d]
         return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], x.shape[3])
