@@ -9,7 +9,11 @@ the parallelizer, Pallas the escape hatch for fused attention/normalization.
 """
 from __future__ import annotations
 
-import os as _os
+import time as _time
+
+_import_start_ns = _time.time_ns()  # `runtime.import` opens here
+
+import os as _os  # noqa: E402
 
 # Multi-process bring-up MUST precede any XLA backend touch (jax raises
 # otherwise), so when the launcher's env contract is present the
@@ -146,3 +150,10 @@ def is_compiled_with_cuda() -> bool:
 
 def is_compiled_with_xpu() -> bool:
     return False
+
+
+# the package and everything it pulled in (JAX too, unless the caller had
+# it already) is one span of the ring: the first of a start-up's time line
+from .observability import spans as _spans  # noqa: E402
+
+_spans.record("runtime.import", _import_start_ns, _time.time_ns())

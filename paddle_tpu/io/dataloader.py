@@ -24,7 +24,9 @@ batch is there; ``ready=`` the batches workers had already delivered
 when ``next`` was called), ``loader.unpack`` (shared memory to arrays,
 or collate) and ``loader.h2d`` (arrays to device Tensors). Worker threads
 collate and place ahead of the consumer, so under THREADS the last two
-are spans of the worker's own thread.
+are spans of the worker's own thread. Each iterator's construction (the
+workers forked or the threads started, before the first ``next``) is one
+``loader.start`` span (``workers=``).
 """
 from __future__ import annotations
 
@@ -593,7 +595,8 @@ class DataLoader:
 
     def _iter_impl(self):
         if self.num_workers == 0:
-            produced = self._produce()
+            with span("loader.start", workers=0):
+                produced = self._produce()
             for want in itertools.count():
                 with span("loader.next", batch=want):
                     with span("loader.wait", ready=0):
@@ -604,7 +607,10 @@ class DataLoader:
                 yield batch
 
         if self.use_shared_memory and not self._iterable_mode:
-            yield from _MPLoaderIter(self)
+            # forked out of a process that may hold a model already
+            with span("loader.start", workers=self.num_workers):
+                forked = _MPLoaderIter(self)
+            yield from forked
             return
 
         def job_stream():
@@ -630,7 +636,8 @@ class DataLoader:
             depth=self.prefetch_factor * self.num_workers,
             num_threads=self.num_workers,
         )
-        pf.start()
+        with span("loader.start", workers=self.num_workers):
+            pf.start()
         try:
             yield from pf
         finally:

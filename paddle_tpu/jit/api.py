@@ -835,8 +835,18 @@ class TrainStep:
         ):
             self._compiled = None  # debug-net toggle changes the program
         if self._compiled is None:
-            self._compiled = self._build()
-        states = [opt._ensure_state(p) for p in self._params]
+            with span("train_step.build"):
+                self._compiled = self._build()
+        if all(id(p) in opt._accumulators for p in self._params):
+            states = [opt._ensure_state(p) for p in self._params]
+        else:
+            # masters and moments are made here, on the first step: a
+            # copy and two zeros a leaf, each shape its own small compile
+            with span("optimizer.init_state",
+                      leaves=len(self._params)) as made:
+                states = [opt._ensure_state(p) for p in self._params]
+                made.attrs["bytes"] = sum(
+                    a.nbytes for a in jax.tree_util.tree_leaves(states))
         # concrete layouts, read before payloads become tracers (static
         # per-param out constraints for the staged optimizer update)
         self._out_shardings = tuple(

@@ -23,7 +23,10 @@ lowering to a module, the backend's compile or the persistent cache's
 load) the watch hears on its own thread and records as ``jit.trace``,
 ``jit.lower`` and ``jit.compile`` spans (``cache_hit=`` on the last)
 and as ``trace_s``, ``lower_s``, ``compile_s`` on the event. A compile
-outside any ``watch`` records nothing.
+on a thread with no ``watch`` open (an eager operator, the optimizer's
+zeros, the caller's own ``jax.jit``) is filed as the same three spans
+with ``kind="unwatched"`` and ``fn=`` JAX's own name for the function:
+outermost intervals only, no event in the log, no counter.
 
 Executables loaded from the persistent compile cache
 (``paddle_tpu.compilecache``) are recorded via :func:`mark_aot_hit`
@@ -108,33 +111,59 @@ _PHASES = {
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 
-def _on_time_span(event, start_s, end_s, **kwargs):
-    """jax.monitoring listener, on the thread that compiles: an interval
-    of the innermost open ``watch``. Only the outermost intervals are
-    kept, so the phases never count a second twice: a jit traced inside
-    another's trace, a helper traced while lowering or a constant
-    compiled while tracing belongs to the phase that contains it."""
+def _on_phase_start(event, value, **kwargs):
+    """jax.monitoring scalar listener: JAX announces the start of each of
+    its timed intervals under the interval's own event. Counted a thread,
+    so that the interval's end knows whether it was an outermost one."""
+    if event in _PHASES:
+        _tls.depth = getattr(_tls, "depth", 0) + 1
+
+
+def _on_time_span(event, start_s, end_s, fun_name="", **kwargs):
+    """jax.monitoring listener, on the thread that compiles. Only the
+    outermost intervals are kept, so the phases never count a second
+    twice: a jit traced inside another's trace, a helper traced while
+    lowering or a constant compiled while tracing belongs to the phase
+    that contains it (one trace of a train step holds thousands).
+
+    Under a ``watch`` the interval is the innermost open watch's, filed
+    when that closes. With none open it is a compile the program or its
+    caller caused some other way (an eager operator, the optimizer's
+    zeros, the caller's own ``jax.jit``) and is filed at once, as a
+    ``jit.<phase>`` span of ``kind="unwatched"`` under JAX's own name for
+    the function: child of whatever span is open."""
     phase = _PHASES.get(event)
-    st = getattr(_tls, "stack", None)
-    if phase is None or not st or _suppressed():
+    if phase is None:
         return
-    w = st[-1]
-    hit = phase == "compile" and w._cache_hits > 0
+    depth = _tls.depth = max(getattr(_tls, "depth", 1) - 1, 0)
+    hit = False
     if phase == "compile":
-        w._cache_hits = 0
-    if any(p[1] <= start_s and end_s <= p[2] for p in w.phases):
+        hit = getattr(_tls, "cache_hits", 0) > 0
+        _tls.cache_hits = 0
+    if _suppressed():
         return
-    w.phases = [p for p in w.phases
-                if not (start_s <= p[1] and p[2] <= end_s)]
-    w.phases.append((phase, start_s, end_s, hit))
+    st = getattr(_tls, "stack", None)
+    if st:
+        if depth == st[-1]._depth:
+            st[-1].phases.append((phase, start_s, end_s, hit))
+    elif depth == 0:
+        _file_phase(phase, start_s, end_s, hit, fun_name, "unwatched")
+
+
+def _file_phase(phase, start_s, end_s, hit, fn, kind):
+    """One of JAX's intervals into the span ring (its clock is the
+    ring's: ``time.time()``)."""
+    attrs = {"cache_hit": hit} if phase == "compile" else {}
+    _spans.record("jit." + phase, start_s * 1e9, end_s * 1e9,
+                  fn=fn, kind=kind, **attrs)
 
 
 def _on_event(event, **kwargs):
-    st = getattr(_tls, "stack", None)
-    if event == _CACHE_HIT and st:
-        st[-1]._cache_hits += 1
+    if event == _CACHE_HIT:
+        _tls.cache_hits = getattr(_tls, "cache_hits", 0) + 1
 
 
+jax.monitoring.register_scalar_listener(_on_phase_start)
 jax.monitoring.register_event_time_span_listener(_on_time_span)
 jax.monitoring.register_event_listener(_on_event)
 
@@ -153,11 +182,12 @@ class watch:
         self.signature = str(signature)
         self.events = []
         self.phases = []      # (phase, start_s, end_s, cache_hit)
-        self._cache_hits = 0
+        self._depth = 0       # JAX's open intervals when the watch opened
         self._t0 = None
 
     def __enter__(self):
         self._t0 = time.perf_counter()
+        self._depth = getattr(_tls, "depth", 0)
         _watch_stack().append(self)
         return self
 
@@ -175,11 +205,8 @@ class watch:
             sums = {"trace": 0.0, "lower": 0.0, "compile": 0.0}
             for phase, start_s, end_s, hit in self.phases:
                 sums[phase] += end_s - start_s
-                attrs = {"cache_hit": hit} if phase == "compile" else {}
-                _spans.record(
-                    "jit." + phase, start_s * 1e9, end_s * 1e9,
-                    fn=self.name, kind=self.kind, **attrs,
-                )
+                _file_phase(phase, start_s, end_s, hit, self.name,
+                            self.kind)
             for ev in self.events:
                 ev["elapsed_s"] = elapsed
                 for phase, seconds in sums.items():

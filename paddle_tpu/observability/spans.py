@@ -16,10 +16,12 @@ With no session the annotation costs well under a microsecond (PERF.md
 has the chip host's figure). Finished spans additionally land in a
 bounded in-memory ring, with start and end as integer nanoseconds of
 ``time.time_ns()``, the clock the profiler stamps host events with, so a
-span in the ring and its event in a trace are the same interval. The
-ring is read with :func:`finished_spans` and :func:`last`, or exported
-as Chrome-trace JSONL (:func:`export_chrome_trace`, load via
-``chrome://tracing`` / Perfetto "json" mode).
+span in the ring and its event in a trace are the same interval, and
+with the id of the thread they ran on (``tid``), so a reader can nest
+the intervals of one thread. The ring is read with
+:func:`finished_spans` and :func:`last`, or exported as Chrome-trace
+JSONL (:func:`export_chrome_trace`, load via ``chrome://tracing`` /
+Perfetto "json" mode).
 """
 from __future__ import annotations
 
@@ -69,7 +71,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "attrs",
-        "start_ns", "end_ns",
+        "start_ns", "end_ns", "tid",
     )
 
     def __init__(self, name, trace_id=None, parent_id=None, **attrs):
@@ -80,6 +82,7 @@ class Span:
         self.attrs = attrs
         self.start_ns = None   # time.time_ns(), the profiler's host clock
         self.end_ns = None     # None while the span is open
+        self.tid = None        # the thread it ran on, set on entry
 
     @property
     def traceparent(self):
@@ -100,7 +103,7 @@ class Span:
             "ts": self.start_ns / 1e3,
             "dur": (self.duration_s or 0.0) * 1e6,
             "pid": os.getpid(),
-            "tid": threading.get_ident() & 0x7FFFFFFF,
+            "tid": (self.tid or 0) & 0x7FFFFFFF,
             "args": {
                 "trace_id": self.trace_id,
                 "span_id": self.span_id,
@@ -127,6 +130,7 @@ class _SpanScope:
         # interval and the trace's event are the same one
         self._annotation = TraceAnnotation(sp.name)
         self._annotation.__enter__()
+        sp.tid = threading.get_ident()
         sp.start_ns = time.time_ns()
         return sp
 
@@ -164,11 +168,13 @@ def span(name, **attrs):
 
 
 def record(name, start_ns, end_ns, **attrs):
-    """A span that somebody else timed, on the same clock, and that is
-    already over (``jit_events`` gets JAX's compile phases this way):
-    child of the current span, straight into the ring, no annotation."""
+    """A span that somebody else timed, on the same clock and on this
+    thread, and that is already over (``jit_events`` gets JAX's compile
+    phases this way): child of the current span, straight into the ring,
+    no annotation."""
     sp = _child(name, attrs)
     sp.start_ns, sp.end_ns = int(start_ns), int(end_ns)
+    sp.tid = threading.get_ident()
     with _buf_lock:
         _finished.append(sp)
     return sp

@@ -371,6 +371,9 @@ class TestServingTimelines:
         assert obs_engine.metrics.latency["ttft"].count >= 2
 
     def test_mean_ttft_derived_from_digest(self, obs_engine):
+        # under xdist's load distribution this may be the first test to
+        # touch its worker's engine: serve one request of its own
+        obs_engine.generate([[5, 6, 7]], SamplingParams(max_new_tokens=2))
         m = obs_engine.metrics
         d = m.latency["ttft"]
         assert m.mean_ttft == pytest.approx(d.sum / d.count)

@@ -328,6 +328,9 @@ def test_every_next_of_the_loader_is_a_span_with_its_parts(
 
 
 def test_compile_phases_are_spans_under_a_watch_and_only_there(ring):
+    """Under a watch the phases are the watch's (``kind="train_step"``,
+    children of the launch); a compile on a thread with no watch open is
+    filed too, once, as ``kind="unwatched"`` under JAX's own name."""
     jit_events.clear_compile_log()
     step, ids = _tiny_step()
     step(ids)
@@ -337,20 +340,33 @@ def test_compile_phases_are_spans_under_a_watch_and_only_there(ring):
     total = 0.0
     for phase in ("trace", "lower", "compile"):
         found = [s for s in ring.finished_spans()
-                 if s.name == "jit." + phase]
+                 if s.name == "jit." + phase
+                 and s.attrs["kind"] == "train_step"]
         assert found and all(s.parent_id == launch.span_id for s in found)
-        assert all(s.attrs["kind"] == "train_step" for s in found)
         seconds = sum(s.duration_s for s in found)
         assert event[phase + "_s"] == pytest.approx(seconds, abs=1e-6)
         assert all(launch.start_ns - 5e6 <= s.start_ns
                    and s.end_ns <= launch.end_ns + 5e6 for s in found)
         total += seconds
     assert "cache_hit" in ring.last("jit.compile", 1)[0].attrs
-    # only outermost intervals are kept: the phases fit inside the call
+    # only outermost intervals are kept: the phases fit inside the call,
+    # and nothing else was heard while the launch was open
     assert 0 < total <= event["elapsed_s"] + 5e-3
-    # the warm call compiles nothing; a jit outside any watch records nothing
+    assert not [s for s in ring.finished_spans()
+                if s.name.startswith("jit.") and s.parent_id == launch.span_id
+                and s.attrs["kind"] != "train_step"]
+    # the warm call compiles nothing; a bare jit files one unwatched set
     ring.clear_finished_spans()
     step(ids)
-    jax.jit(lambda x: x * 3 + 1)(jnp.ones(5))
     assert not [s for s in ring.finished_spans()
                 if s.name.startswith("jit.")]
+    jax.jit(lambda x: x * 3 + 1)(jnp.ones(5))
+    bare = [s for s in ring.finished_spans() if s.name.startswith("jit.")
+            and "<lambda>" in s.attrs["fn"]]
+    assert [(s.name, s.attrs["fn"]) for s in bare] == [
+        ("jit.trace", "<lambda>"), ("jit.lower", "jit(<lambda>)"),
+        ("jit.compile", "jit(<lambda>)")]
+    assert all(s.attrs["kind"] == "unwatched" and s.parent_id is None
+               for s in bare)
+    assert not [e for e in jit_events.compile_log()
+                if e["kind"] != "train_step"]
