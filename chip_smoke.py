@@ -572,6 +572,11 @@ def phase_latent():
               zip([value, dx, *grads], [want, want_dx, *want_grads]))
     c.check("recomputed block == plain block, value and every gradient",
             err <= tol, f"rel err {err:.2e} (tol {tol})")
+    # every call above had 128-wide parts: the kernels read them in place
+    forms = _compat.mla_operands()
+    c.check("the MLA kernels read q_nope, k_nope and v flat, [B, T, H d]",
+            forms.get("flat", 0) >= 2 and not forms.get("heads"),
+            f"mla_operands {forms}")
     c.done()
 
 

@@ -34,7 +34,10 @@ same count for the latent-attention kernels (``paddle_tpu_kernels_mla_blocks{ker
 (``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``), and
 ``record_gdr_operands()`` for the form a ``gated_delta_rule`` call's q, k
 and v came in (``paddle_tpu_kernels_gdr_operands{form}``: ``flat`` is what
-the kernels read in place, ``heads`` costs a copy of each on a TPU).
+the kernels read in place, ``heads`` costs a copy of each on a TPU);
+``record_mla_operands()`` is its twin for an ``mla_attention`` call through
+the kernels (``paddle_tpu_kernels_mla_operands{form}``: ``flat`` where the
+parts are 128 wide, ``heads`` where they are narrower and are merged).
 ``record_ssd_chunk()`` and ``record_ssd_blocks()`` do it for the Mamba-2
 kernels: ``paddle_tpu_kernels_ssd_chunk{chunk,d_head,d_state,groups}`` once
 a traced ``mamba2_ssd`` kernel call, and ``paddle_tpu_kernels_ssd_blocks
@@ -264,6 +267,30 @@ def gdr_operands():
     """{form: traced calls} (test/diagnostic accessor)."""
     return {labels["form"]: child.value
             for labels, child in _gdr_operands_counter()._series()}
+
+
+def _mla_operands_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_mla_operands",
+        "Traced mla_attention calls through the kernels by the form their "
+        "128-wide parts are read in: flat [B, T, H d] or heads [B H, T, d]",
+        labelnames=("form",),
+    )
+
+
+def record_mla_operands(form):
+    """One traced ``mla_attention`` call through the kernels whose q_nope,
+    k_nope and v (and out and the cotangents) the kernels read ``"flat"``,
+    [B, T, H d] in place, or as ``"heads"``, [B H, T, d]."""
+    _mla_operands_counter().inc(form=form)
+
+
+def mla_operands():
+    """{form: traced calls} (test/diagnostic accessor)."""
+    return {labels["form"]: child.value
+            for labels, child in _mla_operands_counter()._series()}
 
 
 def _ssd_chunk_counter():

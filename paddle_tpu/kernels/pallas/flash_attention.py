@@ -7,8 +7,18 @@ an online-softmax forward saving per-row logsumexp, and a blocked backward
 (`dq`; `dk` and `dv`) recomputing probabilities (no s×s materialization in
 HBM either way).
 
-Layout contract matches the public API: q/k/v are [batch, seq, heads,
-head_dim]; the kernel operates in [batch*heads, seq, head_dim].
+Layout contract. The public API takes q/k/v as [batch, seq, heads,
+head_dim]. A kernel operand comes in one of two forms, told apart by its
+leading dim (``_head_spec``, one index-map helper): [batch*heads, seq, d],
+a head a row, which ``flash_attention`` hands its kernels; or flat [batch,
+seq, heads*d], a head a column block of d, read in place where d is a
+multiple of 128 (a lane tile), which ``mla_attention`` hands them for its
+128-wide parts. On a TPU a reshape between [b, s, h*d] and [b, s, h, d] is
+a relayout, so the flat form spares a transpose of every such operand, of
+the output and of each cotangent, forward and backward. An output and a
+cotangent take their operand's form. The per-row statistics (logsumexp,
+delta) are [batch*heads, 8, seq] in either; delta = rowsum(dO * O) is an
+XLA reduction of the first form and the `dq` kernel's of the second.
 
 Grid: (bh, q_blocks, k_blocks) with the k dimension innermost/"arbitrary"
 so the scratch carry (running max / sum / accumulator) is valid across the
@@ -41,21 +51,26 @@ Latent attention (MLA). The kernels take the score as a sum of products
 into one tile (``parts``): ``mla_attention`` gives them ``q_nope k_nope^T``
 over each head's own 128 columns and ``q_rope k_rope^T`` over 64 rotary
 columns whose key is ONE head read by all, under values of their own
-width. The shared key is an operand of its own, [batch, seq, 64], whose
-index map drops the head: it is never broadcast to the heads in HBM, and
-no head is padded to a common width. `dk`/`dv` then walks (batch, k block,
-head, q block): the shared key's block stays resident for all heads and
-its cotangent is summed over the head axis in VMEM (on a v5e at [2,
-8192, 32 heads] forward + backward take 56.2 ms so, 56.9 with a float32
-[batch * heads, seq, 64] output summed by XLA). The same three kernels,
-under names of their own (``MLA_KERNELS``).
+width. q_nope, k_nope and v (so out, dO and their cotangents) are flat,
+[batch, seq, heads*128], as the block's projections write them
+(``_compat.record_mla_operands``: ``flat``; parts narrower than 128 are
+merged: ``heads``); the rotary query, half a lane tile wide, is [batch*
+heads, seq, 64]. The shared key is an operand of its own, [batch, seq,
+64], whose index map drops the head: it is never broadcast to the heads
+in HBM, and no head is padded to a common width. `dk`/`dv` then walks
+(batch, k block, head, q block): the shared key's block stays resident
+for all heads and its cotangent is summed over the head axis in VMEM (on
+a v5e at [2, 8192, 32 heads] forward + backward take 56.2 ms so, 56.9
+with a float32 [batch * heads, seq, 64] output summed by XLA). The same
+three kernels, under names of their own (``MLA_KERNELS``).
 
 Residuals. Beside q, k and v the backward kernels read what the forward
-kernel wrote: the output and the per-row logsumexp. The forward rules name
-the two (``ATTENTION_OUT``, ``ATTENTION_LSE``; ``checkpoint_name`` is the
-identity and lowers to nothing), so that a caller's ``jax.checkpoint`` can
-keep them by policy: ``distributed.recompute`` does, and a rematerialised
-layer then runs the forward kernel once a step, not twice.
+kernel wrote: the output (in v's form) and the per-row logsumexp. The
+forward rules name the two (``ATTENTION_OUT``, ``ATTENTION_LSE``;
+``checkpoint_name`` is the identity and lowers to nothing), so that a
+caller's ``jax.checkpoint`` can keep them by policy:
+``distributed.recompute`` does, and a rematerialised layer then runs the
+forward kernel once a step, not twice.
 
 On non-TPU backends the kernels run in interpreter mode so the numerics
 are testable on the 8-device CPU mesh (conftest).
@@ -72,7 +87,8 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ._compat import (current_spmd_axes, pl_call, record_flash_blocks,
-                      record_mla_blocks, record_recompute_kept)
+                      record_mla_blocks, record_mla_operands,
+                      record_recompute_kept)
 
 NEG_INF = -1e30
 
@@ -278,27 +294,58 @@ def _fwd_kernel(*refs, parts, scale, causal, block_q, block_k):
 
 
 def _kv_index(causal, block_q, block_k, nk):
-    """Index map of a [block_k, d] operand in a (b, q block, k block)
-    grid. Causal: a step above the diagonal names the last block the q
-    block sees any of, which is resident, so nothing is fetched for it."""
+    """Where a [block_k, d] operand's block lies along the sequence in a
+    (b, q block, k block) grid. Causal: a step above the diagonal names the
+    last block the q block sees any of, which is resident, so nothing is
+    fetched for it."""
     if not causal:
-        return lambda b, i, j: (b, j, 0)
+        return lambda b, i, j: j
 
     def last(i):
         return jnp.minimum((i * block_q + block_q - 1) // block_k, nk - 1)
 
-    return lambda b, i, j: (b, jnp.minimum(j, last(i)), 0)
+    return lambda b, i, j: jnp.minimum(j, last(i))
 
 
-def _k_specs(ks, block_k, index, heads):
-    """A [block_k, d] block of every key part at ``index``; with
-    ``heads``, the last part is one head shared by that many, [batch,
-    seq, d], and its index drops the head."""
-    specs = [pl.BlockSpec((1, block_k, k.shape[-1]), index) for k in ks]
+def _head_spec(x, block, bh, rows, cols):
+    """The BlockSpec of one head's [block, d] rows of ``x``, in the form
+    ``x`` comes in (its leading dim tells): [b*h, s, d], a head a row, at
+    ``rows(*grid)`` -> (row, block along s); or flat [b, s, h*d], a head a
+    column block (d a multiple of 128 on a TPU), at ``cols(*grid)`` ->
+    (batch, block along s, head)."""
+    if x.shape[0] == bh:
+        return pl.BlockSpec((1, block, x.shape[-1]),
+                            lambda *g: (*rows(*g), 0))
+    return pl.BlockSpec((1, block, _width(x, bh)), cols)
+
+
+def _width(x, bh):
+    """One head's columns of an operand in either form of ``_head_spec``."""
+    return x.shape[-1] * x.shape[0] // bh
+
+
+def _by_row(seq, heads):
+    """(rows, cols) of ``_head_spec`` in a grid whose first index is the
+    head's row b*h and ``seq(*grid)`` the block along s."""
+    def rows(r, *g):
+        return r, seq(r, *g)
+
+    def cols(r, *g):
+        return r // heads, seq(r, *g), r % heads
+
+    return rows, cols
+
+
+def _k_specs(ks, block_k, bh, at, heads):
+    """A [block_k, d] block of every key part at ``at`` (``_by_row``'s
+    pair); with ``heads``, the last part is one head shared by that many,
+    [batch, seq, d], and its index drops the head."""
+    rows, _ = at
+    specs = [_head_spec(k, block_k, bh, *at) for k in ks]
     if heads:
         specs[-1] = pl.BlockSpec(
             (1, block_k, ks[-1].shape[-1]),
-            lambda b, *ij: (b // heads,) + tuple(index(b, *ij)[1:]))
+            lambda b, *ij: (b // heads, rows(b, *ij)[1], 0))
     return specs
 
 
@@ -309,15 +356,18 @@ def _record(name, block_q, block_k):
 
 def _flash_fwd(qs, ks, v, scale, causal, block_q, block_k, name=FWD,
                heads=0):
-    """qs, ks: the score's parts, tuples of [bh, s, d_part] (``heads``:
-    the last key part is [b, s, d_part], shared by that many heads)."""
-    bh, sq, _ = qs[0].shape
-    sk, dv = ks[0].shape[1], v.shape[-1]
-    d = sum(q.shape[-1] for q in qs)
+    """qs, ks: the score's parts, tuples of [bh, s, d_part] or, with
+    ``heads``, flat [b, s, heads * d_part] (``_head_spec``); with
+    ``heads`` the last key part is [b, s, d_part], shared by that many
+    heads. The output takes v's form."""
+    bh = ks[-1].shape[0] * heads if heads else qs[0].shape[0]
+    sq, sk = qs[0].shape[1], ks[0].shape[1]
+    widths = [_width(q, bh) for q in qs]
+    dv = _width(v, bh)
     nq, nk = sq // block_q, sk // block_k
     _record(name, block_q, block_k)
-    at_q = lambda b, i, j: (b, i, 0)
-    at_k = _kv_index(causal, block_q, block_k, nk)
+    at_q = _by_row(lambda b, i, j: i, heads)
+    at_k = _by_row(_kv_index(causal, block_q, block_k, nk), heads)
 
     out, lse = pl_call(
         functools.partial(
@@ -327,35 +377,46 @@ def _flash_fwd(qs, ks, v, scale, causal, block_q, block_k, name=FWD,
         name=name,
         grid=(bh, nq, nk),
         in_specs=[
-            *(pl.BlockSpec((1, block_q, q.shape[-1]), at_q) for q in qs),
-            *_k_specs(ks, block_k, at_k, heads),
-            pl.BlockSpec((1, block_k, dv), at_k),
+            *(_head_spec(q, block_q, bh, *at_q) for q in qs),
+            *_k_specs(ks, block_k, bh, at_k, heads),
+            _head_spec(v, block_k, bh, *at_k),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), at_q),
+            _head_spec(v, block_q, bh, *at_q),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(v.shape[:1] + (sq, dv), v.dtype),
+            jax.ShapeDtypeStruct(v.shape[:1] + (sq,) + v.shape[2:],
+                                 v.dtype),
             jax.ShapeDtypeStruct((bh, 8, sq), jnp.float32),
         ],
         scratch_shapes=[
-            *(pltpu.VMEM((block_q, q.shape[-1]), q.dtype) for q in qs),
+            *(pltpu.VMEM((block_q, w), q.dtype) for q, w in zip(qs, widths)),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=_compiler_params(FWD, block_q, block_k, d,
+        compiler_params=_compiler_params(FWD, block_q, block_k, sum(widths),
                                          v.dtype),
     )(*qs, *ks, v)
     return out, lse
 
 
 # --------------------------------------------------------------- backward
-def _bwd_dq_kernel(*refs, parts, scale, causal, block_q, block_k):
-    q_refs, k_refs, (v_ref, do_ref, lse_ref, delta_ref), dq_refs, qs_scrs, (
-        lse_scr, delta_scr), acc_scrs = _split(
-            refs, parts, parts, 4, parts, parts, 2, parts)
+def _bwd_dq_kernel(*refs, parts, scale, causal, block_q, block_k,
+                   own_delta=False):
+    """With ``own_delta`` the kernel reads O beside dO, takes delta =
+    rowsum(dO * O) of its q block once a sweep and writes it out for
+    `dk`/`dv`: XLA would take it of the flat form only through a relayout
+    of the float32 product."""
+    if own_delta:
+        q_refs, k_refs, (v_ref, do_ref, out_ref, lse_ref), dq_refs, (
+            delta_ref,), qs_scrs, (lse_scr, delta_scr), acc_scrs = _split(
+                refs, parts, parts, 4, parts, 1, parts, 2, parts)
+    else:
+        q_refs, k_refs, (v_ref, do_ref, lse_ref, delta_ref), dq_refs, \
+            qs_scrs, (lse_scr, delta_scr), acc_scrs = _split(
+                refs, parts, parts, 4, parts, parts, 2, parts)
     kb = pl.program_id(2)
     qb = pl.program_id(1)
     nk = pl.num_programs(2)
@@ -366,7 +427,15 @@ def _bwd_dq_kernel(*refs, parts, scale, causal, block_q, block_k):
             qs_scr[:] = _scaled(q_ref[0], scale)
         # the rows' statistics, turned into columns once a sweep
         lse_scr[:] = _column(lse_ref)
-        delta_scr[:] = _column(delta_ref)
+        if own_delta:
+            col = jnp.sum(do_ref[0].astype(jnp.float32)
+                          * out_ref[0].astype(jnp.float32),
+                          axis=1, keepdims=True)
+            delta_scr[:] = jnp.broadcast_to(col, delta_scr.shape)
+            delta_ref[0] = jnp.broadcast_to(col.reshape(1, -1),
+                                            delta_ref.shape[1:])
+        else:
+            delta_scr[:] = _column(delta_ref)
         for acc_scr in acc_scrs:
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
@@ -458,48 +527,58 @@ def _bwd_dkv_kernel(*refs, parts, heads, scale, causal, block_q, block_k):
 
 def _flash_bwd(qs, ks, v, out, lse, do, scale, causal, dq_blocks,
                dkv_blocks, names=KERNELS, heads=0):
-    """-> (dqs, dks, dv), the parts' cotangents as tuples."""
-    bh, sq, _ = qs[0].shape
-    sk, dv = ks[0].shape[1], v.shape[-1]
-    d = sum(q.shape[-1] for q in qs)
+    """-> (dqs, dks, dv), the parts' cotangents as tuples, each in its
+    operand's form (``_flash_fwd``'s)."""
+    bh = lse.shape[0]
+    sq, sk = qs[0].shape[1], ks[0].shape[1]
+    widths = [_width(q, bh) for q in qs]
+    d, dv = sum(widths), _width(v, bh)
     parts = len(qs)
-    delta_row = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # [bh, sq]
-    # sublane-replicated like lse (TPU block tiling rule)
-    delta = jnp.broadcast_to(delta_row[:, None, :], (bh, 8, sq))
-
-    def widths(block, arrays, index):
-        return [pl.BlockSpec((1, block, a.shape[-1]), index) for a in arrays]
+    # delta = rowsum(dO * O): of the [bh, s, d] form one XLA reduction,
+    # sublane-replicated like lse (TPU block tiling rule); of the flat
+    # form the `dq` kernel's (``own_delta``)
+    own_delta = out.shape[0] != bh
+    if not own_delta:
+        delta_row = jnp.sum(
+            do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+        )  # [bh, sq]
+        delta = jnp.broadcast_to(delta_row[:, None, :], (bh, 8, sq))
 
     block_q, block_k = dq_blocks
     nq, nk = sq // block_q, sk // block_k
     _record(names[1], block_q, block_k)
-    at_q = lambda b, i, j: (b, i, 0)
-    at_k = _kv_index(causal, block_q, block_k, nk)
+    at_q = _by_row(lambda b, i, j: i, heads)
+    at_k = _by_row(_kv_index(causal, block_q, block_k, nk), heads)
     row = pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i))
+    q_specs = [_head_spec(q, block_q, bh, *at_q) for q in qs]
+    do_spec = _head_spec(do, block_q, bh, *at_q)
+    delta_shape = jax.ShapeDtypeStruct((bh, 8, sq), jnp.float32)
     dqs = pl_call(
         functools.partial(
             _bwd_dq_kernel, parts=parts, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, own_delta=own_delta,
         ),
         name=names[1],
         grid=(bh, nq, nk),
-        in_specs=[*widths(block_q, qs, at_q),
-                  *_k_specs(ks, block_k, at_k, heads),
-                  *widths(block_k, (v,), at_k),
-                  *widths(block_q, (do,), at_q), row, row],
-        out_specs=widths(block_q, qs, at_q),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs],
+        in_specs=[*q_specs,
+                  *_k_specs(ks, block_k, bh, at_k, heads),
+                  _head_spec(v, block_k, bh, *at_k), do_spec,
+                  *((_head_spec(out, block_q, bh, *at_q), row) if own_delta
+                    else (row, row))],
+        out_specs=[*q_specs, *((row,) if own_delta else ())],
+        out_shape=[*(jax.ShapeDtypeStruct(q.shape, q.dtype) for q in qs),
+                   *((delta_shape,) if own_delta else ())],
         scratch_shapes=[
-            *(pltpu.VMEM((block_q, q.shape[-1]), q.dtype) for q in qs),
+            *(pltpu.VMEM((block_q, w), q.dtype) for q, w in zip(qs, widths)),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            *(pltpu.VMEM((block_q, q.shape[-1]), jnp.float32) for q in qs),
+            *(pltpu.VMEM((block_q, w), jnp.float32) for w in widths),
         ],
         compiler_params=_compiler_params(BWD_DQ, block_q, block_k, d,
                                          v.dtype),
-    )(*qs, *ks, v, do, lse, delta)
+    )(*qs, *ks, v, do, *((out, lse) if own_delta else (lse, delta)))
+    if own_delta:
+        *dqs, delta = dqs
 
     block_q, block_k = dkv_blocks
     nq, nk = sq // block_q, sk // block_k
@@ -514,21 +593,25 @@ def _flash_bwd(qs, ks, v, out, lse, do, scale, causal, dq_blocks,
         def q_of(j, i):
             return i
     if heads:
-        # (batch, k block, head, q block): the head's own operands at
-        # batch * heads + head, the shared key's at batch
+        # (batch, k block, head, q block): a head's own operands at row
+        # batch * heads + head or at column block head of row batch, the
+        # shared key's at batch
         grid = (bh // heads, nk, heads, nq)
-        at_q = lambda b, j, h, i: (b * heads + h, q_of(j, i), 0)
-        at_k = lambda b, j, h, i: (b * heads + h, j, 0)
+        at_q = (lambda b, j, h, i: (b * heads + h, q_of(j, i)),
+                lambda b, j, h, i: (b, q_of(j, i), h))
+        at_k = (lambda b, j, h, i: (b * heads + h, j),
+                lambda b, j, h, i: (b, j, h))
         at_row = lambda b, j, h, i: (b * heads + h, 0, q_of(j, i))
     else:
         grid = (bh, nk, nq)
-        at_q = lambda b, j, i: (b, q_of(j, i), 0)
-        at_k = lambda b, j, i: (b, j, 0)
+        at_q = (lambda b, j, i: (b, q_of(j, i)), None)
+        at_k = (lambda b, j, i: (b, j), None)
         at_row = lambda b, j, i: (b, 0, q_of(j, i))
-    k_specs = widths(block_k, ks, at_k)
+    k_specs = [_head_spec(k, block_k, bh, *at_k) for k in ks]
     if heads:
         k_specs[-1] = pl.BlockSpec((1, block_k, ks[-1].shape[-1]),
                                    lambda b, j, h, i: (b, j, 0))
+    v_spec = _head_spec(v, block_k, bh, *at_k)
     row = pl.BlockSpec((1, 8, block_q), at_row)
     *dks, dv_out = pl_call(
         functools.partial(
@@ -537,17 +620,17 @@ def _flash_bwd(qs, ks, v, out, lse, do, scale, causal, dq_blocks,
         ),
         name=names[2],
         grid=grid,
-        in_specs=[*widths(block_q, qs, at_q), *k_specs,
-                  *widths(block_k, (v,), at_k),
-                  *widths(block_q, (do,), at_q), row, row],
-        out_specs=[*k_specs, *widths(block_k, (v,), at_k)],
+        in_specs=[*(_head_spec(q, block_q, bh, *at_q) for q in qs),
+                  *k_specs, v_spec, _head_spec(do, block_q, bh, *at_q),
+                  row, row],
+        out_specs=[*k_specs, v_spec],
         out_shape=[
             *(jax.ShapeDtypeStruct(k.shape, k.dtype) for k in ks),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            *(pltpu.VMEM((block_k, k.shape[-1]), k.dtype) for k in ks),
-            *(pltpu.VMEM((block_k, k.shape[-1]), jnp.float32) for k in ks),
+            *(pltpu.VMEM((block_k, w), k.dtype) for k, w in zip(ks, widths)),
+            *(pltpu.VMEM((block_k, w), jnp.float32) for w in widths),
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(BWD_DKV, block_q, block_k, d,
@@ -565,7 +648,8 @@ def _flash_core(q, k, v, scale, causal, blocks):
 
 def _named(out, lse):
     """The forward kernel's output and log-sum-exp, in the kernels' own
-    [b*h, s, d] / [b*h, 8, s] layout, under their names."""
+    layouts ([b*h, s, d] or flat [b, s, h*d]; [b*h, 8, s]), under their
+    names."""
     return (checkpoint_name(out, ATTENTION_OUT),
             checkpoint_name(lse, ATTENTION_LSE))
 
@@ -743,11 +827,23 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale, causal=True,
     blocks = _blocks_for("mla_attention", sq, sk, width, v.dtype, block_q,
                          block_k)
     record_recompute_kept("mla_attention")
+    # a 128-wide part is read in place, its heads column blocks of [b, s,
+    # h*d] (the reshape folds into the producer's); a narrower one would be
+    # part of a lane tile, so it is merged to [b*h, s, d], as the rotary
+    # query always is
+    flat = q_nope.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+    record_mla_operands("flat" if flat else "heads")
 
-    def merge(x):           # [b, s, h, d] -> the kernels' [b*h, s, d]
+    def merge(x):           # [b, s, h, d] -> [b*h, s, d]
         return jnp.swapaxes(x, 1, 2).reshape(-1, x.shape[1], x.shape[3])
 
-    out = _mla_core(merge(q_nope), merge(q_rope), merge(k_nope),
-                    k_rope[:, :, 0], merge(v), heads, float(scale),
+    def join(x):            # [b, s, h, d] -> [b, s, h*d]
+        return x.reshape(b, x.shape[1], -1)
+
+    wide = join if flat else merge
+    out = _mla_core(wide(q_nope), merge(q_rope), wide(k_nope),
+                    k_rope[:, :, 0], wide(v), heads, float(scale),
                     bool(causal), blocks)
+    if flat:
+        return out.reshape(b, sq, heads, v.shape[-1])
     return jnp.swapaxes(out.reshape(b, heads, sq, v.shape[-1]), 1, 2)

@@ -25,7 +25,9 @@ ref: HF transformers ``modeling_deepseek_v3.py`` and the published
     rotate-half order; the last d_rope columns of ``kv_a_proj_with_mqa``
     are permuted alike, so every score is what the interleaved rope gives;
     ``kv_b_proj``'s (per head: key | value) become all keys, then all
-    values. Every boundary is then a multiple of 128 columns.
+    values. Every boundary is then a multiple of 128 columns, and each
+    part is a product of its own with its columns: q_nope, k_nope and v
+    stay [b, s, heads * 128], which the kernels read in place.
   * MLP_i: dense SwiGLU for ``i < first_k_dense_replace`` (and where
     ``i % moe_layer_freq != 0``), else ``incubate.moe.MoELayer`` told which
     experts it holds, ``scoring="sigmoid"``: ``s = sigmoid(x W_g)`` in
@@ -226,30 +228,34 @@ class DeepseekV3Attention(Layer):
         b, s = hidden.shape[0], hidden.shape[1]
         heads, nope, rope, dv = (self.num_heads, self.nope, self.rope,
                                  self.v_dim)
+        # each part is a product of its own with its columns of the
+        # reordered weight: the kernels read a part in place, and a slice
+        # of one wider activation would be a copy of it (its gradient a
+        # concatenation); the weights' gradients are joined at their size
         with scope("attention.latent"):
-            q = F.linear(hidden, _op(
-                _part_major, self.q_proj.weight, heads=heads,
-                widths=(nope, rope),
-                interleaved=(1,) if self.interleaved else ()))
+            w_q = _op(_part_major, self.q_proj.weight, heads=heads,
+                      widths=(nope, rope),
+                      interleaved=(1,) if self.interleaved else ())
+            q_nope = F.linear(hidden, w_q[:, :heads * nope])
+            q_pe = F.linear(hidden, w_q[:, heads * nope:])
             kva = F.linear(hidden, _op(
                 _rope_tail, self.kv_a_proj_with_mqa.weight, width=rope,
                 interleaved=self.interleaved))
             latent = self.kv_a_layernorm(kva[:, :, :self.rank])
         with scope("attention.expand"):
-            kv = F.linear(latent, _op(
-                _part_major, self.kv_b_proj.weight, heads=heads,
-                widths=(nope, dv)))
+            w_kv = _op(_part_major, self.kv_b_proj.weight, heads=heads,
+                       widths=(nope, dv))
+            k_nope = F.linear(latent, w_kv[:, :heads * nope])
+            v = F.linear(latent, w_kv[:, heads * nope:])
         with scope("attention.core"):
             q_rope, k_rope = F.rope_qk(
-                F.reshape(q[:, :, heads * nope:], [b, s, heads, rope]),
+                F.reshape(q_pe, [b, s, heads, rope]),
                 F.reshape(kva[:, :, self.rank:], [b, s, 1, rope]),
                 base=self.rope_theta)
             out = F.mla_attention(
-                F.reshape(q[:, :, :heads * nope], [b, s, heads, nope]),
-                q_rope,
-                F.reshape(kv[:, :, :heads * nope], [b, s, heads, nope]),
-                k_rope,
-                F.reshape(kv[:, :, heads * nope:], [b, s, heads, dv]),
+                F.reshape(q_nope, [b, s, heads, nope]), q_rope,
+                F.reshape(k_nope, [b, s, heads, nope]), k_rope,
+                F.reshape(v, [b, s, heads, dv]),
                 scale=(nope + rope) ** -0.5)
         with scope("attention.out"):
             return self.o_proj(F.reshape(out, [b, s, heads * dv]))

@@ -8,6 +8,8 @@ de-interleaved weights against the interleaved rope, and the scopes and
 counters of a traced step. Three AdamW steps through the runner are in
 tests/benchmarks/test_kanana2_benchmark.py, beside the configuration.
 """
+import contextlib
+import io
 import os
 import sys
 
@@ -253,6 +255,94 @@ def test_mla_kernels_with_bf16_operands_and_no_causal_mask():
             b.astype(jnp.float32)).max()))
 
 
+def _merged_path(q_nope, q_rope, k_nope, k_rope, v, *, scale, causal,
+                 block_q, block_k):
+    """The kernels as the parent handed them every part: merged to [b*h,
+    s, d] and back."""
+    b, s, h, _ = q_nope.shape
+    merge = lambda x: jnp.swapaxes(x, 1, 2).reshape(b * h, s, x.shape[-1])
+    blocks = fa._blocks_for("mla_attention", s, s, 192, v.dtype, block_q,
+                            block_k)
+    out = fa._mla_core(merge(q_nope), merge(q_rope), merge(k_nope),
+                       k_rope[:, :, 0], merge(v), h, float(scale),
+                       bool(causal), blocks)
+    return jnp.swapaxes(out.reshape(b, h, s, -1), 1, 2)
+
+
+@pytest.mark.parametrize("causal,block_q,block_k,kept", [
+    (True, 128, 128, False), (False, 128, 256, False),
+    (True, 256, 128, True), (False, 128, 128, True)])
+def test_mla_kernels_read_the_flat_parts_in_place(causal, block_q, block_k,
+                                                  kept):
+    """Five heads of their own random data (a head read at another's
+    column block fails), several q and k blocks: the forward bit for bit
+    the merged path's (each tile does the same arithmetic) and all five
+    cotangents against plain attention. ``kept``: under jax.checkpoint
+    with `recompute`'s policy, which keeps the forward kernel's output in
+    the flat form."""
+    ops, w = _mla_operands(2, 512, 5, seed=7)
+    b, s, h, d = ops[-1].shape
+    scale = 192 ** -0.5
+    policy = jax.checkpoint_policies.save_only_these_names(
+        fa.ATTENTION_OUT, fa.ATTENTION_LSE)
+
+    def forward(fn, **kw):
+        def run(*a):
+            out = fn(*a, scale=scale, causal=causal, **kw)
+            return jnp.sum(out * w), out
+        return jax.checkpoint(run, policy=policy) if kept else run
+
+    def loss(fn, **kw):
+        return jax.value_and_grad(forward(fn, **kw), argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)
+
+    forms = _compat.mla_operands()
+    (_, out), grads = loss(fa.mla_attention, impl="pallas", block_q=block_q,
+                           block_k=block_k)(*ops)
+    assert _compat.mla_operands().get("flat", 0) == forms.get("flat", 0) + 1
+    (_, ref), ref_grads = loss(fa.mla_attention_xla)(*ops)
+    merged = _merged_path(*ops, scale=scale, causal=causal, block_q=block_q,
+                          block_k=block_k)
+    assert np.array_equal(out, merged)
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+    for got, want in zip(grads, ref_grads):
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    if kept:
+        # what the checkpoint keeps: out flat, [b, s, h*d], and lse
+        listing = io.StringIO()
+        with contextlib.redirect_stdout(listing):
+            jax.ad_checkpoint.print_saved_residuals(
+                lambda *a: forward(fa.mla_attention, impl="pallas",
+                                   block_q=block_q, block_k=block_k)(*a)[0],
+                *ops)
+        # (jax lists `out` as the output of the no-op reduce_precision it
+        # guards a residual with that the forward pass also reads)
+        kept_rows = {line.split(" ")[0]
+                     for line in listing.getvalue().splitlines()
+                     if " from the argument " not in line
+                     and " from a constant" not in line}
+        assert kept_rows == {f"f32[{b},{s},{h * d}]", f"f32[{b * h},8,{s}]"}
+
+
+def test_narrower_parts_are_merged_and_counted_so():
+    """Parts narrower than a lane tile (here 64 and 32 wide) are merged to
+    [b*h, s, d] and read a head a row: the `heads` form."""
+    ks = jax.random.split(jax.random.key(2), 6)
+    shapes = ((1, 256, 3, 64), (1, 256, 3, 32), (1, 256, 3, 64),
+              (1, 256, 1, 32), (1, 256, 3, 64))
+    ops = [jax.random.normal(k, sh) for k, sh in zip(ks, shapes)]
+    forms = _compat.mla_operands()
+    grads = jax.grad(lambda *a: jnp.sum(fa.mla_attention(
+        *a, scale=0.1, impl="pallas", block_q=128, block_k=128) * ops[0]),
+        argnums=(0, 1, 2, 3, 4))(*ops)
+    want = jax.grad(lambda *a: jnp.sum(fa.mla_attention_xla(
+        *a, scale=0.1) * ops[0]), argnums=(0, 1, 2, 3, 4))(*ops)
+    assert _compat.mla_operands().get("heads", 0) == forms.get("heads", 0) + 1
+    assert _compat.mla_operands().get("flat", 0) == forms.get("flat", 0)
+    for got, ref in zip(grads, want):
+        np.testing.assert_allclose(got, ref, atol=2e-5)
+
+
 def test_mla_attention_checks_its_arguments_and_chooses_its_path():
     ops, _ = _mla_operands(1, 128, 2)
     with pytest.raises(ValueError, match="impl"):
@@ -434,9 +524,10 @@ def test_deinterleaved_weights_give_the_interleaved_ropes_scores():
 # ----------------------------------------------------- scopes and counters
 def test_scopes_and_counters_of_a_traced_step():
     """Every device scope of the block, and the registry counters bumped
-    once a traced call: the MLA kernels' tile once a layer and pass (the
-    kernels are taken through the interpreter here), the router's scoring
-    and the held share once an expert layer."""
+    once a traced call: the MLA kernels' tile and the `flat` form of their
+    operands once a layer and pass (the kernels are taken through the
+    interpreter here), the router's scoring and the held share once an
+    expert layer."""
     from unittest import mock
 
     from paddle_tpu.observability import counter
@@ -459,6 +550,7 @@ def test_scopes_and_counters_of_a_traced_step():
     router = series("paddle_tpu_moe_router", ("scoring", "experts", "k"),
                     {"scoring": "sigmoid", "experts": "128", "k": "6"})
     before = held(), router(), _compat.mla_blocks()
+    forms = _compat.mla_operands()
     leaves = list(model.parameters())
 
     def loss(arrays, ids):
@@ -486,6 +578,9 @@ def test_scopes_and_counters_of_a_traced_step():
         key = (kernel, 128, 128)
         assert after.get(key, 0) == before[2].get(key, 0) + 3, key
         assert kernel in text
+    # the 128-wide parts read in place, once a layer and pass
+    assert _compat.mla_operands().get("flat", 0) == forms.get("flat", 0) + 3
+    assert _compat.mla_operands().get("heads", 0) == forms.get("heads", 0)
     for name in ("embedding", "attention", "attention.latent",
                  "attention.expand", "attention.core", "attention.out",
                  "mlp", "moe", "moe.router", "moe.experts",
