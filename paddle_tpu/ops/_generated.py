@@ -2310,6 +2310,16 @@ def mla_attention(q_nope, q_rope, k_nope, k_rope, v, scale, causal=True, impl="a
     return _call('mla_attention', _impl_nn_ops.mla_attention, (q_nope, q_rope, k_nope, k_rope, v,), {'scale': scale, 'causal': causal, 'impl': impl})
 
 
+def window_attention(query, key, value, window, scale=None, impl="auto", name=None):
+    """window_attention (generated from ops.yaml)."""
+    return _call('window_attention', _impl_nn_ops.window_attention, (query, key, value,), {'window': window, 'scale': scale, 'impl': impl})
+
+
+def rope_qk_freqs(q, k, inv_freq, scale=1.0, name=None):
+    """rope_qk_freqs (generated from ops.yaml)."""
+    return _call('rope_qk_freqs', _impl_fused_ops.rope_qk_freqs, (q, k,), {'inv_freq': inv_freq, 'scale': scale})
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", num_k_heads=None, name=None):
     """gated_delta_rule (generated from ops.yaml)."""
     return _call('gated_delta_rule', _impl_gdn_ops.gated_delta_rule, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl, 'num_k_heads': num_k_heads})
@@ -3181,6 +3191,7 @@ __all__ = [
     'rnn',
     'roll',
     'rope_qk',
+    'rope_qk_freqs',
     'rot90',
     'round',
     'round_',
@@ -3276,6 +3287,7 @@ __all__ = [
     'var',
     'vector_norm',
     'where',
+    'window_attention',
     'zero_centered_rms_norm',
     'zeros',
     'zeros_like',

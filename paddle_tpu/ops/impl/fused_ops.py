@@ -99,6 +99,56 @@ def rope_qk(q, k, position_ids=None, *, base=10000.0,
     return out[0], out[1]
 
 
+def yarn_parameters(head_dim, base, factor, original_max_position_embeddings,
+                    beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                    truncate=True):
+    """YaRN's rotary frequencies and the scale of cos and sin (ref: HF
+    transformers ``_compute_yarn_parameters``): -> (inv_freq, a float32
+    numpy array [head_dim / 2], attention_factor). A pair rotating fewer
+    than ``beta_slow`` times over the original context is interpolated
+    (its frequency over ``factor``), one rotating more than ``beta_fast``
+    times is kept, and the pairs between are blended along a linear ramp
+    over the correction range, whose ends are truncated to whole pairs.
+    ``attention_factor`` None is 0.1 ln(factor) + 1."""
+    import math
+
+    import numpy as np
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low, high = correction_dim(beta_fast), correction_dim(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    pos_freqs = base ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                         / head_dim)
+    extrapolation = 1.0 - ramp
+    inv_freq = ((1.0 / (factor * pos_freqs)) * (1.0 - extrapolation)
+                + (1.0 / pos_freqs) * extrapolation)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return inv_freq.astype(np.float32), float(attention_factor)
+
+
+def rope_qk_freqs(q, k, *, inv_freq, scale=1.0):
+    """``rope_qk`` (rotate-half pairs, positions 0 .. s - 1) at the given
+    per-pair frequencies ``inv_freq`` (a sequence of head_dim / 2 floats),
+    cos and sin multiplied by ``scale``: YaRN's rope with
+    ``yarn_parameters``' two results."""
+    s = q.shape[1]
+    freqs = (jnp.arange(s, dtype=jnp.float32)[:, None]
+             * jnp.asarray(inv_freq, jnp.float32)[None, :])[None]
+    cos, sin = jnp.cos(freqs) * scale, jnp.sin(freqs) * scale
+    return _apply_rope(q, cos, sin, True), _apply_rope(k, cos, sin, True)
+
+
 def fused_linear(x, weight, bias=None, *, transpose_weight=False):
     """ref: incubate/nn/functional/fused_matmul_bias.py."""
     w = weight.T if transpose_weight else weight

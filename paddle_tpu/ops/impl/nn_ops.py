@@ -897,6 +897,41 @@ def scaled_dot_product_attention(
     return jnp.swapaxes(out, 1, 2).astype(query.dtype)
 
 
+def window_attention(query, key, value, *, window, scale=None, impl="auto"):
+    """Sliding-window causal self-attention, [batch, seq, heads, head_dim]
+    in and out: a query at position p sees keys p - window + 1 .. p. impl
+    ``"auto"`` is the flash kernels' band schedule where
+    ``scaled_dot_product_attention`` would take the causal kernels, and the
+    band-masked ``jax.numpy`` form elsewhere; ``"pallas"`` always the
+    kernels (the interpreter off the TPU), ``"xla"`` always the math."""
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(
+            f'window_attention impl must be "auto", "pallas" or "xla", got '
+            f"{impl!r}")
+    if impl == "auto":
+        from ...core import device
+
+        impl = "pallas" if device.on_tpu() and _pallas_attention_eligible(
+            query, key, value, None, 0.0, True) else "xla"
+    if impl == "pallas":
+        from ...kernels.pallas.flash_attention import flash_attention
+
+        return flash_attention(query, key, value, causal=True, scale=scale,
+                               window=window)
+    s = query.shape[1]
+    pos = jnp.arange(s)
+    gap = pos[:, None] - pos[None, :]
+    band = jnp.logical_and(gap >= 0, gap < window)
+    q = jnp.swapaxes(query, 1, 2).astype(jnp.float32)
+    k = jnp.swapaxes(key, 1, 2).astype(jnp.float32)
+    v = jnp.swapaxes(value, 1, 2).astype(jnp.float32)
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (
+        scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]))
+    probs = jax.nn.softmax(jnp.where(band, sc, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    return jnp.swapaxes(out, 1, 2).astype(query.dtype)
+
+
 def mla_attention(q_nope, q_rope, k_nope, k_rope, v, *, scale, causal=True,
                   impl="auto"):
     """Latent attention's core: the score is ``(q_nope . k_nope + q_rope .

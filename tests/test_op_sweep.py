@@ -69,6 +69,8 @@ SKIP = {
     "gated_delta_rule": "owned by test_gated_delta_rule",
     "mamba2_ssd": "six-operand scan contract owned by test_mamba2_ssd",
     "mla_attention": "five-operand score contract owned by test_deepseek_v3",
+    "window_attention": "band contract owned by test_mellum2",
+    "rope_qk_freqs": "frequency-list contract owned by test_mellum2",
     "fused_linear_cross_entropy": "chunked loss owned by test_fused_loss",
     "fused_rotary_position_embedding": "owned by test_pallas_kernels",
     "rope_qk": "owned by test_pallas_kernels",
