@@ -42,7 +42,11 @@ parts are 128 wide, ``heads`` where they are narrower and are merged).
 kernels: ``paddle_tpu_kernels_ssd_chunk{chunk,d_head,d_state,groups}`` once
 a traced ``mamba2_ssd`` kernel call, and ``paddle_tpu_kernels_ssd_blocks
 {kernel,heads,chunks}`` once a traced kernel (the heads of a grid step, the
-chunks a sequence is walked in).
+chunks a sequence is walked in). ``record_gmm_tiles()`` counts the
+three grouped-matmul kernels' traced calls by the tiles
+``grouped_matmul.choose_tiles`` (or the caller) gave them
+(``paddle_tpu_kernels_gmm_tiles{kernel,tm,tk,tn}``: rows, depth and
+columns of a grid step).
 
 A fifth: ``recompute_segment()`` — ``distributed.recompute`` marks the
 extent in which its segment is traced, and a ``flash_attention`` or
@@ -344,6 +348,33 @@ def ssd_blocks():
         (labels["kernel"], int(labels["heads"]), int(labels["chunks"])):
         child.value
         for labels, child in _ssd_blocks_counter()._series()
+    }
+
+
+def _gmm_tiles_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_gmm_tiles",
+        "Traced grouped-matmul kernel calls by the tiles they were given: "
+        "rows, depth and columns of a grid step",
+        labelnames=("kernel", "tm", "tk", "tn"),
+    )
+
+
+def record_gmm_tiles(kernel, tm, tk, tn):
+    """One traced call of a grouped-matmul ``kernel`` with row tile
+    ``tm`` over a [tk, tn] block."""
+    _gmm_tiles_counter().inc(kernel=kernel, tm=tm, tk=tk, tn=tn)
+
+
+def gmm_tiles():
+    """{(kernel, tm, tk, tn): traced calls} (test/diagnostic accessor)."""
+    return {
+        (labels["kernel"],) + tuple(int(labels[n]) for n in ("tm", "tk",
+                                                             "tn")):
+        child.value
+        for labels, child in _gmm_tiles_counter()._series()
     }
 
 

@@ -450,3 +450,51 @@ def test_scopes_and_counters_of_a_traced_step():
     for name in ("embedding", SLIDING, FULL, "moe", "moe.router",
                  "moe.experts", "lm_head_loss"):
         assert f"{name}/" in text or f"({name})" in text, name
+
+
+def test_a_traced_step_records_the_grouped_matmul_tiles():
+    """The held experts through the grouped-matmul kernels (the
+    interpreter here): each kernel's traced calls carry the tiles
+    `choose_tiles` gives the layer's pass, and no other."""
+    from unittest import mock
+
+    from paddle_tpu.kernels.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.impl import moe_ops
+
+    paddle.seed(0)
+    model = Mellum2ForCausalLM(Mellum2Config.tiny(
+        sliding_window=64, held_experts=(0, 4), fused_loss_chunk=64))
+    leaves = list(model.parameters())
+    ids = jnp.zeros((1, 256), jnp.int32)
+    mlp = model.model.layers[0].mlp
+    rows, experts = mlp.held_rows(ids.size), mlp.held[1]
+    hidden, ff = mlp.experts.w_gate.shape[1:]
+    itemsize = jnp.dtype(mlp.experts.w_gate._data.dtype).itemsize
+    want = {(kernel, *gm.choose_tiles(rows, k, m, experts, itemsize, kernel))
+            for k, m in ((hidden, ff), (ff, hidden))
+            for kernel, kk, mm in ((gm.FWD, k, m), (gm.DLHS, m, k),
+                                   (gm.DRHS, k, m))}
+    want = {(kernel, min(tm, rows), tk, tn)
+            for kernel, tm, tk, tn in want}
+
+    def loss(arrays, ids):
+        old = [p._data for p in leaves]
+        for p, a in zip(leaves, arrays):
+            p._data = a
+        try:
+            with paddle.no_grad(), _rng_lift(jax.random.key(0)):
+                return model(paddle.to_tensor(ids),
+                             labels=paddle.to_tensor(ids))[1]._data
+        finally:
+            for p, a in zip(leaves, old):
+                p._data = a
+
+    before = _compat.gmm_tiles()
+    real = moe_ops.moe_held_experts
+    with mock.patch.object(
+            moe_ops, "moe_held_experts",
+            lambda *a, **kw: real(*a, **{**kw, "impl": "pallas"})):
+        jax.jit(jax.grad(loss)).trace([p._data for p in leaves], ids)
+    moved = {key for key, n in _compat.gmm_tiles().items()
+             if n != before.get(key, 0)}
+    assert moved == want

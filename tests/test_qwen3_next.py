@@ -385,15 +385,26 @@ def test_held_rows_bound():
 
 
 # ------------------------------------------------ the grouped matmul's VJP
-@pytest.mark.parametrize("sizes", [[3, 0, 5, 1, 0, 7], [0, 0, 16, 0],
-                                   [40, 1, 300, 0, 43], [0, 0, 0]])
-def test_grouped_matmul_vjp_kernels_match_the_xla_form(sizes):
+@pytest.mark.parametrize("sizes,rows", [
+    pytest.param([3, 0, 5, 1, 0, 7], None, id="sizes0"),
+    pytest.param([0, 0, 16, 0], None, id="sizes1"),
+    pytest.param([40, 1, 300, 0, 43], None, id="sizes2"),
+    pytest.param([0, 0, 0], None, id="sizes3"),
+    # a pass's occupancy: every group empty, one group, the rows sent at a
+    # quarter, half and all of the pass
+    pytest.param([0, 0, 0, 0], 64, id="pass_empty"),
+    pytest.param([0, 0, 37, 0], 64, id="pass_one_group"),
+    pytest.param([5, 3, 0, 8], 64, id="pass_quarter"),
+    pytest.param([9, 0, 14, 9], 64, id="pass_half"),
+    pytest.param([16, 17, 15, 16], 64, id="pass_full"),
+])
+def test_grouped_matmul_vjp_kernels_match_the_xla_form(sizes, rows):
     """`grouped_matmul_dlhs` and `grouped_matmul_drhs` against jax.grad of
     the XLA form, with empty and uneven groups and rows past the last
-    group (which belong to none)."""
+    group (which belong to none): 5 of them, or a pass of ``rows``."""
     from paddle_tpu.kernels.pallas.grouped_matmul import grouped_matmul
 
-    n, e, k, m = sum(sizes) + 5, len(sizes), 24, 40
+    n, e, k, m = rows or sum(sizes) + 5, len(sizes), 24, 40
     ks = jax.random.split(jax.random.key(1), 3)
     lhs = jax.random.normal(ks[0], (n, k))
     rhs = jax.random.normal(ks[1], (e, k, m))
