@@ -29,8 +29,11 @@ behind a sigmoid gate or (``shared_gate=False``) added as it is, and
 ``router_dtype="float32"`` takes the router's product in float32.
 ``scoring="sigmoid"`` is DeepSeek-V3's router in place of softmax top-k:
 the float32 buffer ``gate.e_score_correction_bias`` chooses the experts
-and does not weigh them. The int32 buffer ``expert_load`` holds the rows
-each held expert was sent in the last step.
+and does not weigh them; with ``n_group`` > 1 the experts are chosen
+among each token's ``topk_group`` best groups (``noaux_tc``). The int32
+buffer ``expert_load`` holds the rows each held expert was sent in the
+last step, and under groups ``group_load`` the tokens that kept each
+group.
 """
 from __future__ import annotations
 
@@ -199,7 +202,8 @@ class MoELayer(Layer):
                  impl="dense", held=None, shared_expert=None,
                  router_dtype=None, scoring="softmax", norm_topk_prob=True,
                  routed_scaling_factor=1.0, shared_gate=True,
-                 shared_expert_name="shared_expert"):
+                 shared_expert_name="shared_expert", n_group=1,
+                 topk_group=1):
         """``held=(start, count)``: the layer holds ``count`` of the
         ``num_experts`` the router chooses among (its expert weights are
         ``[count, ...]``) and computes their part alone, on the ragged
@@ -214,7 +218,11 @@ class MoELayer(Layer):
         ``gate.e_score_correction_bias`` (a float32 buffer [num_experts]
         at 0, no gradient; its update rule is the trainer's), the chosen
         scores normalised over the k if ``norm_topk_prob`` and multiplied
-        by ``routed_scaling_factor``."""
+        by ``routed_scaling_factor``. ``n_group`` / ``topk_group`` (the
+        sigmoid router's): the experts in ``n_group`` groups of consecutive
+        ones, each token choosing among its ``topk_group`` groups with the
+        highest sum of two best ``s + bias``; the int32 buffer
+        ``group_load`` [n_group] keeps the tokens that kept each group."""
         super().__init__()
         if impl not in ("dense", "ragged"):
             raise ValueError(
@@ -248,6 +256,13 @@ class MoELayer(Layer):
         if held is None and scoring != "softmax":
             raise ValueError(
                 f"MoELayer: scoring={scoring!r} is the held path's (held=)")
+        if n_group > 1 and (scoring != "sigmoid" or num_experts % n_group
+                            or not 0 < topk_group <= n_group):
+            raise ValueError(
+                f"MoELayer: n_group={n_group}, topk_group={topk_group} "
+                f"need the sigmoid router and groups that divide the "
+                f"{num_experts} experts")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         self.scoring = scoring
         self.norm_topk_prob = bool(norm_topk_prob)
         self.routed_scaling_factor = float(routed_scaling_factor)
@@ -273,6 +288,9 @@ class MoELayer(Layer):
         if self.held is not None:
             self.register_buffer(
                 "expert_load", F.zeros([self.held[1]], "int32"))
+        if self.n_group > 1:
+            self.register_buffer(
+                "group_load", F.zeros([self.n_group], "int32"))
         # "dense": the capacity-padded [e, c, m] grouped einsum (the
         # bit-reference path). "ragged": dropless sort-by-expert +
         # ragged grouped_matmul over contiguous expert segments — no
@@ -309,9 +327,13 @@ class MoELayer(Layer):
         """Keep the rows each held expert was sent this step."""
         self.expert_load._rebind(load.detach()._data)
 
+    def record_group_load(self, group_load):
+        """Keep the tokens that kept each group this step."""
+        self.group_load._rebind(group_load.detach()._data)
+
     def _forward_held(self, flat):
-        """[n, m] -> ([n, m], expert_load): the held experts' part of the
-        routed sum, plus the shared expert."""
+        """[n, m] -> ([n, m], expert_load, group_load or None): the held
+        experts' part of the routed sum, plus the shared expert."""
         from ..core.autograd import scope
         from ..observability import counter
 
@@ -335,6 +357,12 @@ class MoELayer(Layer):
                 logits = F.moe_router_logits(
                     flat, self.gate.weight, dtype=self.router_dtype)
             rows = self.held_rows(n)
+            groups, group_load = {}, None
+            if self.n_group > 1:
+                groups = dict(n_group=self.n_group,
+                              topk_group=self.topk_group)
+                group_load = F.moe_group_load(
+                    logits, self.gate.e_score_correction_bias, **groups)
             if self.scoring == "softmax":
                 row_token, row_weight, load = F.moe_held_dispatch(
                     flat, logits, k=k, start=start, count=count, rows=rows)
@@ -343,7 +371,7 @@ class MoELayer(Layer):
                     flat, logits, k=k, start=start, count=count, rows=rows,
                     renormalize=self.norm_topk_prob, scoring=self.scoring,
                     bias=self.gate.e_score_correction_bias,
-                    scale=self.routed_scaling_factor)
+                    scale=self.routed_scaling_factor, **groups)
         with scope("moe.experts"):
             ex = self.experts
             out = F.moe_held_experts(
@@ -357,7 +385,7 @@ class MoELayer(Layer):
                 else:
                     out = out + F.sigmoid(self.shared_gate(flat)) * (
                         shared(flat))
-        return out, load
+        return out, load, group_load
 
     def forward(self, x, return_stats=False):
         """[b, s, m] -> ([b, s, m], aux_loss). With return_stats=True a
@@ -371,15 +399,20 @@ class MoELayer(Layer):
         b, s, m = x.shape
         flat = F.reshape(x, [b * s, m])
         if self.held is not None:
-            out, load = self._forward_held(flat)
+            out, load, group_load = self._forward_held(flat)
             out = F.reshape(out, [b, s, m])
             aux = F.zeros([], "float32")
             if return_stats:
                 # inside a recomputed segment the caller carries the load
                 # out and calls record_load there (a buffer written inside
                 # jax.checkpoint would keep a dead tracer)
-                return out, aux, {"expert_load": load}
+                stats = {"expert_load": load}
+                if group_load is not None:
+                    stats["group_load"] = group_load
+                return out, aux, stats
             self.record_load(load)
+            if group_load is not None:
+                self.record_group_load(group_load)
             return out, aux
         if type(self.gate) is not TopKGate:
             dispatch, combine, aux = self.gate(flat)

@@ -31,7 +31,9 @@ block_k}``, so a test, ``chip_smoke.py`` or a reader of the metrics
 registry can say which tile a shape got. ``record_mla_blocks()`` is the
 same count for the latent-attention kernels (``paddle_tpu_kernels_mla_blocks{kernel,block_q,block_k}``).
 ``record_gdr_blocks()`` does the same for the two gated-delta-rule kernels' grid step
-(``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``), and
+(``paddle_tpu_kernels_gdr_blocks{kernel,key_heads,chunks}``),
+``record_kda_blocks()`` for the two Kimi-delta-attention kernels'
+(``paddle_tpu_kernels_kda_blocks{kernel,heads,chunks}``), and
 ``record_gdr_operands()`` for the form a ``gated_delta_rule`` call's q, k
 and v came in (``paddle_tpu_kernels_gdr_operands{form}``: ``flat`` is what
 the kernels read in place, ``heads`` costs a copy of each on a TPU);
@@ -247,6 +249,33 @@ def gdr_blocks():
         (labels["kernel"], int(labels["key_heads"]), int(labels["chunks"])):
         child.value
         for labels, child in _gdr_blocks_counter()._series()
+    }
+
+
+def _kda_blocks_counter():
+    from ...observability import counter
+
+    return counter(
+        "paddle_tpu_kernels_kda_blocks",
+        "Traced Kimi-delta-attention kernel calls by the grid step they "
+        "were given",
+        labelnames=("kernel", "heads", "chunks"),
+    )
+
+
+def record_kda_blocks(kernel, heads, chunks):
+    """One traced call of ``kernel`` (``kda_fwd``, ``kda_bwd``) whose grid
+    step holds ``heads`` heads over ``chunks`` chunks."""
+    _kda_blocks_counter().inc(kernel=kernel, heads=heads, chunks=chunks)
+
+
+def kda_blocks():
+    """{(kernel, heads, chunks): traced calls} (test/diagnostic
+    accessor)."""
+    return {
+        (labels["kernel"], int(labels["heads"]), int(labels["chunks"])):
+        child.value
+        for labels, child in _kda_blocks_counter()._series()
     }
 
 

@@ -2285,9 +2285,14 @@ def moe_router_logits(x, weight, dtype="float32", name=None):
     return _call('moe_router_logits', _impl_moe_ops.moe_router_logits, (x, weight,), {'dtype': dtype})
 
 
-def moe_held_dispatch(x, gate_logits, k, start, count, rows, renormalize=True, scoring="softmax", bias=None, scale=1.0, name=None):
+def moe_held_dispatch(x, gate_logits, k, start, count, rows, renormalize=True, scoring="softmax", bias=None, scale=1.0, n_group=1, topk_group=1, name=None):
     """moe_held_dispatch (generated from ops.yaml)."""
-    return _call('moe_held_dispatch', _impl_moe_ops.moe_held_dispatch, (x, gate_logits, bias,), {'k': k, 'start': start, 'count': count, 'rows': rows, 'renormalize': renormalize, 'scoring': scoring, 'scale': scale})
+    return _call('moe_held_dispatch', _impl_moe_ops.moe_held_dispatch, (x, gate_logits, bias,), {'k': k, 'start': start, 'count': count, 'rows': rows, 'renormalize': renormalize, 'scoring': scoring, 'scale': scale, 'n_group': n_group, 'topk_group': topk_group})
+
+
+def moe_group_load(gate_logits, bias=None, n_group=1, topk_group=1, name=None):
+    """moe_group_load (generated from ops.yaml)."""
+    return _call('moe_group_load', _impl_moe_ops.moe_group_load, (gate_logits, bias,), {'n_group': n_group, 'topk_group': topk_group})
 
 
 def moe_held_experts(x, w_gate, w_up, w_down, row_token, row_weight, expert_load, rows, impl="auto", name=None):
@@ -2323,6 +2328,11 @@ def rope_qk_freqs(q, k, inv_freq, scale=1.0, name=None):
 def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="auto", num_k_heads=None, name=None):
     """gated_delta_rule (generated from ops.yaml)."""
     return _call('gated_delta_rule', _impl_gdn_ops.gated_delta_rule, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl, 'num_k_heads': num_k_heads})
+
+
+def kda(q, k, v, g, beta, chunk=64, impl="auto", num_heads=None, name=None):
+    """kda (generated from ops.yaml)."""
+    return _call('kda', _impl_gdn_ops.kda, (q, k, v, g, beta,), {'chunk': chunk, 'impl': impl, 'num_heads': num_heads})
 
 
 def mamba2_ssd(x, dt, a, b, c, d, chunk=256, n_groups=1, impl="auto", name=None):
@@ -3056,6 +3066,7 @@ __all__ = [
     'isneginf',
     'isposinf',
     'isreal',
+    'kda',
     'kl_div',
     'kron',
     'kthvalue',
@@ -3123,6 +3134,7 @@ __all__ = [
     'mode',
     'moe_combine',
     'moe_gate_dispatch',
+    'moe_group_load',
     'moe_held_dispatch',
     'moe_held_experts',
     'moe_ragged_combine',

@@ -1,6 +1,7 @@
 """The operations the Qwen3-Next block brought that another block could
-use: the zero-centred RMSNorm, partial rotary embedding, and the gated
-delta rule (kernels/pallas/gated_delta_rule.py). What only that block's
+use: the zero-centred RMSNorm, partial rotary embedding, the gated delta
+rule (kernels/pallas/gated_delta_rule.py), and its per-channel decay
+form, Kimi Delta Attention (kernels/pallas/kda.py). What only that block's
 DeltaNet mixer needs (HF's interleaved layout, its convolution, gates and
 output norm) is private to models/qwen3_next.py.
 
@@ -49,3 +50,12 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk=64, impl="auto",
     from ...kernels.pallas.gated_delta_rule import gated_delta_rule as _gdr
 
     return _gdr(q, k, v, g, beta, chunk, impl=impl, num_k_heads=num_k_heads)
+
+
+def kda(q, k, v, g, beta, *, chunk=64, impl="auto", num_heads=None):
+    """The public op face of ``kernels.pallas.kda``: q, k, g (a log-decay
+    per key channel) and v as [b, t, heads, d], or as [b, t, heads * d]
+    with ``num_heads``."""
+    from ...kernels.pallas.kda import kda as _kda
+
+    return _kda(q, k, v, g, beta, chunk, impl=impl, num_heads=num_heads)

@@ -182,8 +182,33 @@ def moe_router_logits(x, weight, *, dtype="float32"):
         precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None)
 
 
+def _kept_groups(choose, n_group, topk_group):
+    """[s, e] selection scores -> [s, n_group] bool: the ``topk_group``
+    groups of ``e / n_group`` consecutive experts each token keeps, a
+    group scored by the sum of its two highest (DeepSeek-V3's
+    ``noaux_tc``)."""
+    s, e = choose.shape
+    top2, _ = jax.lax.top_k(choose.reshape(s, n_group, e // n_group), 2)
+    _, kept = jax.lax.top_k(top2.sum(-1), topk_group)
+    return jnp.zeros((s, n_group), bool).at[
+        jnp.arange(s)[:, None], kept].set(True)
+
+
+def moe_group_load(gate_logits, bias=None, *, n_group, topk_group):
+    """Tokens that kept each group under the group-limited sigmoid router
+    of ``moe_held_dispatch(n_group=, topk_group=)``: [n_group] int32,
+    ``topk_group`` a token (what a rank that holds one group's experts is
+    offered; a uniform router gives each group s topk_group / n_group)."""
+    scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    choose = scores if bias is None else scores + jax.lax.stop_gradient(
+        bias.astype(jnp.float32))
+    kept = _kept_groups(choose, n_group, topk_group)
+    return kept.sum(0, dtype=jnp.int32)
+
+
 def moe_held_dispatch(x, gate_logits, bias=None, *, k, start, count, rows,
-                      renormalize=True, scoring="softmax", scale=1.0):
+                      renormalize=True, scoring="softmax", scale=1.0,
+                      n_group=1, topk_group=1):
     """Routing for an expert layer that holds experts ``[start, start +
     count)`` of the ``e`` the router chooses among (one expert-parallel
     rank's share; the whole layer when ``count == e``).
@@ -195,8 +220,12 @@ def moe_held_dispatch(x, gate_logits, bias=None, *, k, start, count, rows,
     bias`` (``e_score_correction_bias`` [e], float32, no gradient: it
     chooses and does not weigh), and the weights are the chosen scores,
     normalised over the k (+ 1e-20) when ``renormalize``, times ``scale``
-    (``routed_scaling_factor``). The assignments whose expert is held
-    here are kept, every one of them, and sorted by expert: the first
+    (``routed_scaling_factor``). With ``n_group`` > 1 (sigmoid only) the
+    k are chosen among the ``topk_group`` groups of ``e / n_group``
+    consecutive experts whose two highest ``scores + bias`` add up to the
+    most (``noaux_tc``); the weights are as before. The assignments whose
+    expert is held here are kept, every one of them, and sorted by
+    expert: the first
     ``sum(expert_load)`` entries of the returned lists, each expert's one
     contiguous segment.
 
@@ -206,6 +235,9 @@ def moe_held_dispatch(x, gate_logits, bias=None, *, k, start, count, rows,
     ``n`` = s * k rounded up to whole passes of ``rows``; expert_load
     [count] int32: the rows each held expert was sent)."""
     s = x.shape[0]
+    if n_group > 1 and scoring != "sigmoid":
+        raise ValueError("moe_held_dispatch: n_group > 1 is the sigmoid "
+                         "router's")
     if scoring == "softmax":
         gates = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
         vals, idx = jax.lax.top_k(gates, k)           # [s, k]
@@ -215,6 +247,11 @@ def moe_held_dispatch(x, gate_logits, bias=None, *, k, start, count, rows,
         scores = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
         choose = scores if bias is None else scores + jax.lax.stop_gradient(
             bias.astype(jnp.float32))
+        if n_group > 1:
+            kept = _kept_groups(choose, n_group, topk_group)
+            choose = jnp.where(
+                jnp.repeat(kept, choose.shape[1] // n_group, axis=1),
+                choose, -jnp.inf)
         _, idx = jax.lax.top_k(choose, k)
         vals = jnp.take_along_axis(scores, idx, axis=-1)
         if renormalize:

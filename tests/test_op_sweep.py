@@ -67,6 +67,8 @@ SKIP = {
     "moe_held_dispatch": "held-share routing owned by test_qwen3_next",
     "moe_held_experts": "passes over kept rows owned by test_qwen3_next",
     "gated_delta_rule": "owned by test_gated_delta_rule",
+    "kda": "per-channel decay contract owned by test_kda",
+    "moe_group_load": "group-limited routing owned by test_ling3",
     "mamba2_ssd": "six-operand scan contract owned by test_mamba2_ssd",
     "mla_attention": "five-operand score contract owned by test_deepseek_v3",
     "window_attention": "band contract owned by test_mellum2",
